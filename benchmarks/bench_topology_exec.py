@@ -13,8 +13,10 @@ executes them directly through the `spikemm_gather` channel:
                   bytes ratio — the row CI gates is deterministic
   stream_memory   subprocess peak-RSS rows: `plan.run_stream` on an 8x
                   longer stream must hold RSS constant while the one-shot
-                  full-time path pays linearly (ISSUE acceptance, same
-                  property `tests/test_topology_exec.py` asserts)
+                  full-time path pays linearly (same property
+                  `tests/test_topology_exec.py` asserts). The children run
+                  on the CPU backend: host RSS is what they measure, and a
+                  chip belongs to one process, which is this one.
 
 All gated rows are relative (paired speedups, byte ratios, RSS ratios) so
 they survive runner hardware swaps, matching the tracked.json contract.
@@ -22,6 +24,7 @@ they survive runner hardware swaps, matching the tracked.json contract.
 
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -168,7 +171,8 @@ _MEM_SCRIPT = textwrap.dedent("""
 
 def _peak_rss_kb(mode: str, T: int) -> int:
     r = subprocess.run([sys.executable, "-c", _MEM_SCRIPT, mode, str(T)],
-                       capture_output=True, text=True, timeout=900)
+                       capture_output=True, text=True, timeout=900,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if r.returncode != 0:
         raise RuntimeError(r.stderr[-2000:])
     return int(r.stdout.strip().splitlines()[-1])

@@ -31,6 +31,7 @@ from benchmarks import (bench_applications, bench_energy, bench_kernels,
                         bench_roofline, bench_serving, bench_snn_engine,
                         bench_snn_models, bench_spiking_lm,
                         bench_topology_exec, bench_topology_storage)
+from repro import paths
 
 SUITES = [
     ("topology_storage", bench_topology_storage),
@@ -113,6 +114,7 @@ def main(argv=None) -> int:
 
 
 def _run_suites(selected, out_dir: str) -> int:
+    paths.enable_compile_cache()
     host = _host_meta()
     os.makedirs(out_dir, exist_ok=True)
     aggregate = {"schema": SCHEMA_VERSION, "created_unix": time.time(),

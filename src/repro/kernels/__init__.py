@@ -15,7 +15,8 @@ Cross-cutting machinery (mirroring the paper's single multi-granularity
 instruction set over heterogeneous dynamics):
 
   registry.py — KernelSpec registration + the one dispatch/policy layer,
-                including the pallas -> interpret -> ref fallback chain
+                including the interpret-mode -> ref fallback off-TPU (the
+                compiled path on a chip never falls back)
   incidents.py— per-process incident log of recorded degradations
                 (query with `repro.kernels.incidents()`); REPRO_STRICT=1
                 turns every degradation into a raised FallbackError

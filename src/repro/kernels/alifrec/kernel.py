@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import f32_precision
+
 
 def _alif_kernel(cur_ref, tau_ref, rho_ref, v0_ref, a0_ref, s_ref, vT_ref,
                  aT_ref, v_scr, a_scr, *, ct: int, v_th: float, beta: float):
@@ -37,23 +39,20 @@ def _alif_kernel(cur_ref, tau_ref, rho_ref, v0_ref, a0_ref, s_ref, vT_ref,
         v_scr[...] = v0_ref[...].astype(jnp.float32)
         a_scr[...] = a0_ref[...].astype(jnp.float32)
 
-    cur = cur_ref[...].astype(jnp.float32)           # (ct, bb, bn)
     tau = tau_ref[...].astype(jnp.float32)           # (1, bn)
     rho = rho_ref[...].astype(jnp.float32)           # (1, bn)
 
+    # per-step time slices go through the refs (see lif/kernel.py)
     def step(t, carry):
-        v, a, s_acc = carry
-        v = tau * v + cur[t]
+        v, a = carry
+        v = tau * v + cur_ref[t].astype(jnp.float32)
         s = (v >= v_th + beta * a).astype(jnp.float32)
         v = v * (1.0 - s)
         a = rho * a + s
-        s_acc = jax.lax.dynamic_update_index_in_dim(s_acc, s, t, 0)
-        return v, a, s_acc
+        s_ref[t] = s.astype(s_ref.dtype)
+        return v, a
 
-    v, a, spikes = jax.lax.fori_loop(
-        0, ct, step, (v_scr[...], a_scr[...],
-                      jnp.zeros(cur.shape, jnp.float32)))
-    s_ref[...] = spikes.astype(s_ref.dtype)
+    v, a = jax.lax.fori_loop(0, ct, step, (v_scr[...], a_scr[...]))
     v_scr[...] = v
     a_scr[...] = a
 
@@ -112,26 +111,25 @@ def _alifrec_kernel(cur_ref, w_ref, tau_ref, rho_ref, v0_ref, a0_ref, s0_ref,
         a_scr[...] = a0_ref[...].astype(jnp.float32)
         s_scr[...] = s0_ref[...].astype(jnp.float32)
 
-    cur = cur_ref[...].astype(jnp.float32)           # (ct, bb, N)
     w = w_ref[...].astype(jnp.float32)               # (N, N)
     tau = tau_ref[...].astype(jnp.float32)           # (1, N)
     rho = rho_ref[...].astype(jnp.float32)           # (1, N)
 
+    # per-step time slices go through the refs (see lif/kernel.py)
     def step(t, carry):
-        v, a, s, acc = carry
+        v, a, s = carry
         rec = jax.lax.dot_general(s, w, (((1,), (0,)), ((), ())),
+                                  precision=f32_precision(s, w),
                                   preferred_element_type=jnp.float32)
-        v = tau * v + cur[t] + rec
+        v = tau * v + cur_ref[t].astype(jnp.float32) + rec
         spk = (v >= v_th + beta * a).astype(jnp.float32)
         v = v * (1.0 - spk)
         a = rho * a + spk
-        acc = jax.lax.dynamic_update_index_in_dim(acc, spk, t, 0)
-        return v, a, spk, acc
+        s_out_ref[t] = spk.astype(s_out_ref.dtype)
+        return v, a, spk
 
-    v, a, s, spikes = jax.lax.fori_loop(
-        0, ct, step, (v_scr[...], a_scr[...], s_scr[...],
-                      jnp.zeros(cur.shape, jnp.float32)))
-    s_out_ref[...] = spikes.astype(s_out_ref.dtype)
+    v, a, s = jax.lax.fori_loop(0, ct, step,
+                                (v_scr[...], a_scr[...], s_scr[...]))
     v_scr[...] = v
     a_scr[...] = a
     s_scr[...] = s

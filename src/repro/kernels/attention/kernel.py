@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import f32_precision
+
 NEG_INF = -1e30
 
 
@@ -46,6 +48,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr, *,
         k = k_ref[0].astype(jnp.float32)           # (bk, d)
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=f32_precision(q, k),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -65,7 +68,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr, *,
         corr = jnp.exp(m_prev - m_new)             # (bq, 1)
         l_new = l_scr[...][:, :1] * corr + jnp.sum(p, -1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=f32_precision(p, v),
+            preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 

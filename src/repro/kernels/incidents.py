@@ -39,7 +39,7 @@ class FallbackEvent:
              "guard" (numerical guardrail fired), "autotune" (candidate or
              kernel skipped in a sweep), "serve" (request retry/degrade).
     family:  kernel family / subsystem the event belongs to.
-    stage:   the stage that failed ("pallas", "interpret", ...).
+    stage:   the stage that failed ("pallas", "vmem-model", ...).
     channel: implementation channel in use, if any (e.g. "sparse").
     dims:    logical dims of the call (shape fingerprint).
     error:   repr() of the underlying exception, or a description.
@@ -96,21 +96,24 @@ def strict_mode() -> bool:
 def degrade(kind: str, family: str, stage: str, error: Any, *,
             channel: Optional[str] = None,
             dims: Optional[Dict[str, int]] = None,
-            blocks: Optional[Dict[str, int]] = None) -> FallbackEvent:
-    """Record a degradation; raise `FallbackError` under REPRO_STRICT=1.
+            blocks: Optional[Dict[str, int]] = None,
+            fatal: bool = False) -> FallbackEvent:
+    """Record a degradation; raise `FallbackError` under REPRO_STRICT=1,
+    or always when `fatal` (a degradation the caller must not absorb).
 
-    `error` may be an exception (chained into the strict raise) or a
-    description string. Returns the recorded event when not strict.
+    `error` may be an exception (chained into the raise) or a description
+    string. Returns the recorded event when it does not raise.
     """
     ev = record(FallbackEvent(
         kind=kind, family=family, stage=stage,
         error=error if isinstance(error, str) else repr(error),
         channel=channel, dims=dict(dims or {}),
         blocks={k: int(v) for k, v in (blocks or {}).items()}))
-    if strict_mode():
+    if fatal or strict_mode():
         exc = error if isinstance(error, BaseException) else None
+        why = "fatal" if fatal else "REPRO_STRICT"
         raise FallbackError(
-            f"[REPRO_STRICT] {family}: {kind} degradation at stage "
+            f"[{why}] {family}: {kind} degradation at stage "
             f"{stage!r}: {ev.error}") from exc
     return ev
 

@@ -33,21 +33,20 @@ def _lif_kernel(cur_ref, tau_ref, v0_ref, s_ref, vT_ref, v_scr, *,
     def _():
         v_scr[...] = v0_ref[...].astype(jnp.float32)
 
-    cur = cur_ref[...].astype(jnp.float32)           # (ct, bb, bn)
     tau = tau_ref[...].astype(jnp.float32)           # (1, bn)
-    v = v_scr[...]
 
-    def step(t, carry):
-        v, s_acc = carry
-        v = tau * v + cur[t]
+    # Each step reads and writes its time slice through the refs: a dynamic
+    # index on the leading (untiled) ref axis is a plain VMEM address on
+    # Mosaic, whereas indexing a loaded value lowers to dynamic_slice, which
+    # the TPU lowering does not implement.
+    def step(t, v):
+        v = tau * v + cur_ref[t].astype(jnp.float32)
         s = (v >= v_th).astype(jnp.float32)
         v = v - v_th * s if reset == "subtract" else v * (1.0 - s)
-        s_acc = jax.lax.dynamic_update_index_in_dim(s_acc, s, t, 0)
-        return v, s_acc
+        s_ref[t] = s.astype(s_ref.dtype)
+        return v
 
-    v, spikes = jax.lax.fori_loop(
-        0, ct, step, (v, jnp.zeros(cur.shape, jnp.float32)))
-    s_ref[...] = spikes.astype(s_ref.dtype)
+    v = jax.lax.fori_loop(0, ct, step, v_scr[...])
     v_scr[...] = v
 
     @pl.when(t_idx == nt - 1)

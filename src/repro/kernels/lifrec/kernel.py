@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import f32_precision
+
 
 def _lifrec_kernel(cur_ref, w_ref, tau_ref, v0_ref, s0_ref, s_out_ref,
                    vT_ref, v_scr, s_scr, *, ct: int, v_th: float):
@@ -34,24 +36,22 @@ def _lifrec_kernel(cur_ref, w_ref, tau_ref, v0_ref, s0_ref, s_out_ref,
         v_scr[...] = v0_ref[...].astype(jnp.float32)
         s_scr[...] = s0_ref[...].astype(jnp.float32)
 
-    cur = cur_ref[...].astype(jnp.float32)           # (ct, bb, N)
     w = w_ref[...].astype(jnp.float32)               # (N, N)
     tau = tau_ref[...].astype(jnp.float32)           # (1, N)
 
+    # per-step time slices go through the refs (see lif/kernel.py)
     def step(t, carry):
-        v, s, acc = carry
+        v, s = carry
         rec = jax.lax.dot_general(s, w, (((1,), (0,)), ((), ())),
+                                  precision=f32_precision(s, w),
                                   preferred_element_type=jnp.float32)
-        v = tau * v + cur[t] + rec
+        v = tau * v + cur_ref[t].astype(jnp.float32) + rec
         spk = (v >= v_th).astype(jnp.float32)
         v = v * (1.0 - spk)
-        acc = jax.lax.dynamic_update_index_in_dim(acc, spk, t, 0)
-        return v, spk, acc
+        s_out_ref[t] = spk.astype(s_out_ref.dtype)
+        return v, spk
 
-    v, s, spikes = jax.lax.fori_loop(
-        0, ct, step, (v_scr[...], s_scr[...],
-                      jnp.zeros(cur.shape, jnp.float32)))
-    s_out_ref[...] = spikes.astype(s_out_ref.dtype)
+    v, s = jax.lax.fori_loop(0, ct, step, (v_scr[...], s_scr[...]))
     v_scr[...] = v
     s_scr[...] = s
 

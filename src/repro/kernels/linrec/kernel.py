@@ -57,11 +57,14 @@ def _linrec_kernel(a_ref, x_ref, h0_ref, y_ref, hT_ref, h_scratch, *, ct: int):
     h = h_scratch[...]
     y = x + a * h[None]                         # inject carry
     y_ref[...] = y.astype(y_ref.dtype)
-    h_scratch[...] = y[-1]
+    # a static slice: `y[-1]` would lower to dynamic_slice, which the TPU
+    # lowering does not implement
+    y_last = jax.lax.index_in_dim(y, ct - 1, 0, keepdims=False)
+    h_scratch[...] = y_last
 
     @pl.when(t_idx == nt - 1)
     def _():
-        hT_ref[...] = y[-1].astype(hT_ref.dtype)
+        hT_ref[...] = y_last.astype(hT_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("ct", "bb", "bd", "interpret"))

@@ -11,18 +11,40 @@ Mosaic on TPU) agrees with the pure-jnp oracle on
 
 `check_kernel` raises AssertionError with the offending kernel/leaf on
 mismatch and returns a numeric report on success, so it doubles as a test
-assertion (tests/test_registry.py) and a health probe
-(`python -m repro.kernels.parity`).
+assertion (tests/test_registry.py), a health probe
+(`python -m repro.kernels.parity`) and the first phase of `chip_smoke.py`.
+
+The oracle side always runs the XLA reference at full f32 matmul
+precision: on a TPU, `auto` dispatch would otherwise pick the Pallas
+kernel for both sides, and XLA's default f32 matmul there rounds its
+operands to bf16.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import contextlib
+import os
+from typing import Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import registry
+
+
+@contextlib.contextmanager
+def _reference() -> Iterator[None]:
+    """Dispatch to the XLA reference, with f32 matmuls at full precision."""
+    old = os.environ.get("REPRO_KERNEL_IMPL")
+    os.environ["REPRO_KERNEL_IMPL"] = "ref"
+    try:
+        with jax.default_matmul_precision("highest"):
+            yield
+    finally:
+        if old is None:
+            os.environ.pop("REPRO_KERNEL_IMPL")
+        else:
+            os.environ["REPRO_KERNEL_IMPL"] = old
 
 
 def _loss(out) -> jax.Array:
@@ -47,7 +69,8 @@ def check_kernel(name: str, *, seed: int = 0,
         raise ValueError(f"kernel {name!r} registered without make_inputs")
     args = spec.make_inputs(jax.random.PRNGKey(seed))
 
-    ref_out = spec.apply(args, False)
+    with _reference():
+        ref_out = spec.apply(args, False)
     pal_out = spec.apply(args, True)
     ref_leaves = jax.tree_util.tree_leaves(ref_out)
     pal_leaves = jax.tree_util.tree_leaves(pal_out)
@@ -65,8 +88,9 @@ def check_kernel(name: str, *, seed: int = 0,
     report["forward_max_err"] = fwd_err
 
     if check_vjp and spec.diff_argnums:
-        grad_ref = jax.grad(lambda *a: _loss(spec.apply(a, False)),
-                            spec.diff_argnums)(*args)
+        with _reference():
+            grad_ref = jax.grad(lambda *a: _loss(spec.apply(a, False)),
+                                spec.diff_argnums)(*args)
         grad_pal = jax.grad(lambda *a: _loss(spec.apply(a, True)),
                             spec.diff_argnums)(*args)
         vjp_err = 0.0

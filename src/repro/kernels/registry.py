@@ -9,7 +9,7 @@ block specification ONCE, and every cross-cutting concern lives here
 instead of being copy-pasted per family:
 
   * ref-vs-pallas dispatch policy (`force_pallas` arg, `REPRO_KERNEL_IMPL`
-    env, interpret-mode fallback off-TPU),
+    env, interpret mode off-TPU),
   * block-shape resolution (per-axis alignment fitting, tuned-cache lookup
     via `repro.kernels.tuning`, explicit per-call overrides),
   * enumeration for the parity harness (`repro.kernels.parity`) and the
@@ -32,17 +32,21 @@ the pattern. The spec carries everything the generic machinery needs:
         tol=1e-4,
     ))
 
-Dispatch is also where the runtime's *failure story* lives: every call
-runs through a fallback chain (see `dispatch`) that degrades
-pallas -> interpret -> ref on a Pallas failure or VMEM-model rejection,
-records a structured `FallbackEvent` on the per-process incident log
-(`repro.kernels.incidents()`), and — under `REPRO_STRICT=1` — raises a
-`FallbackError` instead of degrading, so CI can prove the fast paths ran.
+Dispatch is also where the runtime's *failure story* lives (see
+`dispatch`). Every degradation is recorded as a structured `FallbackEvent`
+on the per-process incident log (`repro.kernels.incidents()`). Off-TPU, an
+interpret-mode Pallas failure or a VMEM-model rejection degrades to the
+reference, unless `REPRO_STRICT=1` makes it raise `FallbackError`. With
+compiled kernels (a real chip) nothing degrades: the same failures raise,
+so a chip run cannot pass on the reference while the chip ran no kernel.
 
 Environment knobs:
   REPRO_KERNEL_IMPL     = ref | pallas | auto   (auto: pallas on TPU,
                                                  ref elsewhere)
-  REPRO_PALLAS_INTERPRET= 1 | 0                 (force interpret on/off)
+  REPRO_PALLAS_INTERPRET= 0                     (off-TPU: trace compiled
+                                                 Mosaic kernels, e.g. to
+                                                 compile for a described
+                                                 chip)
   REPRO_TUNING_CACHE    = path to the JSON tuning cache
   REPRO_STRICT          = 1: degradations raise instead of falling back
   REPRO_FAULTS          = fault-injection spec (see repro.core.faults)
@@ -64,11 +68,14 @@ from repro.kernels.incidents import FallbackError, degrade  # noqa: F401
 
 
 def interpret_mode() -> bool:
-    """Pallas kernels execute in interpret mode off-TPU (CPU container)."""
-    forced = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if forced is not None:
-        return forced == "1"
-    return not on_tpu()
+    """Pallas kernels execute in interpret mode off-TPU, never on a TPU.
+
+    `REPRO_PALLAS_INTERPRET=0` turns it off off-TPU too, so the compiled
+    kernels can be traced and compiled for a chip that is described but
+    not attached."""
+    if on_tpu():
+        return False
+    return os.environ.get("REPRO_PALLAS_INTERPRET") != "0"
 
 
 def use_pallas(force_pallas: bool = False) -> bool:
@@ -293,20 +300,19 @@ def dispatch(name: str, args: Sequence[Any], force_pallas: bool = False,
     (e.g. block-sparse vs dense `spikemm` by measured occupancy), then the
     usual ref-vs-Pallas policy applies within the chosen channel.
 
-    **Fallback chain.** When the Pallas stage is selected, failures do not
-    kill the run: a raising Pallas call (genuine, or injected via a
-    `compile_fail` fault — see `repro.core.faults`) degrades
-    compiled -> interpret -> ref, and a call whose modeled VMEM working
-    set (`KernelSpec.vmem_bytes`) busts the budget is rejected up front
-    (real-Mosaic calls always; interpret-mode calls only under simulated
-    `vmem_limit` fault pressure, since interpret mode has no VMEM to
-    blow). Each degradation records a `FallbackEvent` on the incident log
-    and, under `REPRO_STRICT=1`, raises `FallbackError` instead. A failing
-    channel router likewise degrades to the default (dense) channel. Note
-    the chain catches what raises *through this call*: eager/interpret
-    execution and trace-time errors, which is where Pallas failures
-    surface off-TPU; a Mosaic compile error deferred to an outer jit's
-    AOT-compile happens outside dispatch and stays fatal.
+    **Failures.** A raising Pallas call (genuine, or injected via a
+    `compile_fail` fault — see `repro.core.faults`) and a call whose
+    modeled VMEM working set (`KernelSpec.vmem_bytes`) busts the budget
+    are both recorded as a `FallbackEvent`. The VMEM model is consulted
+    for compiled calls always, and for interpret-mode calls only under
+    simulated `vmem_limit` fault pressure, since interpret mode has no
+    VMEM to blow. In interpret mode the call then degrades to the
+    reference (or raises `FallbackError` under `REPRO_STRICT=1`); compiled
+    calls always raise `FallbackError`, because a reference result on the
+    chip would hide that no kernel ran. A failing channel router degrades
+    to the default (dense) channel. Only what raises *through this call*
+    is caught: a Mosaic compile error deferred to an outer jit's compile
+    happens outside dispatch and is fatal anyway.
     """
     from repro.core import faults  # local: keep core<->kernels import acyclic
 
@@ -342,37 +348,27 @@ def dispatch(name: str, args: Sequence[Any], force_pallas: bool = False,
 
     pallas_fn = chan.pallas if chan is not None else spec.pallas
     interp = interpret_mode()
+    blk = resolved_blocks()   # resolve up front so incidents carry context
 
     if spec.vmem_bytes is not None:
         from repro.kernels import tuning  # local: avoid import cycle
         limit = tuning.vmem_limit_bytes()
         pressured = faults.vmem_limit_override_bytes() is not None
         if not interp or pressured:
-            est = spec.vmem_bytes(dims, resolved_blocks())
+            est = spec.vmem_bytes(dims, blk)
             if est > limit:
                 degrade("vmem", name, "vmem-model",
                         f"modeled working set {int(est)} B exceeds budget "
                         f"{limit} B", channel=choice, dims=dims,
-                        blocks=blocks)
+                        blocks=blk, fatal=not interp)
                 return run_ref()
 
-    blk = resolved_blocks()   # resolve up front so incidents carry context
     try:
         faults.maybe_fail_compile(name)
         return pallas_fn(*args, blocks=blk, interpret=interp, **static)
     except Exception as e:
         degrade("dispatch", name, "pallas", e, channel=choice, dims=dims,
-                blocks=blk)
-    if not interp:
-        # the compiled path failed on real hardware: interpret mode runs the
-        # same kernel body in Python — slow, but it preserves the kernel's
-        # exact numerics while we limp along
-        try:
-            faults.maybe_fail_compile(name)
-            return pallas_fn(*args, blocks=blk, interpret=True, **static)
-        except Exception as e:
-            degrade("dispatch", name, "interpret", e, channel=choice,
-                    dims=dims, blocks=blk)
+                blocks=blk, fatal=not interp)
     return run_ref()
 
 

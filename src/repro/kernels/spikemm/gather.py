@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import registry
-from repro.kernels.common import pad_axis
+from repro.kernels.common import f32_precision, pad_axis
 
 DEFAULT_BK = 128
 DEFAULT_BN = 128
@@ -189,8 +189,9 @@ def _gather_kernel(jj_ref, kk_ref, act_ref, s_ref, w_ref, o_ref, acc_scr):
 
     @pl.when(act_ref[t] > 0)
     def _():                                  # sentinels skip the MXU
+        s, w = s_ref[...], w_ref[0]
         acc_scr[...] += jax.lax.dot_general(
-            s_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            s, w, (((1,), (0,)), ((), ())), precision=f32_precision(s, w),
             preferred_element_type=jnp.float32)
 
     # Same-jj entries are contiguous, so consecutive writes land in the same
@@ -223,11 +224,26 @@ def _gather_pallas(jj, kk, act, spikes, wblk, *, bm, bk, bn, jb,
     )(jj, kk, act, spikes, wblk)
 
 
+def _check_lane_tiling(bk: int, bn: int, kb: int, jb: int) -> None:
+    """Mosaic tiles the last axis of a VMEM block in 128-lane units: the
+    spike block (bm, bk) and the output tile (bm, bn) must each be a
+    multiple of 128 wide unless one block spans its whole axis."""
+    bad = [f"{name}={b}" for name, b, nb in (("bk", bk, kb), ("bn", bn, jb))
+           if b % 128 and nb > 1]
+    if bad:
+        raise ValueError(
+            f"spikemm_gather: table blocks {', '.join(bad)} cannot be tiled "
+            "by the TPU's 128-lane layout; build the tables with bk and bn "
+            "multiples of 128")
+
+
 def _pallas_impl(spikes, tables, *, blocks, interpret):
     bm = blocks["bm"]
     bk, bn = tables.bk, tables.bn
     kb = max(1, -(-tables.n_pre // bk))
     jb = max(1, -(-tables.n_post // bn))
+    if not interpret:
+        _check_lane_tiling(bk, bn, kb, jb)
     s_p, _ = pad_axis(spikes, 0, bm)
     s_p = jnp.pad(s_p, ((0, 0), (0, kb * bk - spikes.shape[1])))
     dt = tables.device()

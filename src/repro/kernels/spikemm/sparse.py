@@ -49,6 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import f32_precision
+
 
 def compact_blocks(flags: jax.Array,
                    size: Optional[int] = None
@@ -89,8 +91,9 @@ def _sparse_kernel(ii_ref, kk_ref, act_ref, s_ref, w_ref, o_ref, acc_scr):
 
     @pl.when(act_ref[t] > 0)
     def _():                                   # sentinels/padding skip the MXU
+        s, w = s_ref[...], w_ref[...]
         acc_scr[...] += jax.lax.dot_general(
-            s_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            s, w, (((1,), (0,)), ((), ())), precision=f32_precision(s, w),
             preferred_element_type=jnp.float32)
 
     # Same-row entries are contiguous, so consecutive writes land in the

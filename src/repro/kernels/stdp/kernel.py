@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import f32_precision
+
 
 def _stdp_kernel(xpre_ref, spost_ref, spre_ref, xpost_ref, w_ref, out_ref, *,
                  a_plus: float, a_minus: float, w_min: float, w_max: float):
@@ -39,8 +41,10 @@ def _stdp_kernel(xpre_ref, spost_ref, spre_ref, xpost_ref, w_ref, out_ref, *,
     spre = spre_ref[...].astype(jnp.float32)      # (B, bm)
     xpost = xpost_ref[...].astype(jnp.float32)    # (B, bn)
     pot = jax.lax.dot_general(xpre, spost, (((0,), (0,)), ((), ())),
+                              precision=f32_precision(xpre, spost),
                               preferred_element_type=jnp.float32)
     dep = jax.lax.dot_general(spre, xpost, (((0,), (0,)), ((), ())),
+                              precision=f32_precision(spre, xpost),
                               preferred_element_type=jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     w = w + a_plus * pot - a_minus * dep
@@ -48,17 +52,20 @@ def _stdp_kernel(xpre_ref, spost_ref, spre_ref, xpost_ref, w_ref, out_ref, *,
 
 
 def _stdp_seq_kernel(p_ref, q_ref, w_ref, out_ref, *,
-                     amps: tuple, w_min: float, w_max: float,
-                     batch: int, nsteps: int):
+                     amps: tuple, w_min: float, w_max: float, nsteps: int):
     w = w_ref[...].astype(jnp.float32)            # (bm, bn), VMEM-resident
 
+    # P/Q arrive as (K, T, B, block): step t indexes a leading, untiled
+    # axis, so no dynamic offset ever lands on the sublane (B) axis, which
+    # Mosaic could only take at a provable multiple of 8.
     def step(t, w):
         dw = jnp.zeros_like(w)
         for k, amp in enumerate(amps):            # K static: unrolled
-            p = p_ref[k, pl.ds(t * batch, batch), :].astype(jnp.float32)
-            q = q_ref[k, pl.ds(t * batch, batch), :].astype(jnp.float32)
+            p = p_ref[k, t].astype(jnp.float32)   # (B, bm)
+            q = q_ref[k, t].astype(jnp.float32)   # (B, bn)
             dw = dw + amp * jax.lax.dot_general(
                 p, q, (((0,), (0,)), ((), ())),
+                precision=f32_precision(p, q),
                 preferred_element_type=jnp.float32)
         return jnp.clip(w + dw, w_min, w_max)
 
@@ -83,20 +90,21 @@ def stdp_seq_pallas(P: jax.Array, Q: jax.Array, w: jax.Array, *,
     K, TB, M = P.shape
     N = Q.shape[2]
     assert M % bm == 0 and N % bn == 0 and TB % batch == 0, (M, N, TB)
+    T = TB // batch
     grid = (M // bm, N // bn)
     return pl.pallas_call(
         functools.partial(_stdp_seq_kernel, amps=amps, w_min=w_min,
-                          w_max=w_max, batch=batch, nsteps=TB // batch),
+                          w_max=w_max, nsteps=T),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((K, TB, bm), lambda i, j: (0, 0, i)),   # P
-            pl.BlockSpec((K, TB, bn), lambda i, j: (0, 0, j)),   # Q
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),         # w
+            pl.BlockSpec((K, T, batch, bm), lambda i, j: (0, 0, 0, i)),  # P
+            pl.BlockSpec((K, T, batch, bn), lambda i, j: (0, 0, 0, j)),  # Q
+            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),                 # w
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), w.dtype),
         interpret=interpret,
-    )(P, Q, w)
+    )(P.reshape(K, T, batch, M), Q.reshape(K, T, batch, N), w)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "a_plus", "a_minus",

@@ -22,7 +22,8 @@ infeasible tile would either crash Mosaic or thrash, and either way timing
 it wastes sweep budget. The spec-default config is never pruned — it is
 what dispatch falls back to anyway, so it must stay the measured baseline.
 
-Cache location: `$REPRO_TUNING_CACHE`, else `~/.cache/repro/kernel_tuning.json`.
+Cache location: `$REPRO_TUNING_CACHE`, else `experiments/kernel_tuning.json`
+in the checkout (gitignored; `repro.paths.TUNING_CACHE_PATH`).
 Misses fall through to the checked-in cache (`kernels/tuned/ci_cache.json`),
 which pins the winners for the CI / nightly-benchmark shapes so fresh
 checkouts dispatch with tuned blocks from the first call.
@@ -38,6 +39,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax
 
+from repro import paths
 from repro.core import faults
 from repro.kernels import registry
 # import names from the submodule directly: the `repro.kernels` package
@@ -55,10 +57,7 @@ BUNDLED_CACHE_PATH = os.path.join(os.path.dirname(__file__), "tuned",
 
 
 def default_cache_path() -> str:
-    return os.environ.get(
-        _ENV_CACHE,
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "kernel_tuning.json"))
+    return os.environ.get(_ENV_CACHE, paths.TUNING_CACHE_PATH)
 
 
 def shape_bucket(dims: Mapping[str, int]) -> str:

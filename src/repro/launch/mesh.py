@@ -20,12 +20,17 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests/elastic rescale (e.g. (4, 2) on 8 devices)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/elastic rescale (e.g. (4, 2) on 8 devices).
+
+    Axes are `Auto`: the sharding rules place arrays with
+    `with_sharding_constraint`, which takes only Auto axes, while
+    `jax.make_mesh` defaults to Explicit ones."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def describe(mesh: jax.sharding.Mesh) -> str:

@@ -34,9 +34,10 @@ cohorts run the *same* compiled step); the vmap path because lanes are
 independent by construction; evict/restore because spill is a pure
 device<->host copy.
 
-Resilience composes: kernel dispatch inside the resident step degrades
-pallas -> interpret -> ref per the registry chain (incidents recorded;
-REPRO_STRICT raises), `REPRO_FAULTS` / `REPRO_GUARD` thread through
+Resilience composes: kernel dispatch inside the resident step follows
+the registry's failure policy (off-TPU an interpret-mode failure degrades
+to the reference and is recorded; compiled kernels never degrade),
+`REPRO_FAULTS` / `REPRO_GUARD` thread through
 `plan.run` unchanged. The step cache keys on the ambient
 faults/engine/dispatch environment, so entering a fault context retraces
 instead of silently replaying a clean executable.
@@ -109,6 +110,7 @@ _STEP_CACHE: Dict[tuple, Callable] = {}
 def _env_fingerprint(guard: Optional[str]) -> tuple:
     return (plan_mod.engine_mode(),
             os.environ.get("REPRO_KERNEL_IMPL"),
+            os.environ.get("REPRO_PALLAS_INTERPRET"),
             os.environ.get("REPRO_SPIKEMM_SPARSE"),
             guard if guard is not None else os.environ.get("REPRO_GUARD"),
             faults.active())

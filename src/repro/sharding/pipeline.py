@@ -24,7 +24,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -77,9 +76,9 @@ def pipeline_apply(stage_fn: Callable[[Any, Array], Array],
         return outs
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(per_stage, mesh=mesh,
-                     in_specs=(pspec, P()), out_specs=P(),
-                     check_rep=False)(stage_params, x)
+    return jax.shard_map(per_stage, mesh=mesh,
+                         in_specs=(pspec, P()), out_specs=P(),
+                         check_vma=False)(stage_params, x)
 
 
 def microbatch(x: Array, n_micro: int) -> Array:

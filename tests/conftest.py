@@ -8,7 +8,10 @@ Tier policy (mirrored in .github/workflows/ci.yml):
                routing, plasticity; target well under 2 minutes
   full tier    pytest                 — adds model smoke / sharding /
                training-learns tests (the `slow` marker)
-  tpu tier     pytest -m tpu          — real-Mosaic runs; auto-skipped off-TPU
+
+Tests run on the CPU (Pallas kernels in interpret mode). The chip's
+compiler is exercised here ahead of time (tests/test_chip_compile.py);
+running on a TPU is `python chip_smoke.py`, not a pytest tier.
 
 If `hypothesis` is not installed (the baked container has no dev extras),
 a minimal deterministic stub (tests/_hypothesis_stub.py) is registered
@@ -53,18 +56,3 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running integration/smoke test; excluded "
                    "from the fast CI tier")
-    config.addinivalue_line(
-        "markers", "tpu: requires a real TPU backend; auto-skipped elsewhere")
-
-
-def pytest_collection_modifyitems(config, items):
-    tpu_items = [it for it in items if "tpu" in it.keywords]
-    if not tpu_items:
-        return
-    import jax  # deferred: keep collection cheap for -m deselections
-
-    if jax.default_backend() != "tpu":
-        skip = pytest.mark.skip(reason="requires TPU backend "
-                                       f"(running on {jax.default_backend()})")
-        for it in tpu_items:
-            it.add_marker(skip)

@@ -1,0 +1,174 @@
+"""Ahead-of-time compiles for a described TPU v5e chip.
+
+Nothing here runs on a chip: the TPU compiler, installed alongside JAX,
+compiles each kernel (and the whole resident serve step) for a v5e device
+that is described, not attached. That catches what interpret mode cannot
+(Mosaic lowering gaps, block shapes the (8, 128) tiling refuses) at no chip
+time. The widths are the served models': SHD (700 -> 64 -> 20, window 32,
+64 cohort slots), ECG (4 -> 64 recurrent -> 6, T = 1301), the plastic
+window (64 -> 32 -> 4), and a 10^5-neuron banded topology at the default
+gather blocks.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and a worker that described it at import
+would keep every other worker from collecting this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import registry
+
+f32 = jnp.float32
+
+# SHD serving: window 32 x 64 cohort slots, 700 inputs, 64 hidden, 20 out
+SHD_T, SHD_B, SHD_IN, SHD_H, SHD_OUT, SHD_K = 32, 64, 700, 64, 20, 4
+# ECG: the QTDB sequence length, a small batch, 64 recurrent ALIF neurons
+ECG_T, ECG_B, ECG_H, ECG_OUT = 1301, 8, 64, 6
+# plastic window: 64 -> 32 pair-STDP edge (K = 2 term planes), batch 8
+PL_T, PL_B, PL_IN, PL_H = 32, 8, 64, 32
+# 10^5-neuron banded topology (band 32) at the default 128x128 gather blocks
+TOPO_N, TOPO_M, TOPO_BLK = 100_000, 32, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_dispatch(monkeypatch):
+    """Steer dispatch as it runs on the chip: compiled Pallas kernels, the
+    spec-default blocks (the checked-in tuning cache holds CPU entries
+    only, which the chip never sees)."""
+    from repro.kernels import tuning
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(tuning, "lookup_tuned", lambda *a, **k: None)
+
+
+def _compile(fn, shapes, one_chip):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return compiled
+
+
+# (family, width tag, operand shapes, static kwargs for spec.pallas)
+KERNEL_CASES = [
+    ("spikemm", "shd_hidden",
+     [((SHD_T * SHD_B, SHD_IN), f32), ((SHD_IN, SHD_H), f32)], {}),
+    ("spikemm", "shd_branches",
+     [((SHD_T * SHD_B, SHD_IN), f32), ((SHD_IN, SHD_K * SHD_H), f32)], {}),
+    ("spikemm", "shd_readout",
+     [((SHD_T * SHD_B, SHD_H), f32), ((SHD_H, SHD_OUT), f32)], {}),
+    ("lif", "shd",
+     [((SHD_T, SHD_B, SHD_H), f32), ((SHD_H,), f32), ((SHD_B, SHD_H), f32)],
+     {"v_th": 1.0, "reset": "zero"}),
+    ("alif", "shd",
+     [((SHD_T, SHD_B, SHD_H), f32), ((SHD_H,), f32), ((SHD_H,), f32),
+      ((SHD_B, SHD_H), f32), ((SHD_B, SHD_H), f32)], {}),
+    ("linrec", "shd_readout",
+     [((SHD_T, SHD_B, SHD_OUT), f32)] * 2 + [((SHD_B, SHD_OUT), f32)], {}),
+    ("linrec", "shd_branches",
+     [((SHD_T, SHD_B * SHD_K, SHD_H), f32)] * 2
+     + [((SHD_B * SHD_K, SHD_H), f32)], {}),
+    ("lifrec", "ecg",
+     [((ECG_T, ECG_B, ECG_H), f32), ((ECG_H, ECG_H), f32), ((ECG_H,), f32),
+      ((ECG_B, ECG_H), f32), ((ECG_B, ECG_H), f32)], {}),
+    ("alifrec", "ecg",
+     [((ECG_T, ECG_B, ECG_H), f32), ((ECG_H, ECG_H), f32), ((ECG_H,), f32),
+      ((ECG_H,), f32), ((ECG_B, ECG_H), f32), ((ECG_B, ECG_H), f32),
+      ((ECG_B, ECG_H), f32)], {}),
+    ("linrec", "ecg_readout",
+     [((ECG_T, ECG_B, ECG_OUT), f32)] * 2 + [((ECG_B, ECG_OUT), f32)], {}),
+    ("stdp_seq", "plastic",
+     [((2, PL_T * PL_B, PL_IN), f32), ((2, PL_T * PL_B, PL_H), f32),
+      ((PL_IN, PL_H), f32)],
+     {"amps": (0.01, -0.012), "w_min": -1.0, "w_max": 1.0, "batch": PL_B}),
+    ("linrec", "plastic_traces",
+     [((PL_T, PL_B, PL_IN), f32)] * 2 + [((PL_B, PL_IN), f32)], {}),
+]
+
+
+@pytest.mark.parametrize(
+    "family,width,shapes,static", KERNEL_CASES,
+    ids=[f"{c[0]}-{c[1]}" for c in KERNEL_CASES])
+def test_kernel_compiles_for_v5e(family, width, shapes, static, one_chip):
+    spec = registry.get(family)
+    dims = spec.dims_of(*[jax.ShapeDtypeStruct(s, d) for s, d in shapes])
+    blocks = spec.resolve_blocks(dims, use_cache=False)
+    _compile(lambda *a: spec.pallas(*a, blocks=blocks, interpret=False,
+                                    **static), shapes, one_chip)
+
+
+def test_spikemm_sparse_channel_compiles_for_v5e(one_chip, chip_dispatch,
+                                                 monkeypatch):
+    """The block-sparse channel under jit (capacity-padded entry list)."""
+    from repro.kernels.spikemm.ops import spikemm
+    monkeypatch.setenv("REPRO_SPIKEMM_SPARSE", "always")
+    _compile(lambda s, w: spikemm(s, w),
+             [((SHD_T * SHD_B, SHD_IN), f32), ((SHD_IN, SHD_H), f32)],
+             one_chip)
+
+
+def test_spikemm_gather_compiles_for_v5e_at_default_blocks(one_chip):
+    """The 10^5-neuron banded topology's gather kernel: the tables are
+    operands, so their shapes alone (E occupied 128x128 blocks) suffice."""
+    from repro.kernels.spikemm import gather
+    nb = -(-TOPO_N // TOPO_BLK)
+    E = 3 * nb - 2                   # band 32 < 128: own block +- one
+    blocks = registry.get("spikemm_gather").resolve_blocks(
+        {"M": TOPO_M}, use_cache=False)
+    i32 = jnp.int32
+    _compile(lambda jj, kk, act, s, w: gather._gather_pallas(
+                 jj, kk, act, s, w, bm=blocks["bm"], bk=TOPO_BLK,
+                 bn=TOPO_BLK, jb=nb),
+             [((E,), i32), ((E,), i32), ((E,), i32),
+              ((TOPO_M, nb * TOPO_BLK), f32), ((E, TOPO_BLK, TOPO_BLK), f32)],
+             one_chip)
+
+
+@pytest.mark.parametrize("dendritic", [False, True],
+                         ids=["lif", "dhlif"])
+def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
+                                              chip_dispatch):
+    """The whole jitted cohort step the SHD server runs every window."""
+    from repro.core import plan as plan_mod
+    from repro.core.snn_layers import make_dhsnn_shd
+    from repro.serve import EngineConfig, make_engine
+    from repro.serve.engine import _resident_step
+
+    nodes, params = make_dhsnn_shd(jax.random.PRNGKey(0), n_in=SHD_IN,
+                                   n_hidden=SHD_H, n_out=SHD_OUT,
+                                   dendritic=dendritic)
+    eng = make_engine(nodes, params,
+                      EngineConfig(window=SHD_T, capacity=SHD_B))
+    assert not [s for s in eng.plan.segments
+                if s.kind == plan_mod.FALLBACK], eng.plan.describe()
+    step = _resident_step(eng.nodes, eng.plan, "flat", None)
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one_chip), tree)
+
+    states = abstract((eng._zero_core,) * SHD_B)
+    x = jax.ShapeDtypeStruct((SHD_T, SHD_B, SHD_IN), f32, sharding=one_chip)
+    compiled = step.lower(abstract(params), states, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3, "spikemm, lif, linrec kernels"

@@ -19,7 +19,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.sharding.pipeline import microbatch, pipeline_apply, pipeline_loss_fn
 
 S, M, mb, D = 4, 8, 4, 16
-mesh = jax.make_mesh((S, 2), ("stage", "data"))
+mesh = jax.make_mesh((S, 2), ("stage", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 key = jax.random.PRNGKey(0)
 Ws = 0.3 * jax.random.normal(key, (S, D, D))

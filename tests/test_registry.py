@@ -45,12 +45,76 @@ def test_parity_check_all_covers_every_kernel():
     assert set(reports) == set(ALL_KERNELS)
 
 
-@pytest.mark.tpu
-@pytest.mark.parametrize("name", ALL_KERNELS)
-def test_parity_real_mosaic(name):
-    """Same harness, real compiled kernels (auto-skipped off-TPU)."""
+def test_interpret_mode_policy(monkeypatch):
+    """Off-TPU, interpret mode is on unless REPRO_PALLAS_INTERPRET=0 asks
+    for the compiled kernels (to compile them for a described chip)."""
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert registry.interpret_mode()
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     assert not registry.interpret_mode()
-    parity.check_kernel(name)
+
+
+def _lif_args():
+    return registry.get("lif").make_inputs(jax.random.PRNGKey(0))
+
+
+def test_compiled_dispatch_failure_is_fatal(monkeypatch):
+    """With compiled kernels (as on a chip) a failing Pallas call raises:
+    it never reaches the reference, even without REPRO_STRICT. On the CPU
+    backend the compiled Mosaic call itself is what fails."""
+    from repro.kernels.incidents import incidents
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.delenv("REPRO_STRICT", raising=False)
+    with pytest.raises(registry.FallbackError, match="lif"):
+        registry.dispatch("lif", _lif_args())
+    assert [e.stage for e in incidents(kind="dispatch")] == ["pallas"]
+
+
+def test_compiled_dispatch_vmem_rejection_is_fatal(monkeypatch):
+    from repro.kernels.incidents import incidents
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.setenv("REPRO_VMEM_LIMIT_MB", "0.001")
+    monkeypatch.delenv("REPRO_STRICT", raising=False)
+    with pytest.raises(registry.FallbackError, match="exceeds budget"):
+        registry.dispatch("lif", _lif_args())
+    assert len(incidents(kind="vmem")) == 1
+
+
+def test_interpret_dispatch_failure_still_degrades(monkeypatch):
+    """Interpret mode (off-TPU) keeps the degrade-to-reference chain."""
+    from repro.core import faults
+    from repro.kernels.incidents import incidents
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("REPRO_STRICT", raising=False)
+    args = _lif_args()
+    with faults.inject("compile_fail:kernels=lif,p=1"):
+        s, vT = registry.dispatch("lif", args)
+    s_ref, v_ref = registry.get("lif").ref(*args)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s_ref))
+    assert [e.stage for e in incidents(kind="dispatch")] == ["pallas"]
+
+
+def test_gather_refuses_lane_misaligned_tables():
+    """Compiled gather calls refuse table blocks the TPU's 128-lane tiling
+    cannot take; interpret mode still runs them."""
+    from repro.kernels.spikemm import gather
+    rng = np.random.default_rng(0)
+    pre = rng.integers(0, 300, 500)
+    post = rng.integers(0, 200, 500)
+    tables = gather.build_gather_tables(pre, post, np.ones(500), 300, 200,
+                                        bk=32, bn=32)
+    spikes = jnp.ones((8, 300), jnp.float32)
+    with pytest.raises(ValueError, match="128-lane"):
+        gather._pallas_impl(spikes, tables, blocks={"bm": 8},
+                            interpret=False)
+    out = gather._pallas_impl(spikes, tables, blocks={"bm": 8},
+                              interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(gather._ref_impl(spikes, tables)),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_ops_files_have_no_direct_dispatch_logic():
