@@ -519,12 +519,11 @@ def _is_spiking(node: events.LayerNode) -> bool:
         return False
 
 
-def _run_fused(node: events.LayerNode, kind: str, lower: str,
-               params: Dict[str, Any], outs: Dict[str, Array],
-               state: Dict[str, Any], new_state: Dict[str, Any],
-               T: int, B: int,
-               gcfg: guards.GuardConfig = guards.GuardConfig()) -> None:
-    cur = _hoisted_current(node, params, outs, state, T, B)
+def _fire(node: events.LayerNode, kind: str, lower: str,
+          params: Dict[str, Any], state: Dict[str, Any], cur: Array,
+          T: int, B: int) -> Tuple[Array, Dict[str, Array]]:
+    """All-T FIRE of one fused node on its hoisted current: (output
+    train, new neuron state)."""
     prog = node.neuron.program
     nparams = params.get(node.name, {}).get("neuron")
     sur, alpha = node.neuron.surrogate, node.neuron.alpha
@@ -583,6 +582,20 @@ def _run_fused(node: events.LayerNode, kind: str, lower: str,
         ns = {mem.name: vT, br.name: dT.reshape(B, K, N)}
     else:  # pragma: no cover - compile_program only emits known families
         raise ValueError(f"unknown FIRE lowering {lower!r}")
+    return out, ns
+
+
+def _run_fused(node: events.LayerNode, kind: str, lower: str,
+               params: Dict[str, Any], outs: Dict[str, Array],
+               state: Dict[str, Any], new_state: Dict[str, Any],
+               T: int, B: int,
+               gcfg: guards.GuardConfig = guards.GuardConfig()) -> None:
+    # named scopes tag each HLO op of the fused node with its stage in a
+    # profiler trace and in compiled HLO metadata (op_name)
+    with jax.named_scope("integ"):
+        cur = _hoisted_current(node, params, outs, state, T, B)
+    with jax.named_scope("fire"):
+        out, ns = _fire(node, kind, lower, params, state, cur, T, B)
 
     # dead/stuck-row faults: the mask is time-independent, so masking the
     # full (T, B, N) train here equals the stepper's per-step masking for

@@ -95,6 +95,7 @@ def alif_pallas(current: jax.Array, tau: jax.Array, rho: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32),
                         pltpu.VMEM((bb, bn), jnp.float32)],
+        name="alif_pallas",
         interpret=interpret,
     )(current, tau.reshape(1, N), rho.reshape(1, N), v0, a0)
 
@@ -179,5 +180,6 @@ def alifrec_pallas(current: jax.Array, w_rec: jax.Array, tau: jax.Array,
         scratch_shapes=[pltpu.VMEM((bb, N), jnp.float32),
                         pltpu.VMEM((bb, N), jnp.float32),
                         pltpu.VMEM((bb, N), jnp.float32)],
+        name="alifrec_pallas",
         interpret=interpret,
     )(current, w_rec, tau.reshape(1, N), rho.reshape(1, N), v0, a0, s0)

@@ -123,5 +123,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
+        name="flash_attention_pallas",
         interpret=interpret,
     )(q, k, v)

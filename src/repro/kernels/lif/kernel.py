@@ -83,5 +83,6 @@ def lif_pallas(current: jax.Array, tau: jax.Array, v0: jax.Array, *,
             jax.ShapeDtypeStruct((B, N), current.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="lif_pallas",
         interpret=interpret,
     )(current, tau2, v0)

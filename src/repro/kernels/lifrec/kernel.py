@@ -93,5 +93,6 @@ def lifrec_pallas(current: jax.Array, w_rec: jax.Array, tau: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((bb, N), jnp.float32),
                         pltpu.VMEM((bb, N), jnp.float32)],
+        name="lifrec_pallas",
         interpret=interpret,
     )(current, w_rec, tau2, v0, s0)

@@ -96,5 +96,6 @@ def linrec_pallas(a: jax.Array, x: jax.Array, h0: jax.Array, *,
             jax.ShapeDtypeStruct((B, D), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bb, bd), jnp.float32)],
+        name="linrec_pallas",
         interpret=interpret,
     )(a, x, h0)

@@ -220,6 +220,7 @@ def _gather_pallas(jj, kk, act, spikes, wblk, *, bm, bk, bn, jb,
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, jb * bn), spikes.dtype),
+        name="_gather_pallas",
         interpret=interpret,
     )(jj, kk, act, spikes, wblk)
 

@@ -76,5 +76,6 @@ def spikemm_pallas(flags: jax.Array, spikes: jax.Array, w: jax.Array, *,
         _spikemm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), spikes.dtype),
+        name="spikemm_pallas",
         interpret=interpret,
     )(flags.reshape(-1).astype(jnp.int32), spikes, w)

@@ -132,6 +132,7 @@ def spikemm_sparse_pallas(idx_i: jax.Array, idx_k: jax.Array,
         _sparse_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), spikes.dtype),
+        name="spikemm_sparse_pallas",
         interpret=interpret,
     )(idx_i, idx_k, active, spikes, w)
 

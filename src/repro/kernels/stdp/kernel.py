@@ -103,6 +103,7 @@ def stdp_seq_pallas(P: jax.Array, Q: jax.Array, w: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), w.dtype),
+        name="stdp_seq_pallas",
         interpret=interpret,
     )(P.reshape(K, T, batch, M), Q.reshape(K, T, batch, N), w)
 
@@ -132,5 +133,6 @@ def stdp_pallas(x_pre: jax.Array, s_post: jax.Array, s_pre: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), w.dtype),
+        name="stdp_pallas",
         interpret=interpret,
     )(x_pre, s_post, s_pre, x_post, w)

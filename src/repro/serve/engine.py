@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -56,7 +55,7 @@ import numpy as np
 
 from repro.core import events, faults, plasticity
 from repro.core import plan as plan_mod
-from repro.serve.metrics import ServeMetrics
+from repro.serve.metrics import ServeMetrics, span
 from repro.serve.scheduler import Scheduler
 from repro.serve.sessions import Session, StateCache
 
@@ -131,16 +130,20 @@ def _resident_step(nodes, compiled, kind: str,
     # O(C x leaves) host-side slice ops per window.
     if kind == "flat":
         def step(params, states, x):
-            packed = plan_mod.pack_states(list(states))
+            with jax.named_scope("pack"):
+                packed = plan_mod.pack_states(list(states))
             ns, out, _ = plan_mod.run(nodes, params, x, state=packed,
                                       plan=compiled, learn=False,
                                       guard=guard)
-            return tuple(plan_mod.unpack_state(ns, i)
-                         for i in range(len(states))), out
+            with jax.named_scope("unpack"):
+                return tuple(plan_mod.unpack_state(ns, i)
+                             for i in range(len(states))), out
         fn = jax.jit(step)
     elif kind == "vmap_learn":
         def step(params, states, x):
-            st = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *states)
+            with jax.named_scope("pack"):
+                st = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
+                                            *states)
 
             def one(st_i, x_i):
                 # chunked-online entry weights = the session's last
@@ -151,8 +154,9 @@ def _resident_step(nodes, compiled, kind: str,
                                           guard=guard)
                 return ns, out
             ns, out = jax.vmap(one)(st, x)
-            return tuple(jax.tree_util.tree_map(lambda l, i=i: l[i], ns)
-                         for i in range(len(states))), out
+            with jax.named_scope("unpack"):
+                return tuple(jax.tree_util.tree_map(lambda l, i=i: l[i], ns)
+                             for i in range(len(states))), out
         fn = jax.jit(step)
     else:  # pragma: no cover
         raise ValueError(f"unknown step kind {kind!r}")
@@ -210,6 +214,7 @@ class BatchedEngine:
         self.cache = StateCache(cfg.cache_bytes, metrics=self.metrics)
         self._learn = cfg.learn and bool(self.plan.plastic)
         self._sid_counter = 0
+        self._step_id = -1          # index of the current (or last) step()
         # zero template for padding free cohort slots (results discarded)
         tmpl = events.init_state(self.nodes, 1, self.dtype, params)
         self._zero_full = jax.tree_util.tree_map(jnp.zeros_like, tmpl)
@@ -232,9 +237,10 @@ class BatchedEngine:
         if sid is None:
             sid = f"s{self._sid_counter}"
             self._sid_counter += 1
-        self.scheduler.open(sid)
-        state = events.init_state(self.nodes, 1, self.dtype, self.params)
-        self.cache.put(sid, state)
+        with span("serve.open", sid=sid):
+            self.scheduler.open(sid)
+            state = events.init_state(self.nodes, 1, self.dtype, self.params)
+            self.cache.put(sid, state)
         return sid
 
     def submit(self, sid: str, chunk: np.ndarray) -> bool:
@@ -270,24 +276,39 @@ class BatchedEngine:
     # -- execution ----------------------------------------------------------
 
     def step(self) -> int:
-        """Run one cohort window; returns the number of sessions served."""
-        self.metrics.queue_depth.observe(self.scheduler.ready_count)
-        cohort = self.scheduler.next_cohort(self.cfg.capacity)
+        """Run one cohort window; returns the number of sessions served.
+
+        Spanned (`repro.serve.metrics.span`) as `serve.step`, with the
+        children `serve.schedule`, `serve.gather`, `serve.assemble`,
+        `serve.dispatch`, `serve.readback` and `serve.scatter` in that
+        order, all carrying the step's index as `step`."""
+        self._step_id += 1
+        i, C = self._step_id, self.cfg.capacity
+        with span("serve.step", step=i) as sp:
+            self.metrics.queue_depth.observe(self.scheduler.ready_count)
+            with span("serve.schedule", step=i):
+                cohort = self.scheduler.next_cohort(C)
+            sp.ids.update(sessions=len(cohort), slots=C,
+                          steps=sum(v for _, _, v in cohort))
+            if cohort:
+                with span("serve.gather", step=i):
+                    states = [self.cache.get(s.sid) for s, _, _ in cohort]
+                new_states, outs = self._run_cohort(cohort, states)
+                with span("serve.scatter", step=i):
+                    for (s, _, valid), ns, out in zip(cohort, new_states,
+                                                      outs):
+                        self.cache.put(s.sid, ns)
+                        s.outputs.append(np.asarray(out[:valid]))
+                    self.metrics.bump("windows_run")
+                    self.metrics.bump("session_windows", len(cohort))
+                    self.metrics.bump("steps_run", sp.ids["steps"])
+                    # the replaced states' device buffers are released
+                    # here, so the span times their release
+                    del states, new_states, outs
         if not cohort:
             return 0
-        t0 = time.perf_counter()
-        states = [self.cache.get(s.sid) for s, _, _ in cohort]
-        new_states, outs = self._run_cohort(cohort, states)
-        for (s, _, valid), ns in zip(cohort, new_states):
-            self.cache.put(s.sid, ns)
-        for (s, _, valid), out in zip(cohort, outs):
-            s.outputs.append(np.asarray(out[:valid]))
-        dt = time.perf_counter() - t0
-        self.metrics.bump("windows_run")
-        self.metrics.bump("session_windows", len(cohort))
-        self.metrics.bump("steps_run", sum(v for _, _, v in cohort))
-        self.metrics.window_latency_s.observe(dt)
-        self.metrics.occupancy.observe(len(cohort) / self.cfg.capacity)
+        self.metrics.window_latency_s.observe(sp.seconds)
+        self.metrics.occupancy.observe(len(cohort) / C)
         return len(cohort)
 
     def drain(self) -> int:
@@ -306,40 +327,49 @@ class BatchedEngine:
                     sessions_open=len(self.scheduler.sessions))
         return snap
 
-    def publish_metrics(self) -> None:
-        """Snapshot onto the incident log (kind="serve", stage="metrics")."""
-        self.metrics.publish(family=self.kind)
-
     # cohort execution — the part engines differ in ------------------------
 
     def _run_cohort(self, cohort: List[Tuple[Session, np.ndarray, int]],
                     states: List[Dict[str, Any]]
                     ) -> Tuple[List[Dict[str, Any]], List[np.ndarray]]:
-        C, W = self.cfg.capacity, self.cfg.window
+        """One resident-step launch for the cohort: (new states, outputs),
+        one per session."""
+        C, W, i = self.cfg.capacity, self.cfg.window, self._step_id
         n_live = len(cohort)
-        if self._learn:
-            # per-session vmap: every lane owns its learned weight planes
-            sts = tuple(states) + (C - n_live) * (self._zero_full,)
-            x = np.zeros((C, W, 1, self.n_in), self.dtype)
-            for i, (_, xw, _) in enumerate(cohort):
-                x[i, :, 0, :] = xw
-            step = _resident_step(self.nodes, self.plan, "vmap_learn",
+        with span("serve.assemble", step=i):
+            pad = C - n_live
+            if self._learn:
+                # per-session vmap: every lane owns its learned weight planes
+                sts, syns = tuple(states) + pad * (self._zero_full,), None
+                x = np.zeros((C, W, 1, self.n_in), self.dtype)
+                for k, (_, xw, _) in enumerate(cohort):
+                    x[k, :, 0, :] = xw
+            else:
+                # flat path: sessions concatenated along the batch axis;
+                # each session's syn entries wait aside for the new core
+                cores, syns = zip(*(_split_syn(s) for s in states))
+                sts = tuple(cores) + pad * (self._zero_core,)
+                x = np.zeros((W, C, self.n_in), self.dtype)
+                for k, (_, xw, _) in enumerate(cohort):
+                    x[:, k, :] = xw
+            x = jnp.asarray(x)
+        with span("serve.dispatch", step=i):
+            step = _resident_step(self.nodes, self.plan,
+                                  "vmap_learn" if self._learn else "flat",
                                   self.cfg.guard)
-            ns, out = step(self.params, sts, jnp.asarray(x))
+            ns, out = step(self.params, sts, x)
+        with span("serve.readback", step=i):
             out_np = np.asarray(out)            # one transfer per window
-            return list(ns[:n_live]), [out_np[i, :, 0, :]
-                                       for i in range(n_live)]
-        # flat path: sessions concatenated along the batch axis
-        cores, syns = zip(*(_split_syn(s) for s in states))
-        sts = tuple(cores) + (C - n_live) * (self._zero_core,)
-        x = np.zeros((W, C, self.n_in), self.dtype)
-        for i, (_, xw, _) in enumerate(cohort):
-            x[:, i, :] = xw
-        step = _resident_step(self.nodes, self.plan, "flat", self.cfg.guard)
-        ns, out = step(self.params, sts, jnp.asarray(x))
-        out_np = np.asarray(out)                # one transfer per window
-        news = [_merge_syn(ns[i], syn) for i, syn in enumerate(syns)]
-        return news, [out_np[:, i, :] for i in range(n_live)]
+            if self._learn:
+                news = list(ns[:n_live])
+                outs = [out_np[k, :, 0, :] for k in range(n_live)]
+            else:
+                news = [_merge_syn(ns[k], syn) for k, syn in enumerate(syns)]
+                outs = [out_np[:, k, :] for k in range(n_live)]
+            # the input, the output and the free slots' states leave the
+            # device here, so the span times their release
+            del x, out, ns, sts
+        return news, outs
 
 
 class NaiveEngine(BatchedEngine):

@@ -26,13 +26,14 @@ step never retraces, while staying fair and bounded:
 from __future__ import annotations
 
 import math
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.kernels.incidents import FallbackEvent, record
-from repro.serve.metrics import ServeMetrics
+from repro.serve.metrics import ServeMetrics, record as record_span
 from repro.serve.sessions import Session
 
 
@@ -103,8 +104,12 @@ class Scheduler:
 
     # -- cohort assembly ----------------------------------------------------
 
-    def _requeue(self, sid: str) -> None:
-        if sid not in self._queued and self.sessions[sid].ready(self.window):
+    def _requeue(self, sid: str, now_ns: Optional[int] = None) -> None:
+        """Queue `sid` if it has a runnable window and is not queued; the
+        window's wait (`serve.queue`) starts now, or at `now_ns`."""
+        s = self.sessions[sid]
+        if sid not in self._queued and s.ready(self.window):
+            s.ready_ns = time.perf_counter_ns() if now_ns is None else now_ns
             self._ready.append(sid)
             self._queued.add(sid)
 
@@ -116,26 +121,31 @@ class Scheduler:
                     ) -> List[Tuple[Session, np.ndarray, int]]:
         """Pop up to `capacity` ready sessions (FIFO) with their window
         inputs: [(session, x (window, n_in), valid_steps)]. Served
-        sessions that remain ready re-enter at the tail (fair round-robin);
-        a closed session whose buffer drains is marked finished."""
+        sessions that remain ready re-enter at the tail (fair round-robin),
+        their next window runnable from this pop; a closed session whose
+        buffer drains is marked finished. Each popped window's wait goes
+        to the span log as `serve.queue`."""
         out: List[Tuple[Session, np.ndarray, int]] = []
-        served: List[str] = []
+        served: List[Tuple[str, int]] = []
         while self._ready and len(out) < capacity:
             sid = self._ready.popleft()
             self._queued.discard(sid)
             s = self.sessions[sid]
             if not s.ready(self.window):
                 continue                      # stale queue entry
+            now = time.perf_counter_ns()
+            record_span("serve.queue", s.ready_ns, now, sid=sid,
+                        window=s.windows)
             x, valid = s.pop_window(self.window)
             s.windows += 1
             s.steps += valid
             out.append((s, x, valid))
-            served.append(sid)
+            served.append((sid, now))
             if s.closed and s.buffered == 0:
                 s.finished = True
                 self.metrics.bump("sessions_finished")
-        for sid in served:
-            self._requeue(sid)
+        for sid, popped in served:
+            self._requeue(sid, popped)
         return out
 
 

