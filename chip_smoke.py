@@ -280,7 +280,8 @@ def serve_and_check(label, nodes, params, n_sessions, steps, rng) -> dict:
     require(np.isfinite(out).all(), f"{label}: non-finite served output")
 
     step = _resident_step(eng.nodes, eng.plan, "flat", eng.cfg.guard)
-    hlo = step.lower(params, (eng._zero_core,) * CAPACITY,
+    hlo = step.lower(params, eng.pool.leaves,
+                     np.zeros(CAPACITY, np.int32), np.ones(CAPACITY, bool),
                      jnp.zeros((WINDOW, CAPACITY, SHD["n_in"]),
                                jnp.float32)).as_text()
     assert_kernels_in(hlo, label)

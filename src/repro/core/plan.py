@@ -657,82 +657,31 @@ def _run_fallback(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
 
 
 # ---------------------------------------------------------------------------
-# session-state pack/unpack (the serve engine's gather/scatter primitives)
+# session-state layout (the serve engine's state pool builds on it)
 # ---------------------------------------------------------------------------
 #
 # A `plan.run` state tree is {node: {key: array}} where every per-neuron
 # leaf carries the batch on axis 0 — except the delay ring, whose layout is
-# (depth, batch, n). The serve engine (repro.serve) multiplexes many
-# batch-1 streaming sessions through ONE resident jitted window step by
-# concatenating their states into cohort slots along the batch axis and
-# slicing them back out on window boundaries; these helpers are the
-# batch-axis-aware primitives it builds on. Synapse ("syn:") entries are
-# deliberately rejected: their weight plane has NO batch axis (one tile
-# per connection, batch-summed updates), so packing sessions that learn
-# would alias their weights — the engine keeps those per-session and runs
-# the learning path vmapped instead.
+# (depth, batch, n). The serve engine (repro.serve) keeps many batch-1
+# streaming sessions in one device-resident pool per leaf, a row per
+# session along this batch axis, and gathers a cohort's rows into one
+# batched state inside its resident step. Synapse ("syn:") entries stay
+# out of the pool: their weight plane has NO batch axis (one tile per
+# connection, batch-summed updates), so batching sessions that learn would
+# alias their weights — the engine keeps those per-session and runs the
+# learning path vmapped instead.
 
 
-def _state_batch_axis(key: str) -> int:
+def state_batch_axis(key: str) -> int:
     return 1 if key == "ring" else 0
 
 
 def state_nbytes(state: Dict[str, Any]) -> int:
     """Total bytes of one state tree — the per-session footprint the serve
-    cache budgets against (syn entries included: they are carried per
-    session even though they never enter a packed cohort)."""
+    cache budgets against (syn entries included: the learning path carries
+    them per session)."""
     return sum(int(v.size) * v.dtype.itemsize if hasattr(v, "dtype") else 0
                for v in jax.tree_util.tree_leaves(state))
-
-
-def pack_states(states: List[Dict[str, Any]], pad_to: Optional[int] = None
-                ) -> Dict[str, Any]:
-    """Concatenate per-session state trees into one cohort state.
-
-    Every leaf joins along its batch axis (axis 0; axis 1 for delay
-    rings); `pad_to` right-pads the cohort with zero slots up to a fixed
-    capacity so the resident jitted step never retraces. Raises on
-    "syn:" entries — see the module note above.
-    """
-    if not states:
-        raise ValueError("pack_states needs at least one state")
-    total = sum(next(iter(s.values()))["out"].shape[0] for s in states)
-    pad = 0 if pad_to is None else pad_to - total
-    if pad < 0:
-        raise ValueError(f"pack_states: {total} batch rows exceed "
-                         f"pad_to={pad_to}")
-    out: Dict[str, Any] = {}
-    for node in states[0]:
-        nd: Dict[str, Any] = {}
-        for k in states[0][node]:
-            if k.startswith("syn:"):
-                raise ValueError(
-                    f"pack_states: node {node!r} carries synapse state "
-                    f"{k!r}, which has no batch axis; keep syn entries "
-                    "per-session (see repro.serve)")
-            ax = _state_batch_axis(k)
-            parts = [s[node][k] for s in states]
-            if pad:
-                shape = list(parts[0].shape)
-                shape[ax] = pad
-                parts.append(jnp.zeros(tuple(shape), parts[0].dtype))
-            nd[k] = jnp.concatenate(parts, axis=ax)
-        out[node] = nd
-    return out
-
-
-def unpack_state(state: Dict[str, Any], index: int,
-                 width: int = 1) -> Dict[str, Any]:
-    """Slice one session (batch rows [index, index+width)) back out of a
-    packed cohort state — the exact inverse of its `pack_states` slot, so
-    gather -> run -> scatter round-trips are bit-identical."""
-    out: Dict[str, Any] = {}
-    for node, nd in state.items():
-        out[node] = {
-            k: (v[:, index:index + width] if _state_batch_axis(k) == 1
-                else v[index:index + width])
-            for k, v in nd.items()}
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -993,7 +942,7 @@ def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
 __all__ = ["Plan", "PlasticLower", "Segment", "compile_program",
            "engine_mode", "check_mode", "run", "run_stream",
            "CROSS_ENGINE_ATOL",
-           "state_nbytes", "pack_states", "unpack_state",
+           "state_nbytes", "state_batch_axis",
            "FUSED_FF", "FUSED_REC", "FALLBACK",
            "LOWER_LI", "LOWER_LIF", "LOWER_ALIF", "LOWER_DHLIF",
            "SYN_SEQ", "SYN_STEP"]

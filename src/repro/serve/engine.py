@@ -1,14 +1,19 @@
 """Multi-tenant streaming serve engine over one resident `plan.run` step.
 
 TaiBai amortizes one resident program across many concurrent spike
-streams; this is the software analogue. ONE compiled plan — jitted once
-per (window, capacity) shape — serves every open session: on each window
-boundary the scheduler packs whichever sessions have a runnable window
-into fixed cohort slots, the engine gathers their persistent state out of
-the LRU cache (`plan.pack_states`), runs the resident step, scatters the
-per-slot results back (`plan.unpack_state`), and retires/admits sessions
-for the next window. Nothing ever retraces: free slots are zero-padded
-and their results discarded.
+streams, keeping neuron state in the core and moving only spikes; this is
+the software analogue. ONE compiled plan — jitted once per (window,
+capacity, pool size) shape — serves every open session. Every hot
+session's state lives in a device-resident `StatePool` (one array per
+state leaf, a row per session); on each window boundary the scheduler
+packs whichever sessions have a runnable window into fixed cohort slots,
+the engine resolves them to pool rows through the LRU cache, and the
+resident step gathers those rows (fresh sessions read the initial-state
+row), runs `plan.run`, and scatters the new state back into the pool,
+which it takes donated and returns. Only the rows, a fresh mask, the
+input block and the output cross the call. Nothing retraces unless the
+pool grows: free slots point at a scratch row and their results are
+discarded.
 
 Two engines share the scheduler/cache/metrics machinery:
 
@@ -21,18 +26,20 @@ Two engines share the scheduler/cache/metrics machinery:
     its own state (entry weights come from the session's last published
     `syn:` tensors via `plasticity.apply_learned`, the chunked-online
     contract, per lane).
+    That path keeps a tuple of per-session state trees through its step
+    (and the cache keeps the trees); it shares no code with the pool.
   * `NaiveEngine` — the one-session-at-a-time baseline: same scheduler,
-    same cache, same semantics, but every served session pays its own
-    B=1 window launch. `bench_serving` measures the gap.
+    same cache and pool, same semantics, but every served session pays
+    its own one-slot window launch. `bench_serving` measures the gap.
 
 Isolation invariant (property-tested): a session's output trajectory and
 final state are bit-identical whether it runs alone, interleaved with
 strangers, or is evicted to host and restored mid-stream. The flat path
 earns this because every per-slot computation in the fused kernels is
 row-independent and the executable is shape-fixed (solo and packed
-cohorts run the *same* compiled step); the vmap path because lanes are
-independent by construction; evict/restore because spill is a pure
-device<->host copy.
+cohorts run the *same* compiled step, whichever pool rows they hold);
+the vmap path because lanes are independent by construction;
+evict/restore because spill is a pure device<->host copy.
 
 Resilience composes: kernel dispatch inside the resident step follows
 the registry's failure policy (off-TPU an interpret-mode failure degrades
@@ -57,7 +64,8 @@ from repro.core import events, faults, plasticity
 from repro.core import plan as plan_mod
 from repro.serve.metrics import ServeMetrics, span
 from repro.serve.scheduler import Scheduler
-from repro.serve.sessions import Session, StateCache
+from repro.serve.sessions import (SCRATCH, Row, Session, StateCache,
+                                  StatePool, gather_rows, scatter_rows)
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -123,22 +131,20 @@ def _resident_step(nodes, compiled, kind: str,
         return fn
     nodes = list(nodes)
 
-    # Both step kinds take a TUPLE of per-session state trees and return a
-    # tuple of per-session results: the gather (pack/stack) and scatter
-    # (per-slot slice) both happen INSIDE the compiled program, so a
-    # C-slot cohort costs one dispatch + one output transfer instead of
-    # O(C x leaves) host-side slice ops per window.
+    # "flat" takes the state pool (donated: updated in place) and the
+    # cohort's rows; its argument count does not grow with the capacity.
+    # "vmap_learn" takes a TUPLE of per-session state trees and returns a
+    # tuple of per-session results, stacked and sliced inside the program.
     if kind == "flat":
-        def step(params, states, x):
+        def step(params, pool, rows, fresh, x):
             with jax.named_scope("pack"):
-                packed = plan_mod.pack_states(list(states))
-            ns, out, _ = plan_mod.run(nodes, params, x, state=packed,
+                state = gather_rows(pool, rows, fresh)
+            ns, out, _ = plan_mod.run(nodes, params, x, state=state,
                                       plan=compiled, learn=False,
                                       guard=guard)
             with jax.named_scope("unpack"):
-                return tuple(plan_mod.unpack_state(ns, i)
-                             for i in range(len(states))), out
-        fn = jax.jit(step)
+                return scatter_rows(pool, rows, ns), out
+        fn = jax.jit(step, donate_argnums=1)
     elif kind == "vmap_learn":
         def step(params, states, x):
             with jax.named_scope("pack"):
@@ -185,6 +191,16 @@ def _merge_syn(core: Dict[str, Any], syn: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def _pool_operands(handles: List[Row], slots: int):
+    """The step's row and fresh vectors: session k in slot k, the free
+    slots on the scratch row, fresh (they read the initial state)."""
+    rows = np.full(slots, SCRATCH, np.int32)
+    fresh = np.ones(slots, bool)
+    for k, r in enumerate(handles):
+        rows[k], fresh[k] = r
+    return rows, fresh
+
+
 # ---------------------------------------------------------------------------
 # engines
 # ---------------------------------------------------------------------------
@@ -211,14 +227,21 @@ class BatchedEngine:
         self.scheduler = Scheduler(cfg.window, self.n_in,
                                    queue_limit=cfg.queue_limit,
                                    metrics=self.metrics)
-        self.cache = StateCache(cfg.cache_bytes, metrics=self.metrics)
         self._learn = cfg.learn and bool(self.plan.plastic)
         self._sid_counter = 0
         self._step_id = -1          # index of the current (or last) step()
-        # zero template for padding free cohort slots (results discarded)
-        tmpl = events.init_state(self.nodes, 1, self.dtype, params)
-        self._zero_full = jax.tree_util.tree_map(jnp.zeros_like, tmpl)
-        self._zero_core, _ = _split_syn(self._zero_full)
+        init = events.init_state(self.nodes, 1, self.dtype, params)
+        if self._learn:
+            # each session's tree lives in the cache; free cohort slots
+            # are zero-padded (results discarded)
+            self.pool = None
+            self._zero_full = jax.tree_util.tree_map(jnp.zeros_like, init)
+        else:
+            # synapse entries never change on the flat path: held once
+            core, self._syn = _split_syn(init)
+            self.pool = StatePool(core, cfg.capacity, metrics=self.metrics)
+        self.cache = StateCache(cfg.cache_bytes, metrics=self.metrics,
+                                store=self.pool)
 
     def _infer_n_in(self) -> int:
         for n in self.nodes:
@@ -239,7 +262,11 @@ class BatchedEngine:
             self._sid_counter += 1
         with span("serve.open", sid=sid):
             self.scheduler.open(sid)
-            state = events.init_state(self.nodes, 1, self.dtype, self.params)
+            if self.pool is None:
+                state = events.init_state(self.nodes, 1, self.dtype,
+                                          self.params)
+            else:
+                state = Row(self.pool.claim(), True)
             self.cache.put(sid, state)
         return sid
 
@@ -264,10 +291,14 @@ class BatchedEngine:
 
     def state_of(self, sid: str) -> Dict[str, Any]:
         """The session's current state tree (restored to device)."""
-        return self.cache.get(sid)
+        state = self.cache.get(sid)
+        if self.pool is None:
+            return state
+        return _merge_syn(self.pool.read(state), self._syn)
 
     def retire(self, sid: str) -> np.ndarray:
-        """Drop a finished (or abandoned) session; returns its outputs."""
+        """Drop a finished (or abandoned) session, freeing its state (its
+        pool row); returns its outputs."""
         out = self.outputs(sid)
         self.cache.drop(sid)
         self.scheduler.sessions.pop(sid, None)
@@ -291,19 +322,19 @@ class BatchedEngine:
             sp.ids.update(sessions=len(cohort), slots=C,
                           steps=sum(v for _, _, v in cohort))
             if cohort:
+                sids = [s.sid for s, _, _ in cohort]
                 with span("serve.gather", step=i):
-                    states = [self.cache.get(s.sid) for s, _, _ in cohort]
+                    states = self.cache.get_many(sids)
                 new_states, outs = self._run_cohort(cohort, states)
                 with span("serve.scatter", step=i):
-                    for (s, _, valid), ns, out in zip(cohort, new_states,
-                                                      outs):
-                        self.cache.put(s.sid, ns)
+                    self.cache.put_many(zip(sids, new_states))
+                    for (s, _, valid), out in zip(cohort, outs):
                         s.outputs.append(np.asarray(out[:valid]))
                     self.metrics.bump("windows_run")
                     self.metrics.bump("session_windows", len(cohort))
                     self.metrics.bump("steps_run", sp.ids["steps"])
-                    # the replaced states' device buffers are released
-                    # here, so the span times their release
+                    # the replaced states' device buffers (learning path)
+                    # are released here, so the span times their release
                     del states, new_states, outs
         if not cohort:
             return 0
@@ -324,48 +355,63 @@ class BatchedEngine:
                     capacity=self.cfg.capacity,
                     cache_hot_bytes=self.cache.hot_bytes,
                     cache_spilled=len(self.cache.spilled),
+                    pool_rows=self.pool.rows if self.pool else 0,
                     sessions_open=len(self.scheduler.sessions))
         return snap
 
     # cohort execution — the part engines differ in ------------------------
 
     def _run_cohort(self, cohort: List[Tuple[Session, np.ndarray, int]],
-                    states: List[Dict[str, Any]]
-                    ) -> Tuple[List[Dict[str, Any]], List[np.ndarray]]:
+                    states: List[Any]) -> Tuple[List[Any], List[np.ndarray]]:
         """One resident-step launch for the cohort: (new states, outputs),
-        one per session."""
+        one per session. A state is the session's pool `Row`, or on the
+        learning path its tree."""
+        if self._learn:
+            return self._run_learn(cohort, states)
         C, W, i = self.cfg.capacity, self.cfg.window, self._step_id
         n_live = len(cohort)
         with span("serve.assemble", step=i):
-            pad = C - n_live
-            if self._learn:
-                # per-session vmap: every lane owns its learned weight planes
-                sts, syns = tuple(states) + pad * (self._zero_full,), None
-                x = np.zeros((C, W, 1, self.n_in), self.dtype)
-                for k, (_, xw, _) in enumerate(cohort):
-                    x[k, :, 0, :] = xw
-            else:
-                # flat path: sessions concatenated along the batch axis;
-                # each session's syn entries wait aside for the new core
-                cores, syns = zip(*(_split_syn(s) for s in states))
-                sts = tuple(cores) + pad * (self._zero_core,)
-                x = np.zeros((W, C, self.n_in), self.dtype)
-                for k, (_, xw, _) in enumerate(cohort):
-                    x[:, k, :] = xw
+            rows, fresh = _pool_operands(states, C)
+            self.metrics.bump("fresh_resets", int(fresh[:n_live].sum()))
+            # sessions concatenated along the batch axis
+            x = np.zeros((W, C, self.n_in), self.dtype)
+            for k, (_, xw, _) in enumerate(cohort):
+                x[:, k, :] = xw
+            rows, fresh, x = jnp.asarray(rows), jnp.asarray(fresh), \
+                jnp.asarray(x)
+        with span("serve.dispatch", step=i):
+            step = _resident_step(self.nodes, self.plan, "flat",
+                                  self.cfg.guard)
+            self.pool.leaves, out = step(self.params, self.pool.leaves, rows,
+                                         fresh, x)
+        with span("serve.readback", step=i):
+            out_np = np.asarray(out)            # one transfer per window
+            outs = [out_np[:, k, :] for k in range(n_live)]
+            news = [Row(r.index, False) for r in states]
+            # the step's input and output leave the device here, so the
+            # span times their release
+            del x, out
+        return news, outs
+
+    def _run_learn(self, cohort, states):
+        """The learning path: a tuple of per-session state trees through
+        the per-session `vmap` step."""
+        C, W, i = self.cfg.capacity, self.cfg.window, self._step_id
+        n_live = len(cohort)
+        with span("serve.assemble", step=i):
+            sts = tuple(states) + (C - n_live) * (self._zero_full,)
+            x = np.zeros((C, W, 1, self.n_in), self.dtype)
+            for k, (_, xw, _) in enumerate(cohort):
+                x[k, :, 0, :] = xw
             x = jnp.asarray(x)
         with span("serve.dispatch", step=i):
-            step = _resident_step(self.nodes, self.plan,
-                                  "vmap_learn" if self._learn else "flat",
+            step = _resident_step(self.nodes, self.plan, "vmap_learn",
                                   self.cfg.guard)
             ns, out = step(self.params, sts, x)
         with span("serve.readback", step=i):
             out_np = np.asarray(out)            # one transfer per window
-            if self._learn:
-                news = list(ns[:n_live])
-                outs = [out_np[k, :, 0, :] for k in range(n_live)]
-            else:
-                news = [_merge_syn(ns[k], syn) for k, syn in enumerate(syns)]
-                outs = [out_np[:, k, :] for k in range(n_live)]
+            news = list(ns[:n_live])
+            outs = [out_np[k, :, 0, :] for k in range(n_live)]
             # the input, the output and the free slots' states leave the
             # device here, so the span times their release
             del x, out, ns, sts
@@ -381,7 +427,7 @@ class NaiveEngine(BatchedEngine):
 
     def _run_cohort(self, cohort, states):
         W = self.cfg.window
-        news: List[Dict[str, Any]] = []
+        news: List[Any] = []
         outs: List[np.ndarray] = []
         for (sess, xw, _), state in zip(cohort, states):
             if self._learn:
@@ -392,12 +438,15 @@ class NaiveEngine(BatchedEngine):
                 news.append(ns[0])
                 outs.append(np.asarray(out)[0, :, 0, :])
             else:
-                core, syn = _split_syn(state)
+                # a one-slot cohort through the pool
+                rows, fresh = _pool_operands([state], 1)
+                self.metrics.bump("fresh_resets", int(state.fresh))
                 x = jnp.asarray(xw, self.dtype).reshape(W, 1, self.n_in)
                 step = _resident_step(self.nodes, self.plan, "flat",
                                       self.cfg.guard)
-                ns, out = step(self.params, (core,), x)
-                news.append(_merge_syn(ns[0], syn))
+                self.pool.leaves, out = step(self.params, self.pool.leaves,
+                                             rows, fresh, x)
+                news.append(Row(state.index, False))
                 outs.append(np.asarray(out)[:, 0, :])
         return news, outs
 

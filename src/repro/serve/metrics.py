@@ -85,7 +85,9 @@ class ServeMetrics:
       sessions_opened/closed/finished, chunks_admitted, chunks_rejected
       (backpressure), windows_run, session_windows (slot-windows actually
       served), steps_run (timesteps x sessions), cache_hits/misses,
-      cache_evictions, cache_restores.
+      cache_evictions, cache_restores, pool_grows (state-pool doublings,
+      each a retrace of the step), fresh_resets (sessions whose first
+      window read the initial state inside the step).
     Histograms:
       window_latency_s   wall clock of one engine.step() that served a
                          cohort, scheduling included (its `serve.step`
@@ -106,6 +108,8 @@ class ServeMetrics:
     cache_misses: int = 0
     cache_evictions: int = 0
     cache_restores: int = 0
+    pool_grows: int = 0
+    fresh_resets: int = 0
     window_latency_s: Histogram = dataclasses.field(default_factory=Histogram)
     queue_depth: Histogram = dataclasses.field(default_factory=Histogram)
     occupancy: Histogram = dataclasses.field(default_factory=Histogram)

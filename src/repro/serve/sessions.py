@@ -1,33 +1,42 @@
-"""Stateful streaming sessions + the LRU state cache behind the engine.
+"""Stateful streaming sessions, the device-resident state pool, and the
+LRU state cache behind the engine.
 
 A `Session` is one tenant's streaming SNN run: an input buffer of
-not-yet-executed timesteps, an output trail, and — held separately in the
-`StateCache` — the persistent per-session neuron/synapse state tree that
-`plan.run` threads between windows. The cache is the multi-tenant memory
-story: hot sessions keep their state as device arrays ready to be packed
-into the next cohort; once the hot set exceeds the byte budget, the
-least-recently-used sessions are *spilled* to host memory (`numpy` copies)
-and restored bit-identically on readmission. Spill -> restore is a pure
+not-yet-executed timesteps, an output trail, and — held separately — the
+persistent per-session neuron state that `plan.run` threads between
+windows. On the engine's inference path that state lives in a
+`StatePool`: one device array per state leaf, a row per session, read and
+written only inside the resident step, so a cohort crosses the jit
+boundary as a row vector instead of one state tree per slot (TaiBai keeps
+neuron state resident in the core and moves only spikes). The learning
+path keeps one state tree per session instead.
+
+The `StateCache` is the multi-tenant memory story over either store: hot
+sessions keep their state on the device (a pool row, or a tree); once the
+hot set exceeds the byte budget, the least-recently-used sessions are
+*spilled* to host memory (`numpy` copies; a pool row is freed) and
+restored bit-identically on readmission. Spill -> restore is a pure
 device<->host copy of every leaf (no re-quantization, no re-init), so a
 session's trajectory is exactly the same whether it stayed resident or
 bounced through the cache — the invariant the isolation property tests
 pin down.
 
-Byte accounting uses `plan.state_nbytes` over the full state tree
-(synapse entries included: they travel with the session even though they
-never enter a packed cohort).
+Byte accounting uses `plan.state_nbytes`: a tree's whole footprint
+(synapse entries included), or a pool row's.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, NamedTuple,
+                    Optional, Sequence, Tuple)
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.core.plan import state_nbytes
+from repro.core.plan import state_batch_axis, state_nbytes
 from repro.serve.metrics import ServeMetrics
 
 
@@ -94,25 +103,171 @@ class Session:
         return self.buffered >= window or (self.closed and self.buffered > 0)
 
 
+# ---------------------------------------------------------------------------
+# the state pool
+# ---------------------------------------------------------------------------
+
+SCRATCH, INIT = 0, 1        # reserved pool rows: padded slots' writes, and
+                            # the initial state a fresh session reads
+
+
+class Row(NamedTuple):
+    """A session's place in the pool: its row, and whether it has yet to
+    run (a fresh session's row holds stale data; the step reads INIT)."""
+
+    index: int
+    fresh: bool
+
+
+def _map(fn: Callable, tree: Dict[str, Any], *rest: Dict[str, Any]
+         ) -> Dict[str, Any]:
+    """`fn(batch_axis, leaf, *same leaf of rest)` over a {node: {key: leaf}}
+    state tree."""
+    return {node: {k: fn(state_batch_axis(k), v, *(r[node][k] for r in rest))
+                   for k, v in nd.items()}
+            for node, nd in tree.items()}
+
+
+def gather_rows(leaves: Dict[str, Any], rows: jax.Array,
+                fresh: jax.Array) -> Dict[str, Any]:
+    """Inside the step: the cohort state, slot k taken from pool row
+    `rows[k]`, or from INIT where `fresh[k]`."""
+    src = jnp.where(fresh, INIT, rows)
+    # rows come from the free-list, so always in bounds: "clip" gathers
+    # with no out-of-bounds mask
+    return _map(lambda ax, v: jnp.take(v, src, axis=ax, mode="clip"), leaves)
+
+
+def scatter_rows(leaves: Dict[str, Any], rows: jax.Array,
+                 state: Dict[str, Any]) -> Dict[str, Any]:
+    """Inside the step: the pool with slot k of `state` written to row
+    `rows[k]` (padded slots all write SCRATCH)."""
+    return _map(lambda ax, v, s: (v.at[rows] if ax == 0 else v.at[:, rows])
+                .set(s), leaves, state)
+
+
+class StatePool:
+    """The device-resident state of every hot session (module docstring).
+
+    Built from a one-session state tree `init` (batch axis of size 1, no
+    synapse entries): each leaf becomes one array whose batch axis holds
+    `rows` rows, every row a copy of `init`. The host free-list hands
+    rows out (`claim`) and takes them back (`free`); rows SCRATCH and
+    INIT are never handed out. The pool starts at the power of two at or
+    above 4 x `capacity` rows and doubles whenever the free-list runs
+    dry; the step traces anew once per size (`pool_grows` counts them).
+    """
+
+    def __init__(self, init: Dict[str, Any], capacity: int,
+                 metrics: Optional[ServeMetrics] = None):
+        self.metrics = metrics or ServeMetrics()
+        self.row_bytes = state_nbytes(init)
+        self.rows = 1 << max(2, (4 * capacity - 1).bit_length())
+        self.leaves = _map(lambda ax, v: jnp.repeat(v, self.rows, axis=ax),
+                           init)
+        self._free = list(range(self.rows - 1, INIT, -1))
+
+    def claim(self) -> int:
+        if not self._free:
+            self._grow()
+        return self._free.pop()
+
+    def _grow(self) -> None:
+        def doubled(ax, v):
+            # built on the host: an eager device concatenate would compile
+            # anew for each leaf's new shape
+            h = np.asarray(v)
+            return jnp.asarray(np.concatenate([h, np.zeros_like(h)], axis=ax))
+
+        old = self.rows
+        self.leaves = _map(doubled, self.leaves)
+        self.rows = 2 * old
+        self._free = list(range(self.rows - 1, old - 1, -1))
+        self.metrics.bump("pool_grows")
+
+    def read(self, row: Row) -> Dict[str, Any]:
+        """The session's one-row state tree (INIT's if it is fresh)."""
+        i = INIT if row.fresh else row.index
+        return _map(lambda ax, v: jax.lax.slice_in_dim(v, i, i + 1, axis=ax),
+                    self.leaves)
+
+    def write(self, index: int, state: Dict[str, Any]) -> None:
+        """Put a one-row state tree (device or host) into row `index`."""
+        self.leaves = _map(
+            lambda ax, v, s: jax.lax.dynamic_update_slice_in_dim(
+                v, jnp.asarray(s, v.dtype), index, axis=ax),
+            self.leaves, state)
+
+    # the StateCache store protocol: a hot value is a Row ------------------
+
+    def nbytes(self, row: Row) -> int:
+        return self.row_bytes
+
+    def spill(self, row: Row) -> Optional[Dict[str, Any]]:
+        """Copy the row to host and free it (a fresh row copies nothing)."""
+        host = (None if row.fresh
+                else jax.tree_util.tree_map(np.asarray, self.read(row)))
+        self.free(row)
+        return host
+
+    def restore(self, host: Optional[Dict[str, Any]]) -> Row:
+        index = self.claim()
+        if host is None:
+            return Row(index, True)
+        self.write(index, host)
+        return Row(index, False)
+
+    def free(self, row: Row) -> None:
+        self._free.append(row.index)
+
+
+class _Trees:
+    """The StateCache store where each session keeps its own device tree
+    (the learning path)."""
+
+    nbytes = staticmethod(state_nbytes)
+
+    @staticmethod
+    def spill(state: Dict[str, Any]) -> Dict[str, Any]:
+        return jax.tree_util.tree_map(np.asarray, state)
+
+    @staticmethod
+    def restore(host: Dict[str, Any]) -> Dict[str, Any]:
+        return jax.tree_util.tree_map(jnp.asarray, host)
+
+    @staticmethod
+    def free(state: Dict[str, Any]) -> None:
+        pass
+
+
+# ---------------------------------------------------------------------------
+# the LRU cache
+# ---------------------------------------------------------------------------
+
+
 class StateCache:
     """LRU session-state cache with a hot-set byte budget.
 
     `put`/`get` move states in and out keyed by session id; every access
-    refreshes recency. When hot bytes exceed `budget_bytes`, the
-    least-recently-used entries spill to host (`numpy`) until the budget
-    holds again — `get` of a spilled entry restores it to device
-    bit-identically and counts a miss+restore. `budget_bytes=None` means
-    unbounded (nothing ever spills).
+    refreshes recency. A state is what the `store` keeps hot: a `Row` of
+    a `StatePool`, or (no store given) the session's own device tree.
+    When hot bytes exceed `budget_bytes`, the least-recently-used entries
+    spill to host (`numpy`) until the budget holds again — `get` of a
+    spilled entry restores it to device bit-identically and counts a
+    miss+restore. `budget_bytes=None` means unbounded (nothing ever
+    spills).
     """
 
     def __init__(self, budget_bytes: Optional[int] = None,
-                 metrics: Optional[ServeMetrics] = None):
+                 metrics: Optional[ServeMetrics] = None,
+                 store: Optional[StatePool] = None):
         if budget_bytes is not None and budget_bytes <= 0:
             raise ValueError(f"budget_bytes must be positive or None, "
                              f"got {budget_bytes}")
         self.budget_bytes = budget_bytes
         self.metrics = metrics or ServeMetrics()
-        # sid -> (state tree, nbytes, spilled?); insertion order = recency
+        self.store = store if store is not None else _Trees()
+        # sid -> (state, nbytes, spilled?); insertion order = recency
         self._entries: "OrderedDict[str, Tuple[Any, int, bool]]" = \
             OrderedDict()
 
@@ -138,32 +293,49 @@ class StateCache:
 
     # -- core ---------------------------------------------------------------
 
-    def put(self, sid: str, state: Dict[str, Any]) -> None:
+    def put(self, sid: str, state: Any) -> None:
         """Insert/replace a session's state (hot) and enforce the budget."""
-        self._entries.pop(sid, None)
-        self._entries[sid] = (state, state_nbytes(state), False)
-        self._enforce(keep=sid)
+        self.put_many([(sid, state)])
 
-    def get(self, sid: str) -> Dict[str, Any]:
+    def put_many(self, items: Iterable[Tuple[str, Any]]) -> None:
+        """Insert/replace states (hot), then enforce the budget once, the
+        last one exempt."""
+        sid = None
+        for sid, state in items:
+            self._entries.pop(sid, None)
+            self._entries[sid] = (state, self.store.nbytes(state), False)
+        self._enforce(keep=(sid,))
+
+    def get(self, sid: str) -> Any:
         """Fetch a session's state onto device, refreshing recency."""
-        state, nb, spilled = self._entries.pop(sid)
-        if spilled:
-            state = jax.tree_util.tree_map(jax.numpy.asarray, state)
-            self.metrics.bump("cache_misses")
-            self.metrics.bump("cache_restores")
-        else:
-            self.metrics.bump("cache_hits")
-        self._entries[sid] = (state, nb, False)
-        self._enforce(keep=sid)
-        return state
+        return self.get_many([sid])[0]
+
+    def get_many(self, sids: Sequence[str]) -> List[Any]:
+        """Fetch states onto device, refreshing recency; none of them
+        spills before the next call (a pool row stays the session's)."""
+        out = []
+        for sid in sids:
+            state, nb, spilled = self._entries.pop(sid)
+            if spilled:
+                state = self.store.restore(state)
+                self.metrics.bump("cache_misses")
+                self.metrics.bump("cache_restores")
+            else:
+                self.metrics.bump("cache_hits")
+            self._entries[sid] = (state, nb, False)
+            out.append(state)
+        self._enforce(keep=sids)
+        return out
 
     def drop(self, sid: str) -> None:
-        self._entries.pop(sid, None)
+        entry = self._entries.pop(sid, None)
+        if entry is not None and not entry[2]:
+            self.store.free(entry[0])
 
-    def _enforce(self, keep: Optional[str] = None) -> None:
+    def _enforce(self, keep: Sequence[Optional[str]] = ()) -> None:
         """Spill LRU-first until hot bytes fit the budget. The `keep`
-        entry (the session about to run / just scattered) is exempt so a
-        budget smaller than one session still serves — it just spills
+        entries (the sessions about to run / just scattered) are exempt so
+        a budget smaller than one session still serves — it just spills
         everything else."""
         if self.budget_bytes is None:
             return
@@ -174,12 +346,12 @@ class StateCache:
             if hot <= self.budget_bytes:
                 break
             state, nb, spilled = self._entries[sid]
-            if spilled or sid == keep:
+            if spilled or sid in keep:
                 continue
-            host = jax.tree_util.tree_map(np.asarray, state)
-            self._entries[sid] = (host, nb, True)
+            self._entries[sid] = (self.store.spill(state), nb, True)
             hot -= nb
             self.metrics.bump("cache_evictions")
 
 
-__all__ = ["Session", "StateCache"]
+__all__ = ["Session", "Row", "StatePool", "StateCache", "SCRATCH", "INIT",
+           "gather_rows", "scatter_rows"]

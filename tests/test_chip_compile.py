@@ -169,9 +169,11 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one_chip), tree)
 
-    states = abstract((eng._zero_core,) * SHD_B)
+    pool = abstract(eng.pool.leaves)
+    rows = jax.ShapeDtypeStruct((SHD_B,), jnp.int32, sharding=one_chip)
+    fresh = jax.ShapeDtypeStruct((SHD_B,), jnp.bool_, sharding=one_chip)
     x = jax.ShapeDtypeStruct((SHD_T, SHD_B, SHD_IN), f32, sharding=one_chip)
-    compiled = step.lower(abstract(params), states, x).compile()
+    compiled = step.lower(abstract(params), pool, rows, fresh, x).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3, "spikemm, lif, linrec kernels"
     # each kernel's instruction carries its wrapper's name, which is how a

@@ -3,10 +3,12 @@ state cache, ragged scheduling/backpressure, fault degradation without
 cross-session contamination, long-prompt admission, and TB5xx checks.
 
 The load-bearing invariant: a session's output trajectory and final state
-are bit-identical whether it runs alone, interleaved with strangers, or
-is evicted to host and restored mid-stream — because the batched engine
-always executes the SAME fixed-shape resident step (free slots
-zero-padded) and spill/restore is a pure device<->host copy.
+are bit-identical whether it runs alone, interleaved with strangers, is
+evicted to host and restored mid-stream, or reuses a retired session's
+pool row — because the batched engine always executes the SAME
+fixed-shape resident step (free slots on the scratch row, fresh sessions
+reading the initial-state row) and spill/restore is a pure device<->host
+copy.
 """
 
 import functools
@@ -19,11 +21,16 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro import analysis
-from repro.core import faults
-from repro.core.snn_layers import make_dhsnn_shd, make_plastic_ff
+from repro.core import events, faults
+from repro.core.neuron import ALIF, LI, LIF
+from repro.core.snn_layers import (ff_integrate, make_dhsnn_shd,
+                                   make_plastic_ff)
 from repro.kernels.incidents import clear, incidents
 from repro.serve import (EngineConfig, Histogram, Scheduler, ServeMetrics,
                          Session, StateCache, make_engine)
+from repro.serve.engine import _resident_step
+from repro.serve.sessions import (SCRATCH, Row, StatePool,
+                                  gather_rows)
 from repro.serve.loop import Request, ServeConfig, _admit, generate
 from tests._faults import forced_pallas
 
@@ -40,6 +47,29 @@ def _model():
 def _plastic_model():
     return make_plastic_ff(jax.random.PRNGKey(1), n_in=10, n_hidden=12,
                            n_out=3)
+
+
+@functools.lru_cache(maxsize=None)
+def _delay_model():
+    """Delay rings (pool axis 1) and an ALIF beside the plain leaves."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+
+    def w(k, i, o):
+        return jax.random.normal(k, (i, o)) / np.sqrt(i)
+
+    nodes = [
+        events.LayerNode("a", LIF(tau=0.5, v_th=0.6), ff_integrate,
+                         ("input",), 10),
+        events.LayerNode("c", ALIF(), ff_integrate, ("a@3",), 8),
+        events.LayerNode("ro", LI(), ff_integrate, ("c", "a@1"), 5),
+    ]
+    params = {"a": {"w_input": w(ks[0], 12, 10)},
+              "c": {"w_a": w(ks[1], 10, 8)},
+              "ro": {"w_c": w(ks[2], 8, 5), "w_a": w(ks[3], 10, 5)}}
+    return nodes, params
+
+
+MODELS = {"dhsnn": _model, "delays": _delay_model}
 
 
 def _streams(n, T, seed, n_in=12):
@@ -176,7 +206,106 @@ def test_fault_world_retraces_resident_step():
 
 
 # ---------------------------------------------------------------------------
-# state cache
+# state pool
+# ---------------------------------------------------------------------------
+
+
+def _row_of(eng, sid):
+    return eng.cache.get(sid).index
+
+
+@pytest.mark.parametrize("kind", ["batched", "naive"])
+def test_retired_row_reused_matches_solo(kind):
+    """A row freed at retire goes to the next open; that session starts
+    from the initial state, not the stale row, and its outputs and final
+    state equal a solo run's bit for bit."""
+    data = _streams(2, 29, seed=13)
+    solo = _run(kind, {"s1": data["s1"]})
+    nodes, params = _model()
+    eng = make_engine(nodes, params, EngineConfig(window=W, capacity=C),
+                      kind=kind)
+    eng.open("s0")
+    row = _row_of(eng, "s0")
+    eng.submit("s0", data["s0"])
+    eng.close("s0")
+    eng.drain()
+    eng.retire("s0")
+    eng.open("s1")
+    assert _row_of(eng, "s1") == row
+    eng.submit("s1", data["s1"])
+    eng.close("s1")
+    eng.drain()
+    np.testing.assert_array_equal(solo.outputs("s1"), eng.outputs("s1"))
+    for a, b in zip(_leaves(solo.state_of("s1")), _leaves(eng.state_of("s1"))):
+        np.testing.assert_array_equal(a, b)
+    assert eng.stats()["fresh_resets"] == 2
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_fresh_reset_equals_init_state(model):
+    """The step's gather reads INIT for a fresh slot, whatever the row
+    holds: leaf for leaf `events.init_state`; a slot that is not fresh
+    reads its row."""
+    nodes, params = MODELS[model]()
+    eng = make_engine(nodes, params, EngineConfig(window=W, capacity=C))
+    pool = eng.pool
+    r = pool.claim()
+    rng = np.random.default_rng(0)
+    junk = jax.tree_util.tree_map(
+        lambda v: rng.standard_normal(v.shape).astype(v.dtype),
+        pool.read(Row(r, True)))
+    pool.write(r, junk)
+    got = gather_rows(pool.leaves, jnp.array([r, r, SCRATCH]),
+                      jnp.array([True, False, True]))
+    init = events.init_state(nodes, 1, jnp.float32, params)
+    for node, nd in init.items():
+        for k, v in nd.items():
+            ax = 1 if k == "ring" else 0
+            slot = [jax.lax.index_in_dim(got[node][k], j, axis=ax)
+                    for j in range(3)]
+            np.testing.assert_array_equal(np.asarray(slot[0]), np.asarray(v))
+            np.testing.assert_array_equal(np.asarray(slot[2]), np.asarray(v))
+            np.testing.assert_array_equal(np.asarray(slot[1]),
+                                          junk[node][k])
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_pool_growth_keeps_isolation(model):
+    """Past the first 4 x capacity rows the pool doubles, once per
+    doubling, and a session's trajectory is the solo one across it."""
+    data = _streams(40, 19, seed=17)
+    m = MODELS[model]()
+    solo = _run("batched", {"s0": data["s0"]}, model=m)
+    many = _run("batched", data, model=m)
+    assert solo.stats()["pool_rows"] == 4 * C
+    assert solo.metrics.pool_grows == 0
+    assert many.stats()["pool_rows"] == 16 * C     # 16 -> 32 -> 64 rows
+    assert many.metrics.pool_grows == 2
+    np.testing.assert_array_equal(solo.outputs("s0"), many.outputs("s0"))
+    for a, b in zip(_leaves(solo.state_of("s0")),
+                    _leaves(many.state_of("s0"))):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_flat_step_argument_count_is_independent_of_capacity():
+    """The flat step's operands flatten to the same number of arrays at 8
+    and 64 slots: the pool, rows and fresh vectors, not a tree a slot."""
+    nodes, params = _model()
+    counts = []
+    for cap in (8, 64):
+        eng = make_engine(nodes, params, EngineConfig(window=W, capacity=cap))
+        step = _resident_step(eng.nodes, eng.plan, "flat", None)
+        rows, fresh = np.zeros(cap, np.int32), np.ones(cap, bool)
+        x = jnp.zeros((W, cap, 12), jnp.float32)
+        lowered = step.lower(params, eng.pool.leaves, rows, fresh, x)
+        counts.append(len(jax.tree_util.tree_leaves(lowered.args_info)))
+    n_pool = len(jax.tree_util.tree_leaves(eng.pool.leaves))
+    assert counts[0] == counts[1] == \
+        len(jax.tree_util.tree_leaves(params)) + n_pool + 3
+
+
+# ---------------------------------------------------------------------------
+# state cache (over a session's own tree, and over a pool row)
 # ---------------------------------------------------------------------------
 
 
@@ -185,16 +314,39 @@ def _toy_state(v, n=4):
                      "out": jnp.zeros((1, n), jnp.float32)}}
 
 
-def test_cache_lru_spills_and_restores_bit_identical():
+def _toy_cache(store, budget, metrics=None):
+    pool = (StatePool(_toy_state(0), capacity=1, metrics=metrics)
+            if store == "pool" else None)
+    return StateCache(budget_bytes=budget, metrics=metrics, store=pool)
+
+
+def _put(cache, sid, v):
+    if isinstance(cache.store, StatePool):
+        i = cache.store.claim()
+        cache.store.write(i, _toy_state(v))
+        cache.put(sid, Row(i, False))
+    else:
+        cache.put(sid, _toy_state(v))
+
+
+def _tree(cache, state):
+    return cache.store.read(state) if isinstance(state, Row) else state
+
+
+STORES = ["trees", "pool"]
+
+
+@pytest.mark.parametrize("store", STORES)
+def test_cache_lru_spills_and_restores_bit_identical(store):
     m = ServeMetrics()
     nbytes = 2 * 4 * 4                          # two (1,4) float32 leaves
-    cache = StateCache(budget_bytes=2 * nbytes, metrics=m)
+    cache = _toy_cache(store, 2 * nbytes, m)
     for i in range(3):
-        cache.put(f"s{i}", _toy_state(i))
+        _put(cache, f"s{i}", i)
     assert cache.hot_bytes <= 2 * nbytes
     assert cache.spilled == ("s0",)             # LRU spilled first
     assert m.cache_evictions == 1
-    got = cache.get("s0")                       # restore refreshes recency
+    got = _tree(cache, cache.get("s0"))         # restore refreshes recency
     np.testing.assert_array_equal(np.asarray(got["node"]["mem"]),
                                   np.full((1, 4), 0.0))
     assert isinstance(got["node"]["mem"], jax.Array)
@@ -203,19 +355,25 @@ def test_cache_lru_spills_and_restores_bit_identical():
     assert "s0" not in cache.spilled and len(cache.spilled) == 1
 
 
-def test_cache_unbounded_never_spills():
-    cache = StateCache(budget_bytes=None)
+@pytest.mark.parametrize("store", STORES)
+def test_cache_unbounded_never_spills(store):
+    cache = _toy_cache(store, None)
     for i in range(20):
-        cache.put(f"s{i}", _toy_state(i))
+        _put(cache, f"s{i}", i)
     assert cache.spilled == ()
+    for i in range(20):
+        np.testing.assert_array_equal(
+            np.asarray(_tree(cache, cache.get(f"s{i}"))["node"]["mem"]),
+            np.full((1, 4), float(i)))
 
 
-def test_cache_budget_smaller_than_one_session_still_serves():
+@pytest.mark.parametrize("store", STORES)
+def test_cache_budget_smaller_than_one_session_still_serves(store):
     m = ServeMetrics()
-    cache = StateCache(budget_bytes=1, metrics=m)
-    cache.put("a", _toy_state(1))
-    cache.put("b", _toy_state(2))
-    got = cache.get("a")                        # the active session stays hot
+    cache = _toy_cache(store, 1, m)
+    _put(cache, "a", 1)
+    _put(cache, "b", 2)
+    got = _tree(cache, cache.get("a"))          # the active session stays hot
     assert not cache.is_spilled("a") and cache.is_spilled("b")
     np.testing.assert_array_equal(np.asarray(got["node"]["mem"]),
                                   np.full((1, 4), 1.0))
