@@ -432,8 +432,9 @@ def phase_topology(rng) -> None:
     out = np.concatenate([np.asarray(o) for _, o in
                           plan_mod.run_stream(nodes, params, chunks,
                                               plan=plan)])
-    # the same chunks through plan.run again, recording the hidden spikes
-    mir_out, hid, _ = run_windows(nodes, params, x, TOPO_CHUNK)
+    # the same chunks through plan.run again, jitted as run_stream jits
+    # its chunks, recording the hidden spikes
+    mir_out, hid, _ = run_windows(nodes, params, x, TOPO_CHUNK, jit=True)
     require(np.array_equal(mir_out, out), "run_stream != chunked plan.run")
     ref_out, ref_hid, _ = reference_windows(nodes, params, x, TOPO_CHUNK)
     compare("topology_1e5", out, hid, ref_out, ref_hid, w_ro, 0.9)

@@ -35,12 +35,26 @@ def _model_factories() -> Dict[str, Callable[..., Tuple[list, dict]]]:
     }
 
 
+def _simulation_factories() -> Dict[str, Callable[..., Tuple[list, dict]]]:
+    """Simulated circuits (not served): the Potjans-Diesmann microcircuit
+    at 1 % of its neurons and in-degrees, its structure whole."""
+    from repro.core import microcircuit as mc
+
+    def pd14(key):
+        del key
+        nodes, params, _ = mc.make_microcircuit(0, scale=0.01)
+        return nodes, params
+
+    return {"pd14_microcircuit": pd14}
+
+
 def _check_models(T: int, B: int) -> List[Diagnostic]:
     import jax
 
     from repro import analysis
     out: List[Diagnostic] = []
-    for name, factory in _model_factories().items():
+    factories = dict(_model_factories(), **_simulation_factories())
+    for name, factory in factories.items():
         nodes, params = factory(jax.random.PRNGKey(0))
         for d in analysis.check_nodes(nodes, params=params, T=T, B=B):
             out.append(Diagnostic(d.code, d.severity, f"{name}:{d.site}",

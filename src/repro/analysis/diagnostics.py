@@ -52,6 +52,7 @@ CODES: Dict[str, Tuple[str, str]] = {
     "TB207": ("info", "hoist convention mismatch"),
     "TB208": ("info", "recurrent variant unsupported"),
     "TB210": ("info", "synapse program runs per-step"),
+    "TB211": ("info", "event group cannot lower"),
     "TB230": ("warning", "predicted segment VMEM over budget"),
     "TB231": ("error", "plastic connections collide on a weight key"),
     "TB232": ("warning", "plastic weight key aliased by another edge"),

@@ -113,8 +113,8 @@ def _vmem_diags(nodes: Sequence[LayerNode], plan: "plan_mod.Plan",
                      "reject the compiled channel and degrade"))
 
     for seg in plan.segments:
-        if seg.kind == plan_mod.FALLBACK:
-            continue
+        if seg.kind in (plan_mod.FALLBACK, plan_mod.EVENT):
+            continue              # EVENT: XLA operations, no VMEM tiles
         node = by_name[seg.names[0]]
         prog = node.neuron.program
         kb = prog.n_branches

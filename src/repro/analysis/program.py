@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
-from repro.core.events import LayerNode
+from repro.core.events import LayerNode, event_topology
 from repro.core.neuron import NeuronProgram, validate_program
 from repro.core.plasticity import SynapseProgram, validate_synapse_program
 
@@ -70,12 +70,14 @@ def check_program(prog: NeuronProgram, site: str = "program") -> List[Diagnostic
     for sv in prog.states:
         if sv.drive.startswith("sum:"):
             read.add(sv.drive[4:])
+        elif sv.drive.startswith("state:"):
+            read.add(sv.drive[6:])
     for sv in prog.states:
         if sv.name not in read:
             out.append(make(
                 "TB105", f"{site}.{sv.name}",
                 "state is never read (not the output, not thresholded, "
-                "not a branch-sum source)",
+                "not a branch-sum or state-drive source)",
                 hint="drop the state or wire it into the output/threshold"))
 
     # TB108: constant decay outside (0, 1]
@@ -230,13 +232,18 @@ def _check_weight_shapes(n: LayerNode, prog: Optional[NeuronProgram],
     return out
 
 
-def _zero_delay_cycles(nodes: Sequence[LayerNode]) -> List[List[str]]:
-    """Cycles in the zero-delay cross-node feed graph (self edges excluded)."""
+def _zero_delay_cycles(nodes: Sequence[LayerNode],
+                       params: Optional[Dict[str, Any]] = None
+                       ) -> List[List[str]]:
+    """Cycles in the zero-delay cross-node feed graph (self edges
+    excluded). An event edge (a `FanOut`, every synapse delayed by >= 1
+    step) is no zero-delay feed."""
     names = {n.name for n in nodes}
     edges: Dict[str, List[str]] = {n.name: [] for n in nodes}
     for n in nodes:
         for c in n.connections:
-            if c.delay == 0 and c.src != "self" and c.src in names:
+            if (c.delay == 0 and c.src != "self" and c.src in names
+                    and event_topology(c, n.name, params) is None):
                 edges[c.src].append(n.name)
     cycles: List[List[str]] = []
     color: Dict[str, int] = {}
@@ -338,16 +345,19 @@ def check_nodes_graph(nodes: Sequence[LayerNode],
             out.extend(_check_weight_shapes(n, prog, node_params, widths))
 
     # TB103: zero-delay cross-node cycles
-    for cyc in _zero_delay_cycles(nodes):
+    for cyc in _zero_delay_cycles(nodes, params):
         out.append(make(
             "TB103", cyc[0],
             "zero-delay cycle " + " -> ".join(cyc) + ": later edges read "
             "stale t-1 outputs, silently, in declaration order",
             hint="add delay=1 on one edge to make the loop explicit"))
 
-    # TB104: unreachable from any external input; dead outputs
+    # TB104: unreachable from any external input; dead outputs. A
+    # background drive drawn on the device is an external input too.
     fed_by_ext = {n.name for n in nodes
                   for c in n.connections if c.src in ext}
+    fed_by_ext |= {n.name for n in nodes
+                   if getattr(_node_program(n), "background", None)}
     reach = set(fed_by_ext)
     frontier = list(fed_by_ext)
     consumers: Dict[str, List[str]] = {n.name: [] for n in nodes}

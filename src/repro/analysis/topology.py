@@ -8,7 +8,8 @@ exception. These checks prove table integrity before anything is lowered:
   TB601  ghost entries: IE targets outside [0, n_post) or sources outside
          [0, n_pre) — the gather lowering would scatter out of bounds
   TB602  duplicate (pre, post) entries: the COO accumulation sums them,
-         which is almost never what an encoder intended
+         which is almost never what an encoder intended (encodings that
+         declare `meta["multapses"]`, such as fanout, hold them on purpose)
   TB603  coverage: structured kinds (fc / conv / pool) should reach every
          output neuron; a hole means a mis-sized encode
   TB604  storage honesty: `meta["n_connections"]` (the denominator of the
@@ -129,7 +130,7 @@ def check_topology(topo: Any) -> List[Diagnostic]:
                 f"outside [0, {n_post})"))
         pairs = pre.astype(np.int64) * n_post + post.astype(np.int64)
         n_dup = pairs.size - np.unique(pairs).size
-        if n_dup:
+        if n_dup and not topo.meta.get("multapses"):
             out.append(make(
                 "TB602", site,
                 f"{n_dup} duplicate (pre, post) entries — their weights "

@@ -17,6 +17,16 @@ works everywhere: `Connection.parse` is the thin back-compat adapter, and
 `LayerNode` normalizes mixed string/Connection input tuples at
 construction.
 
+An edge whose topology is a `FanOut` encoding (a delay per synapse, kind
+"fanout") is an *event edge*: it reads no feed. When its source fires at
+step t, each synapse adds its weight into the target's ring of arriving
+currents, `state[node]["arr"]` of shape (R, batch, n), at slot
+(t + delay) mod R; the target reads slot t mod R as part of its current
+and clears it. Every such delay is >= 1 step, so an event edge never
+couples nodes within a step, whatever their order. Nodes that receive
+event edges or run a background drive carry the step index,
+`state[node]["t"]` (int32 scalar), across steps and chunks.
+
 The stepper itself is forward-only; plasticity executes at run granularity
 in `core/plan.py` (fused `stdp_seq` lowering or the per-step fallback over
 the realized spike trains — identical trajectories), with synapse state
@@ -200,6 +210,52 @@ class LayerNode:
         object.__setattr__(self, "inputs", tuple(keys))
 
 
+def event_topology(conn: Connection, node_name: str = "",
+                   params: Optional[Dict[str, Any]] = None):
+    """The `FanOut` an event edge delivers through, or None. A topology
+    named by a params key resolves only when `params` is given."""
+    from repro.core.topology import FanOut
+    t = conn.topology
+    if isinstance(t, str):
+        if params is None:
+            return None
+        t = resolve_topology(conn, node_name, params)
+    return t if isinstance(t, FanOut) else None
+
+
+def arrival_depth(nodes: List["LayerNode"],
+                  params: Optional[Dict[str, Any]] = None) -> int:
+    """Slots R of every arrival ring of the Program: the smallest power of
+    two above its longest synaptic delay (0 where it has no event edge)."""
+    longest = 0
+    for n in nodes:
+        for c in n.connections:
+            topo = event_topology(c, n.name, params)
+            if topo is not None:
+                longest = max(longest, topo.max_delay)
+    return 1 << longest.bit_length() if longest else 0
+
+
+def _has_background(n: "LayerNode") -> bool:
+    try:
+        return n.neuron.program.background is not None
+    except NotImplementedError:
+        return False
+
+
+def clocked_nodes(nodes: List["LayerNode"],
+                  params: Optional[Dict[str, Any]] = None) -> set:
+    """Names of the nodes that carry the step index: the ends of event
+    edges, and nodes with a background drive."""
+    out = {n.name for n in nodes if _has_background(n)}
+    for n in nodes:
+        for c in n.connections:
+            if event_topology(c, n.name, params) is not None:
+                out.add(n.name)
+                out.add(n.name if c.src == "self" else c.src)
+    return out
+
+
 def state_dtype(dtype) -> jnp.dtype:
     """Neuron state must be float: integer spike inputs (common for encoded
     datasets) would otherwise build integer membranes that truncate every
@@ -220,9 +276,16 @@ def init_state(nodes: List[LayerNode], batch: int, dtype=jnp.float32,
         for c in n.connections:
             if c.delay:
                 max_delay[c.src] = max(max_delay.get(c.src, 0), c.delay)
+    depth = arrival_depth(nodes, params)
+    clocked = clocked_nodes(nodes, params)
     for n in nodes:
         s = n.neuron.init_state((batch, n.out_dim), dtype)
         s["out"] = jnp.zeros((batch, n.out_dim), dtype)  # last emitted spikes
+        if n.name in clocked:
+            s["t"] = jnp.zeros((), jnp.int32)
+            if any(event_topology(c, n.name, params) is not None
+                   for c in n.connections):
+                s["arr"] = jnp.zeros((depth, batch, n.out_dim), dtype)
         if n.name in max_delay:
             s["ring"] = jnp.zeros((max_delay[n.name], batch, n.out_dim), dtype)
         for c in n.connections:
@@ -273,9 +336,15 @@ def step(nodes: List[LayerNode], params: Dict[str, Any], state: Dict[str, Any],
     """
     new_state = dict(state)
     emitted: Dict[str, Array] = {"input": x_t}
+    pushes = []                 # (target node, event topology, source)
     for n in nodes:
         feeds = {}
         for c in n.connections:
+            topo = event_topology(c, n.name, params)
+            if topo is not None:
+                pushes.append((n.name, topo, n.name if c.src == "self"
+                               else c.src))
+                continue
             if c.src == "self":
                 feeds[c.key] = state[n.name]["out"]        # recurrent: t-1
             elif ext is not None and c.key in ext:
@@ -287,10 +356,21 @@ def step(nodes: List[LayerNode], params: Dict[str, Any], state: Dict[str, Any],
             else:
                 feeds[c.key] = state[c.src]["out"]         # not yet run: t-1
         current = n.integrate(_node_params(n, params), feeds)  # INTEG
-        ns, s_out = n.neuron.fire(
-            {k: v for k, v in state[n.name].items()
-             if k not in ("out", "ring") and not k.startswith("syn:")},
-            current, params.get(n.name, {}).get("neuron"))      # FIRE
+        st = state[n.name]
+        if not hasattr(current, "shape"):            # no feed at all
+            current = jnp.full(st["out"].shape, current, st["out"].dtype)
+        fire_state = {k: v for k, v in st.items()
+                      if k not in ("out", "ring", "t", "arr")
+                      and not k.startswith("syn:")}
+        nparams = params.get(n.name, {}).get("neuron")
+        if "t" in st:
+            t = st["t"]
+            if "arr" in st:                          # this step's arrivals
+                current = current + st["arr"][t % st["arr"].shape[0]]
+            ns, s_out = n.neuron.fire(fire_state, current, nparams,
+                                      t=t)           # FIRE
+        else:
+            ns, s_out = n.neuron.fire(fire_state, current, nparams)
         # injected dead/stuck neuron rows (repro.core.faults): the faulty
         # rows poison everything downstream of the emission — recurrence,
         # rings, same-timestep feeds — exactly like a dead core would
@@ -303,9 +383,29 @@ def step(nodes: List[LayerNode], params: Dict[str, Any], state: Dict[str, Any],
         for k, v in state[n.name].items():
             if k.startswith("syn:"):
                 ns[k] = v
+        if "t" in st:
+            ns["t"] = st["t"] + 1
+            if "arr" in st:                          # the slot is consumed
+                ns["arr"] = st["arr"].at[st["t"] % st["arr"].shape[0]].set(0)
         new_state[n.name] = ns
         emitted[n.name] = s_out
+    for name, topo, src in pushes:                   # event edges, delay >= 1
+        st = new_state[name]
+        new_state[name] = dict(st, arr=push_arrivals(
+            st["arr"], topo, emitted[src], state[name]["t"]))
     return new_state, emitted[nodes[-1].name]
+
+
+def push_arrivals(arr: Array, topo, spikes: Array, t) -> Array:
+    """Every synapse of `topo` weighted by its source's spike, added into
+    the arrival ring `arr` (R, batch, n_post) at slot (t + delay) mod R.
+    Touches the whole table: the plain form the stepper runs; the plan's
+    event segment pushes only the spiking rows (`kernels/event`)."""
+    R, B = arr.shape[0], arr.shape[1]
+    slot = (t + topo.delays.astype(jnp.int32)) % R          # (n_pre, F)
+    w = topo.weights[None] * spikes.astype(topo.weights.dtype)[:, :, None]
+    b = jnp.arange(B)[:, None, None]
+    return arr.at[slot[None], b, topo.targets[None]].add(w.astype(arr.dtype))
 
 
 def run(nodes: List[LayerNode], params: Dict[str, Any], x: Array,

@@ -28,6 +28,9 @@ dataclass factory producing its program:
   ALIF    adaptive threshold (Yin et al. 2021) — ECG SRNN hidden layer
   DHLIF   multi-branch dendritic LIF (Zheng et al. 2024) — SHD speech task
   LI      non-spiking leaky-integrator readout (DHSNN/SRNN output layers)
+  IafPscExp  NEST's iaf_psc_exp (exact propagators, refractory period,
+          device-drawn Poisson background) — the Potjans-Diesmann
+          cortical microcircuit's neuron
 
 Custom models: build a `NeuronProgram`, wrap it in `ProgramNeuron`, and
 (optionally) `register_neuron("myneuron", factory)` so configs and CLIs can
@@ -120,6 +123,12 @@ class StateVar:
                              threshold fires, e.g. ALIF's adaptation trace)
             "sum:<state>"  — branch-sum of another (branch) state, e.g. the
                              DH-LIF soma integrating its dendrites
+            "state:<name>" — another (non-branch) state's value, read as
+                             the declaration order leaves it: a source
+                             declared later is read at its previous-step
+                             value (exact-integration propagators, e.g.
+                             iaf_psc_exp's V driven by its synaptic current)
+    gain:   constant coefficient on the drive (1.0 leaves it as it is).
     branch: the state carries a leading dendritic-branch axis
             (shape (..., n_branches, n)); its drive arrives per branch.
     """
@@ -128,6 +137,7 @@ class StateVar:
     decay: Decay
     drive: str = "current"
     branch: bool = False
+    gain: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,12 +156,34 @@ class Threshold:
 
 
 @dataclasses.dataclass(frozen=True)
+class Background:
+    """A per-neuron drive drawn on the device every step and added to the
+    INTEG current: `weight` x a Poisson count.
+
+    The count of neuron n at step t inverts one uniform draw through the
+    neuron's CDF table, `params[table]` of shape (K, n) (`poisson_table`):
+    count = #{k : u >= cdf[k]}, u = uniform(fold_in(params[key], t)). The
+    key is raw PRNG key data (uint32[2]), so the counts depend on (key,
+    step) alone and any reference can draw the same ones.
+    """
+
+    weight: float
+    table: str = "bg_cdf"
+    key: str = "bg_key"
+
+
+@dataclasses.dataclass(frozen=True)
 class NeuronProgram:
     """Declarative FIRE-stage dynamics.
 
     threshold=None describes a non-spiking integrator (no reset either);
     reset "zero" is the hard reset of eq. (3), "none" skips it; output is
     "spikes" or the name of a state to read out (LI reads its membrane).
+    refractory: steps the thresholded state is held after a spike (its
+    update is skipped while a per-neuron counter, state "ref", counts
+    down; the other states keep integrating).
+    background: optional device-drawn drive (`Background`); a program
+    with one needs the step index (`fire(..., t=step)`).
     """
 
     states: Tuple[StateVar, ...]
@@ -159,6 +191,8 @@ class NeuronProgram:
     reset: str = "zero"
     output: str = "spikes"
     n_branches: int = 1
+    refractory: int = 0
+    background: Optional[Background] = None
 
 
 def validate_program(prog: NeuronProgram) -> NeuronProgram:
@@ -189,12 +223,24 @@ def validate_program(prog: NeuronProgram) -> NeuronProgram:
             if sv.branch:
                 raise ValueError(f"branch state {sv.name!r} cannot be "
                                  "sum-driven")
+        elif sv.drive.startswith("state:"):
+            src = sv.drive[6:]
+            if src not in names or src == sv.name:
+                raise ValueError(f"state {sv.name!r} is driven by unknown "
+                                 f"or own state {src!r}")
+            if sv.branch or next(s for s in prog.states
+                                 if s.name == src).branch:
+                raise ValueError(f"state {sv.name!r}: state drives join "
+                                 "non-branch states only")
         elif sv.drive == "spikes":
             if prog.threshold is None:
                 raise ValueError(f"state {sv.name!r} is spike-driven but "
                                  "the program never spikes")
         elif sv.drive != "current":
             raise ValueError(f"state {sv.name!r}: bad drive {sv.drive!r}")
+        if not math.isfinite(sv.gain):
+            raise ValueError(f"state {sv.name!r}: gain {sv.gain} is not "
+                             "finite")
     if prog.threshold is not None:
         th = prog.threshold
         if th.on not in names:
@@ -218,7 +264,47 @@ def validate_program(prog: NeuronProgram) -> NeuronProgram:
         raise ValueError("spike output needs a threshold")
     if prog.n_branches < 1:
         raise ValueError(f"n_branches must be >= 1, got {prog.n_branches}")
+    if prog.refractory < 0:
+        raise ValueError(f"refractory must be >= 0, got {prog.refractory}")
+    if prog.refractory and prog.threshold is None:
+        raise ValueError("a refractory period needs a threshold")
+    if "ref" in names and prog.refractory:
+        raise ValueError("state name 'ref' is the refractory counter's")
+    bg = prog.background
+    if bg is not None and (not math.isfinite(bg.weight) or not bg.table
+                           or not bg.key):
+        raise ValueError(f"bad background {bg}")
     return prog
+
+
+def poisson_table(lam) -> "np.ndarray":
+    """(K, n) float32 CDF table of Poisson(lam[n]) for `Background`.
+
+    Computed in float64 and rounded once to float32; K stops at the first
+    entry that rounds to 1.0 for every neuron, so no float32 uniform in
+    [0, 1) can draw past the table (the truncation drops nothing the draw
+    can represent)."""
+    import numpy as np
+    lam = np.atleast_1d(np.asarray(lam, np.float64))
+    p = np.exp(-lam)
+    cdf, rows, k = p.copy(), [], 0
+    while True:
+        rows.append(cdf.astype(np.float32))
+        if np.all(rows[-1] == np.float32(1.0)) or k > 10_000:
+            break
+        k += 1
+        p = p * lam / k
+        cdf = cdf + p
+    return np.stack(rows)
+
+
+def background_counts(bg: Background, params: Dict[str, Array], t,
+                      shape) -> Array:
+    """This step's Poisson counts (int32, `shape` = (..., n))."""
+    key = jax.random.fold_in(params[bg.key], t)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    cdf = params[bg.table]                               # (K, n)
+    return jnp.sum(u[..., None, :] >= cdf, axis=-2, dtype=jnp.int32)
 
 
 def decay_array(decay: Decay, params: Optional[Dict[str, Array]],
@@ -231,32 +317,58 @@ def decay_array(decay: Decay, params: Optional[Dict[str, Array]],
 
 def program_fire(prog: NeuronProgram, state: State, current: Array,
                  params: Optional[Dict[str, Any]], surrogate: str,
-                 alpha: float) -> Tuple[State, Array]:
+                 alpha: float, t=None) -> Tuple[State, Array]:
     """Interpret one FIRE-stage step of a NeuronProgram.
 
-    Phase order: current-/sum-driven states update first (in declaration
-    order, so a sum-driven soma sees its branches' NEW values), then the
-    threshold fires and resets, then spike-driven states integrate the
-    fresh spikes — exactly the per-model closed forms the programs replace.
+    Phase order: the background drive joins the current; current-, sum-
+    and state-driven states update (in declaration order, so a sum-driven
+    soma sees its branches' NEW values and a state read before its source
+    updates sees the previous step's value), a refractory neuron keeping
+    its thresholded state; then the threshold fires and resets and the
+    refractory counter counts down or restarts; then spike-driven states
+    integrate the fresh spikes — exactly the per-model closed forms the
+    programs replace, and NEST's update order for `iaf_psc_exp`.
     """
+    if prog.background is not None:
+        if t is None:
+            raise ValueError("a program with a background drive needs the "
+                             "step index (fire(..., t=step))")
+        bg = prog.background
+        shape = jnp.shape(state[next(sv.name for sv in prog.states
+                                     if not sv.branch)])
+        current = current + bg.weight * background_counts(
+            bg, params, t, shape).astype(jnp.float32)
     dtype = current.dtype
     vals = {sv.name: state[sv.name] for sv in prog.states}
+    th = prog.threshold
+    held = state["ref"] > 0 if prog.refractory else None
     for sv in prog.states:
         if sv.drive == "spikes":
             continue
-        c = (current if sv.drive == "current"
-             else jnp.sum(vals[sv.drive[4:]], axis=-2))
-        vals[sv.name] = diff(vals[sv.name], decay_array(sv.decay, params,
-                                                        dtype), c)
-    if prog.threshold is None:
+        if sv.drive == "current":
+            c = current
+        elif sv.drive.startswith("sum:"):
+            c = jnp.sum(vals[sv.drive[4:]], axis=-2)
+        else:
+            c = vals[sv.drive[6:]]
+        if sv.gain != 1.0:
+            c = sv.gain * c
+        new = diff(vals[sv.name], decay_array(sv.decay, params, dtype), c)
+        if held is not None and sv.name == th.on:
+            new = jnp.where(held, vals[sv.name], new)
+        vals[sv.name] = new
+    if th is None:
         return vals, vals[prog.output]
-    th = prog.threshold
     level = th.base + (th.scale * vals[th.adapt] if th.adapt else 0.0)
     s = spike(vals[th.on] - level, surrogate, alpha)
     if prog.reset == "zero":
         vals[th.on] = vals[th.on] * (1.0 - s)
     elif prog.reset == "subtract":
         vals[th.on] = vals[th.on] - level * s
+    if held is not None:
+        ref = state["ref"]
+        vals["ref"] = jnp.where(s > 0, jnp.asarray(prog.refractory, ref.dtype),
+                                jnp.where(held, ref - 1, ref))
     for sv in prog.states:
         if sv.drive == "spikes":
             vals[sv.name] = diff(vals[sv.name], decay_array(sv.decay, params,
@@ -289,13 +401,17 @@ class NeuronSpec:
             s = (shape[:-1] + (prog.n_branches,) + shape[-1:] if sv.branch
                  else tuple(shape))
             state[sv.name] = jnp.zeros(s, dtype)
+        if prog.refractory:
+            state["ref"] = jnp.zeros(tuple(shape), jnp.int32)
         return state
 
     def fire(self, state: State, current: Array,
-             params: Dict[str, Any] | None = None) -> Tuple[State, Array]:
-        """One FIRE-stage update given the INTEG-stage current."""
+             params: Dict[str, Any] | None = None, t=None
+             ) -> Tuple[State, Array]:
+        """One FIRE-stage update given the INTEG-stage current (`t`, the
+        step index, for programs with a background drive)."""
         return program_fire(self.program, state, current, params,
-                            self.surrogate, self.alpha)
+                            self.surrogate, self.alpha, t)
 
     def param_init(self, key, shape) -> Dict[str, Array]:
         """Learnable per-neuron parameters (empty for fixed models)."""
@@ -445,12 +561,70 @@ class LI(NeuronSpec):
             threshold=None, reset="none", output="v")
 
 
+@dataclasses.dataclass(frozen=True)
+class IafPscExp(NeuronSpec):
+    """NEST's `iaf_psc_exp`: a current-based LIF with an exponential
+    synaptic current, integrated exactly on the `dt` grid (units pF, ms,
+    mV, pA). The membrane is held relative to E_L:
+
+        V <- P22 V + P21 I        unless refractory (then V is held)
+        I <- P11 I + arrivals     (+ bg_weight x background counts)
+        spike where V >= v_th - e_l; V <- 0 (V_reset = E_L); the counter
+        holds V for round(t_ref / dt) steps
+
+    with P22 = exp(-dt/tau_m), P11 = exp(-dt/tau_syn) and P21 = tau_m
+    tau_syn / (c_m (tau_m - tau_syn)) (P22 - P11), NEST's propagators.
+    V is updated from the previous step's I, then I decays and takes this
+    step's arrivals: NEST's update order. `bg_weight` > 0 adds a Poisson
+    background (`Background`, its tables in the node's neuron params)."""
+
+    c_m: float = 250.0
+    tau_m: float = 10.0
+    tau_syn: float = 0.5
+    t_ref: float = 2.0
+    e_l: float = -65.0
+    v_th: float = -50.0
+    v_reset: float = -65.0
+    dt: float = 0.1
+    bg_weight: float = 0.0
+
+    def __post_init__(self):
+        if self.v_reset != self.e_l:
+            raise ValueError("iaf_psc_exp here resets to E_L "
+                             f"(v_reset={self.v_reset}, e_l={self.e_l})")
+        if self.tau_m == self.tau_syn:
+            raise ValueError("tau_m == tau_syn needs the singular "
+                             "propagator, which is not implemented")
+
+    @property
+    def propagators(self) -> Tuple[float, float, float]:
+        """(P22, P21, P11) in float64."""
+        p22 = math.exp(-self.dt / self.tau_m)
+        p11 = math.exp(-self.dt / self.tau_syn)
+        p21 = (self.tau_m * self.tau_syn
+               / (self.c_m * (self.tau_m - self.tau_syn)) * (p22 - p11))
+        return p22, p21, p11
+
+    @property
+    def program(self) -> NeuronProgram:
+        p22, p21, p11 = self.propagators
+        return NeuronProgram(
+            states=(StateVar("v", Decay("const", p22), drive="state:i",
+                             gain=p21),
+                    StateVar("i", Decay("const", p11))),
+            threshold=Threshold(base=self.v_th - self.e_l),
+            reset="zero", refractory=int(round(self.t_ref / self.dt)),
+            background=(Background(self.bg_weight) if self.bg_weight
+                        else None))
+
+
 NEURON_REGISTRY: Dict[str, Callable[..., NeuronSpec]] = {
     "lif": LIF,
     "plif": PLIF,
     "alif": ALIF,
     "dhlif": DHLIF,
     "li": LI,
+    "iaf_psc_exp": IafPscExp,
 }
 
 

@@ -86,11 +86,12 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import (Any, Dict, Iterable, Iterator, List, Optional, Tuple,
-                    Union)
+from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple,
+                    Optional, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import events, faults, guards, plasticity
 from repro.core.neuron import Decay, NeuronProgram, decay_array
@@ -109,12 +110,14 @@ Array = jax.Array
 FUSED_FF = "fused_ff"
 FUSED_REC = "fused_rec"
 FALLBACK = "fallback"
+EVENT = "event"                # populations stepped together, event INTEG
 
 # FIRE lowering families the pattern matcher can emit
 LOWER_LI = "li"
 LOWER_LIF = "lif"
 LOWER_ALIF = "alif"
 LOWER_DHLIF = "dhlif"
+LOWER_EVENT = "event_push"     # FIRE interpreted per step, INTEG pushed
 
 # synapse-program lowerings
 SYN_SEQ = "stdp_seq"
@@ -155,7 +158,7 @@ def check_mode() -> str:
 class Segment:
     """One unit of the lowered schedule, executed over the full time axis."""
 
-    kind: str                  # fused_ff | fused_rec | fallback
+    kind: str                  # fused_ff | fused_rec | fallback | event
     names: Tuple[str, ...]     # node names (fused segments hold exactly one)
     reason: str = ""           # why the planner fell back (diagnostics)
     lower: str = ""            # FIRE kernel family for fused segments
@@ -228,6 +231,12 @@ def _match_fire_pattern(prog: NeuronProgram
     th = prog.threshold
     if not prog.states:
         return None, "TB206", "empty program"
+    if prog.refractory or prog.background is not None or any(
+            sv.gain != 1.0 or sv.drive.startswith("state:")
+            for sv in prog.states):
+        # the fused kernels know none of these; the interpreter does
+        return None, "TB206", ("refractory, background, state drive or gain "
+                               "(interpreted per step)")
     if th is None:
         sv = prog.states[0]
         if (len(prog.states) == 1 and not sv.branch
@@ -327,6 +336,69 @@ def _classify(node: events.LayerNode, order: Dict[str, int]
     return FUSED_FF, "", "", family
 
 
+def _is_event_edge(c: events.Connection) -> bool:
+    """A `FanOut` on the connection itself: every synapse has a delay of
+    at least one step (the encoding refuses less). A topology named by a
+    params key is unknown until run time and does not count here."""
+    return events.event_topology(c) is not None
+
+
+def _event_groups(nodes: List[events.LayerNode]
+                  ) -> Tuple[List[List[str]], List[Tuple[List[str], str]]]:
+    """Populations joined by event edges, as connected groups in
+    declaration order: (groups that lower to one EVENT segment, groups
+    that cannot with the reason). A group lowers when each member's every
+    inbound edge is an event edge from a node (so a member) or itself,
+    and each member declares a neuron program. (An event edge cannot be
+    plastic: `Connection` refuses a topology that learns.)"""
+    names = {n.name for n in nodes}
+    parent = {n.name: n.name for n in nodes}
+
+    def find(a):
+        while parent[a] != a:
+            parent[a] = parent[parent[a]]
+            a = parent[a]
+        return a
+
+    members = set()
+    for n in nodes:
+        for c in n.connections:
+            if _is_event_edge(c):
+                members.add(n.name)
+                if c.src in names:
+                    members.add(c.src)
+                    parent[find(c.src)] = find(n.name)
+    groups: Dict[str, List[str]] = {}
+    for n in nodes:
+        if n.name in members:
+            groups.setdefault(find(n.name), []).append(n.name)
+    by_name = {n.name: n for n in nodes}
+    ok, bad = [], []
+    for g in groups.values():
+        why = ""
+        for name in g:
+            n = by_name[name]
+            for c in n.connections:
+                if not _is_event_edge(c):
+                    why = f"{name} also reads {c.key} through no event edge"
+                elif c.src not in names and c.src != "self":
+                    why = f"{name} has an event edge from {c.src}, no node"
+                if why:
+                    break
+            if not why:
+                try:
+                    n.neuron.program
+                except NotImplementedError:
+                    why = f"{name} declares no neuron program"
+            if why:
+                break
+        if why:
+            bad.append((g, why))
+        else:
+            ok.append(g)
+    return ok, bad
+
+
 def compile_program(nodes: List[events.LayerNode]) -> Plan:
     """Analyze the node DAG and emit the segment + plastic-lowering plan."""
     order = {n.name: i for i, n in enumerate(nodes)}
@@ -338,11 +410,24 @@ def compile_program(nodes: List[events.LayerNode]) -> Plan:
             lower, code, why = _match_synapse_pattern(c.plastic)
             plastic.append(PlasticLower(n.name, c.key, lower, why, code))
 
+    # Event groups: populations joined by event edges step together in one
+    # EVENT segment. One that cannot lower couples the Program per step.
+    groups, unlowered = _event_groups(nodes)
+    plan = None
+    if unlowered:
+        g, why = unlowered[0]
+        plan = Plan((Segment(
+            FALLBACK, tuple(x.name for x in nodes),
+            f"{g[0]}: TB211 event group cannot lower: {why}",
+            codes=("TB211",)),), tuple(plastic))
+
     # Any previous-timestep read of a later node couples the whole Program
     # per-timestep: compile to one stepper segment (exactly events.run).
-    plan = None
-    for n in nodes:
+    # Event edges deliver at least a step later whatever the order.
+    for n in ([] if plan else nodes):
         for c in n.connections:
+            if _is_event_edge(c):
+                continue
             if c.src not in ("input", "self") and order[c.src] >= order[n.name]:
                 plan = Plan((Segment(
                     FALLBACK, tuple(x.name for x in nodes),
@@ -367,7 +452,15 @@ def compile_program(nodes: List[events.LayerNode]) -> Plan:
                 pending_fallback, pending_reason = [], ""
                 pending_codes = []
 
+        in_group = {name: g for g in groups for name in g}
         for n in nodes:
+            if n.name in in_group:
+                g = in_group[n.name]
+                if n.name == g[0]:
+                    flush()
+                    segments.append(Segment(EVENT, tuple(g),
+                                            lower=LOWER_EVENT))
+                continue
             kind, code, reason, family = _classify(n, order)
             if kind == FALLBACK:
                 pending_fallback.append(n.name)
@@ -657,6 +750,125 @@ def _run_fallback(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
 
 
 # ---------------------------------------------------------------------------
+# EVENT segments: populations joined by per-synapse-delay edges
+# ---------------------------------------------------------------------------
+#
+# The group's nodes step together inside one `lax.scan`: each step reads
+# the group's arrival ring at slot t mod R as every member's current, runs
+# each member's FIRE (the NeuronProgram interpreter, background drive
+# included), clears the slot, and pushes the step's spikes through the
+# group's flat fan-out table (`kernels/event`). The ring is the members'
+# `arr` states side by side; the tables are built once per `run` call, or
+# once per stream (`prepare`), never inside a traced chunk.
+
+
+def _event_members(seg: Segment, nodes_by_name: Dict[str, events.LayerNode]
+                   ) -> Tuple[List[events.LayerNode], Dict[str, int], int]:
+    members = [nodes_by_name[name] for name in seg.names]
+    offs, pos = {}, 0
+    for n in members:
+        offs[n.name] = pos
+        pos += n.out_dim
+    return members, offs, pos
+
+
+def event_tables(seg: Segment, nodes_by_name: Dict[str, events.LayerNode]):
+    """(tables, shift, width) of one EVENT segment: every member's event
+    edges regrouped by source population (eager; see `prepare`)."""
+    from repro.kernels.event.ops import build_event_tables
+    members, offs, total = _event_members(seg, nodes_by_name)
+    leaving: Dict[str, List] = {n.name: [] for n in members}
+    for n in members:
+        for c in n.connections:
+            src = n.name if c.src == "self" else c.src
+            leaving[src].append((events.event_topology(c), offs[n.name]))
+    return build_event_tables([(n.out_dim, leaving[n.name])
+                               for n in members], total)
+
+
+def prepare(plan: Plan, nodes: List[events.LayerNode]) -> Dict[int, Any]:
+    """The event tables of every EVENT segment of `plan`, keyed by segment
+    index: build them once and pass them to each `run` of a stream."""
+    by_name = {n.name: n for n in nodes}
+    return {i: event_tables(seg, by_name)
+            for i, seg in enumerate(plan.segments) if seg.kind == EVENT}
+
+
+def _pack_bits(s: Array) -> Array:
+    """(..., n) 0/1 -> (words,) uint32, bit k of word i is entry 32 i + k
+    of the flattened spikes."""
+    flat = (s.reshape(-1) > 0).astype(jnp.uint32)
+    pad = (-flat.shape[0]) % 32
+    flat = jnp.pad(flat, (0, pad)).reshape(-1, 32)
+    return jnp.sum(flat << jnp.arange(32, dtype=jnp.uint32), axis=1,
+                   dtype=jnp.uint32)
+
+
+def _run_event(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
+               params: Dict[str, Any], state: Dict[str, Any],
+               new_state: Dict[str, Any], T: int, B: int, prepared,
+               packed: bool = False):
+    """Step an EVENT segment T times. Returns (spikes (T, B, N) over the
+    group, or (T, words) packed bits when `packed`, and the summed push
+    stats uint32[3]: spikes, synaptic events delivered, steps that took
+    an extra pass)."""
+    from repro.kernels.event.ops import event_push
+    tables, shift, width = prepared
+    members, offs, N = _event_members(seg, nodes_by_name)
+    rings = [state[n.name]["arr"] for n in members if "arr" in state[n.name]]
+    R = rings[0].shape[0]
+    dtype = rings[0].dtype
+    ring = jnp.concatenate(
+        [state[n.name]["arr"] if "arr" in state[n.name]
+         else jnp.zeros((R, B, n.out_dim), dtype) for n in members], axis=-1)
+    fire0 = {n.name: {k: v for k, v in state[n.name].items()
+                      if k not in ("out", "ring", "t", "arr")
+                      and not k.startswith("syn:")} for n in members}
+    last0 = jnp.concatenate([state[n.name]["out"] for n in members], -1)
+    nparams = {n.name: params.get(n.name, {}).get("neuron") for n in members}
+    t0 = state[members[0].name]["t"]
+
+    def body(carry, _):
+        fs, ring, t, _last, acc = carry
+        slot = t % R
+        cur = ring[slot]                                    # (B, N)
+        new_fs, outs = {}, []
+        with jax.named_scope("fire"):
+            for n in members:
+                o = offs[n.name]
+                ns, so = n.neuron.fire(fs[n.name], cur[:, o:o + n.out_dim],
+                                       nparams[n.name], t=t)
+                new_fs[n.name] = ns
+                outs.append(faults.perturb_output(n.name, so))
+        spk = jnp.concatenate(outs, axis=-1).astype(dtype)
+        ring = ring.at[slot].set(jnp.zeros((B, N), dtype))
+        with jax.named_scope("integ"):
+            flat, st = event_push(ring.reshape(R, B * N), spk.reshape(-1), t,
+                                  tables, shift=shift, width=width)
+        y = _pack_bits(spk) if packed else spk
+        return (new_fs, flat.reshape(R, B, N), t + 1, spk, acc + st), y
+
+    carry0 = (fire0, ring, t0, last0, jnp.zeros((3,), jnp.uint32))
+    (fs, ring, tT, last, stats), ys = jax.lax.scan(body, carry0, None,
+                                                   length=T)
+    for n in members:
+        o = offs[n.name]
+        ns = dict(fs[n.name])
+        ns["out"] = last[:, o:o + n.out_dim]
+        ns["t"] = tT
+        if "arr" in state[n.name]:
+            ns["arr"] = ring[..., o:o + n.out_dim]
+        if "ring" in state[n.name]:     # read by a delayed edge downstream
+            ns["ring"] = _advance_ring(state[n.name]["ring"],
+                                       ys[..., o:o + n.out_dim])
+        for k, v in state[n.name].items():
+            if k.startswith("syn:"):
+                ns[k] = v
+        new_state[n.name] = ns
+    return ys, stats
+
+
+# ---------------------------------------------------------------------------
 # session-state layout (the serve engine's state pool builds on it)
 # ---------------------------------------------------------------------------
 #
@@ -828,7 +1040,8 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
         state: Optional[Dict[str, Any]] = None, record: Tuple[str, ...] = (),
         plan: Optional[Plan] = None, mod: Optional[Array] = None,
         learn: bool = True,
-        guard: Union[None, str, guards.GuardConfig] = None):
+        guard: Union[None, str, guards.GuardConfig] = None,
+        prepared: Optional[Dict[int, Any]] = None):
     """Drop-in replacement for `events.run` through the compiled plan.
 
     x: (T, batch, n_in). Returns (final_state, outputs (T, batch, n_out),
@@ -844,6 +1057,11 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
     outputs inside both engines, identically. `guard` enables numerical
     guardrails (`core/guards.py`) — a policy string off|warn|raise|sanitize
     or a full `GuardConfig`; None defers to `REPRO_GUARD` (default off).
+
+    EVENT segments build their fan-out tables from the connections' arrays
+    on each call unless `prepared` (from `prepare`) holds them; under a
+    caller's jit, pass `prepared` as an argument, or the tables become
+    constants of the compiled program.
     """
     mode = engine_mode()
     if plan is None:
@@ -893,10 +1111,22 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
         state = events.init_state(nodes, B, x.dtype, params)
     outs: Dict[str, Array] = {"input": x}
     new_state = dict(state)
-    for seg in plan.segments:
+    for i, seg in enumerate(plan.segments):
         if seg.kind == FALLBACK:
             _run_fallback(seg, nodes_by_name, params, x, outs, state,
                           new_state, T, gcfg)
+        elif seg.kind == EVENT:
+            tabs = (prepared or {}).get(i) or event_tables(seg,
+                                                          nodes_by_name)
+            spk, _ = _run_event(seg, nodes_by_name, params, state, new_state,
+                                T, B, tabs)
+            members, offs, _ = _event_members(seg, nodes_by_name)
+            for n in members:
+                out = spk[..., offs[n.name]:offs[n.name] + n.out_dim]
+                if gcfg.active:
+                    out = guards.check_tensor(f"{n.name}.out", out, gcfg)
+                    guards.check_spikes(n.name, out, gcfg)
+                outs[n.name] = out
         else:
             _run_fused(nodes_by_name[seg.names[0]], seg.kind, seg.lower,
                        params, outs, state, new_state, T, B, gcfg)
@@ -907,42 +1137,177 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
     return new_state, outs[nodes[-1].name], recs
 
 
+class StreamSpikes(NamedTuple):
+    """One chunk's spikes, compacted (`run_stream(..., spikes="list")`).
+
+    step:   absolute step index of each spike, ascending
+    neuron: the spiking neuron, indexed over the program's nodes side by
+            side in declaration order
+    batch:  its batch row
+    counts: the chunk's counters: `spikes` (counted on the device, before
+            any read-back), `events` (synaptic events delivered) and
+            `extra_pass_steps` (steps whose spikes took more than one
+            push pass)
+    """
+
+    step: np.ndarray
+    neuron: np.ndarray
+    batch: np.ndarray
+    counts: Dict[str, int]
+
+
+def _decode_words(pos: np.ndarray, vals: np.ndarray, t0: int, W: int,
+                  N: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(step, neuron, batch) of the set bits of packed words at flat
+    positions `pos` of a (T, W) array."""
+    bits = (vals[:, None] >> np.arange(32, dtype=np.uint32)) & 1
+    r, k = np.nonzero(bits)
+    word = pos[r].astype(np.int64)
+    idx = (word % W) * 32 + k
+    return t0 + word // W, (idx % N).astype(np.int32), \
+        (idx // N).astype(np.int32)
+
+
+# nonzero spike words a step that a list-mode chunk reads back compacted;
+# a chunk with more reads every word
+WORDS_A_STEP = 64
+
+
+def stream_step(nodes: List[events.LayerNode], plan: Plan,
+                spikes: str = "dense", learn: bool = True,
+                guard: Union[None, str, guards.GuardConfig] = None):
+    """The jitted chunk step of `run_stream`, state (argument 2) donated:
+    spikes="dense": (params, prepared, state, x, mod) -> (state, outputs);
+    spikes="list": (params, prepared, state, x) -> (state, (positions and
+    values of the nonzero spike words, their count, every word, the push
+    stats)) for a program that is one EVENT segment."""
+    by_name = {n.name: n for n in nodes}
+
+    def dense_chunk(params, prepared, state, x, m):
+        st, out, _ = run(nodes, params, x, state=state, plan=plan, mod=m,
+                         learn=learn, guard=guard, prepared=prepared)
+        return st, out
+
+    def list_chunk(params, prepared, state, x):
+        T, B = x.shape[0], x.shape[1]
+        new_state = dict(state)
+        words, stats = _run_event(plan.segments[0], by_name,
+                                  faults.perturb_params(params), state,
+                                  new_state, T, B, prepared[0], packed=True)
+        flat = words.reshape(-1)
+        cap = min(flat.shape[0], WORDS_A_STEP * T)
+        n_nz = jnp.sum(flat != 0, dtype=jnp.int32)
+        pos = jnp.nonzero(flat, size=cap, fill_value=0)[0].astype(jnp.int32)
+        return new_state, (pos, flat[pos], n_nz, words, stats)
+
+    return jax.jit(list_chunk if spikes == "list" else dense_chunk,
+                   donate_argnums=(2,))
+
+
 def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
                chunks: Iterable[Array],
                state: Optional[Dict[str, Any]] = None,
                plan: Optional[Plan] = None, mod: Optional[Array] = None,
                learn: bool = True,
-               guard: Union[None, str, guards.GuardConfig] = None
-               ) -> Iterator[Tuple[Dict[str, Any], Array]]:
+               guard: Union[None, str, guards.GuardConfig] = None,
+               spikes: str = "dense"
+               ) -> Iterator[Tuple[Dict[str, Any], Any]]:
     """Chunked/streaming execution: constant peak memory in stream length.
 
     Consumes an iterable of (T_chunk, batch, n_in) spike chunks and yields
     `(state, outputs)` after each one, carrying neuron state, skip-delay
-    ring buffers, and synapse state across chunk boundaries. Ring-buffered
-    delay lines make this exact: a delayed edge reads its prefix from the
-    carried ring (`_feed_full`), never from a delay-shifted full-time
-    tensor, so concatenating the yielded outputs reproduces the one-shot
-    `run` on the concatenated stream bit-for-bit while peak host+device
+    ring buffers, arrival rings, step counters and synapse state across
+    chunk boundaries. Ring-buffered delay lines make this exact: a delayed
+    edge reads its prefix from the carried ring (`_feed_full`), never from
+    a delay-shifted full-time tensor, and a background drive draws from
+    (key, step), so concatenating the yielded outputs reproduces the
+    one-shot `run` on the concatenated stream while peak host+device
     memory scales with the chunk length only — the paper's
     infinite-time-window streaming mode.
 
-    The plan is compiled once up front; `mod`, when given, must be an
-    iterable aligned with `chunks` (one modulator window per chunk).
+    Each chunk runs one jitted step program (compiled once per chunk
+    shape) that takes the state DONATED: the state a chunk yields is
+    consumed by the next one, so copy what you keep before resuming the
+    stream. The stream works on its own copy of a `state` passed in. The
+    plan is compiled once up front and EVENT tables built once
+    (`prepare`); `mod`, when given, must be an iterable aligned with
+    `chunks` (one modulator window per chunk). Guards under policy
+    "raise" need concrete values, so that policy runs the chunks eagerly.
+
+    spikes="dense" yields the last node's (T, batch, n_out) outputs, as
+    `run` returns them. spikes="list" — for a program that is one EVENT
+    segment — yields a `StreamSpikes` of every neuron's spikes instead:
+    the chunk packs each step's spikes into bits and compacts the nonzero
+    words on the device (at most 64 a step, `WORDS_A_STEP`), and the host
+    reads back only those; a chunk with more reads every word, so no
+    spike is lost. Both modes log the spans `sim.chunk` (the call to its
+    outputs on the host, with the counters as ids in list mode) and, in
+    list mode, `sim.readback` (`repro.serve.metrics.span`).
     """
+    from repro.serve import metrics as _metrics
     if plan is None:
         plan = compile_program(nodes)
+    if spikes not in ("dense", "list"):
+        raise ValueError(f"spikes={spikes!r}: expected 'dense' or 'list'")
+    listed = spikes == "list"
+    if listed and not (len(plan.segments) == 1
+                       and plan.segments[0].kind == EVENT
+                       and engine_mode() != "stepper"):
+        raise ValueError("spikes='list' steps a program that compiles to "
+                         f"one EVENT segment, not {plan.describe()}")
+    gcfg = guards.config(guard)
+    eager = gcfg.policy == "raise" and not listed
+    prepared = prepare(plan, nodes) if engine_mode() != "stepper" else {}
     mods = iter(mod) if mod is not None else None
-    for x in chunks:
+    if state is not None:
+        state = jax.tree_util.tree_map(jnp.copy, state)
+
+    step = stream_step(nodes, plan, spikes, learn, guard)
+    t_host = None
+    for k, x in enumerate(chunks):
         m = next(mods) if mods is not None else None
-        state, out, _ = run(nodes, params, x, state=state, plan=plan,
-                            mod=m, learn=learn, guard=guard)
+        if state is None:
+            state = events.init_state(nodes, x.shape[1], x.dtype, params)
+        with _metrics.span("sim.chunk", chunk=k) as sp:
+            if not listed:
+                if eager:
+                    state, out, _ = run(nodes, params, x, state=state,
+                                        plan=plan, mod=m, learn=learn,
+                                        guard=guard, prepared=prepared)
+                else:
+                    state, out = step(params, prepared, state, x, m)
+            else:
+                if t_host is None:
+                    t_host = int(state[nodes[0].name]["t"])
+                state, (pos, vals, n_nz, words, stats) = step(
+                    params, prepared, state, x)
+                with _metrics.span("sim.readback", chunk=k):
+                    n_nz, stats = int(n_nz), np.asarray(stats)
+                    if n_nz <= pos.shape[0]:
+                        pos, vals = np.asarray(pos)[:n_nz], \
+                            np.asarray(vals)[:n_nz]
+                    else:                        # more than the cap: all
+                        w = np.asarray(words).reshape(-1)
+                        pos = np.flatnonzero(w)
+                        vals = w[pos]
+                    W = words.shape[1]
+                    n_all = sum(n.out_dim for n in nodes)
+                    st_, nr, bt = _decode_words(pos, vals, t_host, W, n_all)
+                counts = {"spikes": int(stats[0]), "events": int(stats[1]),
+                          "extra_pass_steps": int(stats[2])}
+                sp.ids.update(counts)
+                out = StreamSpikes(st_, nr, bt, counts)
+                t_host += x.shape[0]
         yield state, out
 
 
 __all__ = ["Plan", "PlasticLower", "Segment", "compile_program",
-           "engine_mode", "check_mode", "run", "run_stream",
+           "engine_mode", "check_mode", "run", "run_stream", "prepare",
+           "stream_step",
+           "event_tables", "StreamSpikes",
            "CROSS_ENGINE_ATOL",
            "state_nbytes", "state_batch_axis",
-           "FUSED_FF", "FUSED_REC", "FALLBACK",
+           "FUSED_FF", "FUSED_REC", "FALLBACK", "EVENT",
            "LOWER_LI", "LOWER_LIF", "LOWER_ALIF", "LOWER_DHLIF",
+           "LOWER_EVENT",
            "SYN_SEQ", "SYN_STEP"]

@@ -255,6 +255,7 @@ _KERNEL_MODULES = (
     "repro.kernels.spikemm.gather",
     "repro.kernels.attention.ops",
     "repro.kernels.stdp.ops",
+    "repro.kernels.event.ops",
 )
 
 

@@ -183,3 +183,64 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
     # the stages of the step tag their ops' metadata
     scopes = set(re.findall(r'op_name="jit\(step\)/(\w+)/', text))
     assert {"integ", "fire", "pack", "unpack"} <= scopes, scopes
+
+
+def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
+    """The jitted 200-step stream chunk of the Potjans-Diesmann circuit at
+    its published sizes (77,169 neurons, 2.99e8 synapses, 2.4 GB of
+    table): the whole EVENT segment, with the push's scatter into the
+    flat ring."""
+    from repro.core import events, microcircuit as mc, plan as plan_mod
+    from repro.core.neuron import IafPscExp
+    from repro.core.snn_layers import ff_integrate
+    from repro.core.topology import encode_fanout
+    from repro.kernels.event.ops import EventTables, GroupTables
+
+    n, R, T, syn, W = mc.N_FULL, 64, 200, 298_880_968, 512
+    neuron = IafPscExp(bg_weight=87.81)
+    nodes = []
+    for q, name in enumerate(mc.POPULATIONS):
+        conns = tuple(
+            events.Connection("self" if p == q else mc.POPULATIONS[p],
+                              topology=encode_fanout(
+                                  np.array([0]), np.array([0]),
+                                  np.array([1.0], np.float32),
+                                  np.array([R - 1]), n[p], n[q]))
+            for p in range(8) if mc.CONN_PROB[q][p] > 0)
+        nodes.append(events.LayerNode(name, neuron, ff_integrate, conns,
+                                      n[q]))
+    plan = plan_mod.compile_program(nodes)
+    assert [s.kind for s in plan.segments] == [plan_mod.EVENT]
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    N = sum(n)
+    params = {name: {"neuron": {"bg_cdf": S((13, n[q]), f32),
+                                "bg_key": S((2,), jnp.uint32)}}
+              for q, name in enumerate(mc.POPULATIONS)}
+    i32 = jnp.int32
+    tables = GroupTables(EventTables(
+        S((N,), i32), S((N,), i32), S((N,), i32), S((syn + W,), i32),
+        S((syn + W,), f32)), 17, W)
+    state = {name: {"v": S((1, n[q]), f32), "i": S((1, n[q]), f32),
+                    "ref": S((1, n[q]), i32), "out": S((1, n[q]), f32),
+                    "t": S((), i32), "arr": S((R, 1, n[q]), f32)}
+             for q, name in enumerate(mc.POPULATIONS)}
+    step = plan_mod.stream_step(nodes, plan, spikes="list")
+    compiled = step.lower(params, {0: tables}, state,
+                          S((T, 1, 0), f32)).compile()
+    text = compiled.as_text()
+    # `push_roofline.sim` finds the push's pass loop in the trace as the
+    # innermost loop around the one operation that writes the flat ring
+    ring = re.compile(rf"^\s*%[\w.-]+ = f32\[{R * N}\]\S* (fusion|scatter)\(",
+                      re.M)
+    hits = ring.findall(text)
+    assert len(hits) == 1, hits
+    at = ring.search(text).start()
+    head = re.findall(r"^(?:ENTRY )?%([\w.-]+) .*\{$", text[:at], re.M)[-1]
+    loops = re.findall(rf"^\s*%while[.\d]* = .* while\(.*body=%{re.escape(head)}\b",
+                       text, re.M)
+    assert len(loops) == 1, f"the scatter's computation {head} is no loop body"
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 4e9

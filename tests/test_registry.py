@@ -24,7 +24,7 @@ ALL_KERNELS = registry.names()
 def test_all_families_registered():
     assert set(ALL_KERNELS) == {"linrec", "lif", "lifrec", "alif", "alifrec",
                                 "spikemm", "spikemm_gather", "attention",
-                                "stdp", "stdp_seq"}
+                                "stdp", "stdp_seq", "event_push"}
     for name in ALL_KERNELS:
         spec = registry.get(name)
         assert spec.make_inputs is not None, name
