@@ -4,18 +4,20 @@ of arriving currents (ROADMAP Reach 3).
 A group of populations joined by `FanOut` edges (a delay per synapse,
 `core/topology.py`) is laid out once as one table over the group's
 neurons (`build_event_tables`, on the host): neuron j's synapses, its
-edges' live entries one after another, are the `row_count[j]` entries
-from `row_start[j]` of two flat arrays, each entry packed
-`(delay << shift) | target` int32 beside a float32 weight. Rows hold no
-padding; a row is read in `seg_count[j]` windows of `width` entries, the
-last one cut at the row's end. A step then costs what its spikes fan out
+edges' live entries one after another, fill the `seg_count[j]` rows from
+`win_start[j]` of a table of windows, `width` entries a row. A row holds
+lanes `[0, width)` packed `(delay << shift) | target` and lanes
+`[width, 2 * width)` the float32 weights' bits; a neuron's last window
+is padded to the row's end with inert entries (weight 0), and spare
+rows of them end the table, which holds `ceil(entries / width) + N`
+rows whatever the rows' lengths. A step then costs what its spikes fan out
 to, not what the table holds:
 
   1. rank: every spiking neuron contributes its windows; a cumulative
      sum over the neurons ranks them, and a pass takes the next `cap`
      windows (window r belongs to the neuron whose cumulative count
      first exceeds r: the count of cumulative sums <= r);
-  2. gather those windows (contiguous slices of `width`);
+  2. gather those windows: one row gather of the table;
   3. scatter-add their weights into the flat ring at slot
      (t + delay) mod R.
 
@@ -27,10 +29,11 @@ The whole push is one jitted function, `event_push_xla`: its device work
 runs under the name scope `event_push`, and its only scatter is the push
 into the ring.
 
-The plain oracle (`ref.py`) pushes every entry of the table weighted by
-its neuron's spike. Dispatch follows the registry: the ranking push is
-this family's accelerator implementation (XLA operations, no Pallas
-kernel), the oracle runs where the registry picks the reference.
+The plain oracle (`ref.py`) pushes every live lane of every window
+weighted by its neuron's spike. Dispatch follows the registry: the
+ranking push is this family's accelerator implementation (XLA
+operations, no Pallas kernel), the oracle runs where the registry picks
+the reference.
 """
 
 from __future__ import annotations
@@ -51,13 +54,12 @@ SEGMENT = 512              # entries a window (a pass gathers whole windows)
 
 
 class EventTables(NamedTuple):
-    """A group's fan-out table, flat (see the module docstring)."""
+    """A group's fan-out table of windows (see the module docstring)."""
 
-    row_start: Array     # (N,) int32: neuron j's first entry
+    win_start: Array     # (N,) int32: neuron j's first window (row)
     row_count: Array     # (N,) int32: its synapses
     seg_count: Array     # (N,) int32: its windows, ceil(row_count / width)
-    flat_pk: Array       # (entries + width,) int32: (delay << shift) | target
-    flat_w: Array        # (entries + width,) float32
+    windows: Array       # (ceil(entries / width) + N, 2 * width) int32
 
 
 class GroupTables(NamedTuple):
@@ -98,7 +100,7 @@ def _source_rows(edges, shift: int):
 
 def build_event_tables(sources: Sequence[Tuple[int, Sequence]],
                        n_total: int, width: int = SEGMENT) -> GroupTables:
-    """The flat table of a group of `n_total` neurons.
+    """The table of windows of a group of `n_total` neurons.
 
     sources: one (rows, [(FanOut, target offset), ...]) per population in
     the group's order, its rows the population's neurons and its edges
@@ -111,28 +113,37 @@ def build_event_tables(sources: Sequence[Tuple[int, Sequence]],
     if longest >= 1 << (31 - shift):
         raise ValueError(f"delay {longest} does not fit beside {shift} "
                          "target bits in an int32")
-    pks, ws, counts = [], [], []
-    for rows, edges in sources:
-        if edges:
-            pk, w, cnt = _source_rows(edges, shift)
-            pks.append(pk)
-            ws.append(w)
-        else:
-            cnt = np.zeros(rows, np.int32)
-        counts.append(cnt)
-    cnt = np.concatenate(counts)
-    total = int(cnt.sum(dtype=np.int64))
-    if total + width >= 1 << 31:
-        raise ValueError(f"{total} synapses do not fit int32 offsets")
-    # a window may start at any entry: `width` spare entries keep the last
-    # one whole (a dynamic slice past the end would be moved back)
-    pks.append(np.full(width, 1 << shift, np.int32))
-    ws.append(np.zeros(width, np.float32))
-    start = np.concatenate([[0], np.cumsum(cnt)[:-1]]).astype(np.int32)
-    tables = EventTables(jnp.asarray(start), jnp.asarray(cnt),
-                         jnp.asarray(-(-cnt // width)),
-                         jnp.asarray(np.concatenate(pks)),
-                         jnp.asarray(np.concatenate(ws)))
+    rows = [_source_rows(edges, shift) if edges
+            else (None, None, np.zeros(n, np.int32)) for n, edges in sources]
+    cnt = np.concatenate([r[2] for r in rows])
+    segs = -(-cnt // width)
+    start = np.concatenate([[0], np.cumsum(segs, dtype=np.int64)])
+    # more rows than any layout of these entries over these neurons takes
+    # (each neuron pads less than one window), so the table's shape, and
+    # the compiled push, follow the group's sizes, not how a draw spread
+    # its synapses; a new draw of the same sizes compiles nothing anew
+    n_rows = -(-int(cnt.sum(dtype=np.int64)) // width) + cnt.shape[0]
+    if n_rows >= 1 << 31:
+        raise ValueError(f"{n_rows} windows do not fit int32 rows")
+    # padding lanes, and the spare rows a pass reads past its last
+    # window, deliver nothing: weight 0 at delay 1 to neuron 0
+    table = np.zeros((n_rows, 2 * width), np.int32)
+    table[:, :width] = 1 << shift
+    k = np.arange(width)
+    lo = 0
+    for pk, w, c in rows:
+        hi = lo + c.shape[0]
+        if pk is not None:
+            a, b = int(start[lo]), int(start[hi])
+            owner = np.repeat(np.arange(lo, hi), segs[lo:hi])
+            nth = np.arange(a, b) - start[owner]
+            live = nth[:, None] * width + k[None, :] < cnt[owner][:, None]
+            table[a:b, :width][live] = pk
+            table[a:b, width:][live] = w.view(np.int32)
+        lo = hi
+    tables = EventTables(jnp.asarray(start[:-1].astype(np.int32)),
+                         jnp.asarray(cnt), jnp.asarray(segs.astype(np.int32)),
+                         jnp.asarray(table))
     return GroupTables(tables, shift, width)
 
 
@@ -141,8 +152,9 @@ def event_push_xla(ring, spikes, t, tables, *, shift: int, width: int,
                    cap: int):
     """The ranking push (see the module docstring)."""
     R, M = ring.shape
-    N = tables.row_start.shape[0]
+    N = tables.win_start.shape[0]
     B = M // N
+    spare = tables.windows.shape[0] - 1
     with jax.named_scope("event_push"):
         s = spikes > 0
         segs = jnp.where(s, jnp.tile(tables.seg_count, B), 0)
@@ -163,11 +175,10 @@ def event_push_xla(ring, spikes, t, tables, *, shift: int, width: int,
             m = jnp.where(valid, m, 0)
             j, b = m % N, m // N
             nth = r - (csum[m] - segs[m])                    # of j's windows
-            first = tables.row_start[j] + nth * width
-            pk = jax.vmap(lambda a: jax.lax.dynamic_slice(
-                tables.flat_pk, (a,), (width,)))(first)
-            w = jax.vmap(lambda a: jax.lax.dynamic_slice(
-                tables.flat_w, (a,), (width,)))(first)
+            first = jnp.where(valid, tables.win_start[j] + nth, spare)
+            win = tables.windows[first]                      # (cap, 2 width)
+            pk = win[:, :width]
+            w = jax.lax.bitcast_convert_type(win[:, width:], jnp.float32)
             cut = tables.row_count[j] - nth * width      # the row's end
             live = valid[:, None] & (k[None, :] < cut[:, None])
             slot = (t + (pk >> shift)) % R
@@ -254,7 +265,7 @@ registry.register(registry.KernelSpec(
     block_axes=(registry.BlockAxis("cap", "M", preferred=32, align=8),),
     dims_of=lambda ring, spikes, t, tables, *rest: {
         "R": ring.shape[0], "M": ring.shape[1],
-        "N": tables.row_start.shape[0]},
+        "N": tables.win_start.shape[0]},
     candidates=({"cap": 16}, {"cap": 32}, {"cap": 64}),
     make_inputs=_make_inputs,
     diff_argnums=(),

@@ -1,8 +1,10 @@
-"""Plain oracle of the event-driven INTEG step: every entry of the table,
-weighted by its neuron's spike, added into the arrival ring."""
+"""Plain oracle of the event-driven INTEG step: every live lane of every
+window of the table, weighted by the spike of the window's neuron, added
+into the arrival ring."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -14,18 +16,23 @@ def event_push_ref(ring, spikes, t, tables, *, shift: int, width: int,
     step's spiking neurons hold more than `cap` windows (a ranking push
     needs an extra pass)."""
     R, M = ring.shape
-    N = tables.row_start.shape[0]
+    N = tables.win_start.shape[0]
     B = M // N
-    e = jnp.arange(tables.flat_pk.shape[0])
-    # the last neuron whose row starts at or before the entry; the entry
-    # is its if it lies before that row's end (else a spare one)
-    owner = jnp.searchsorted(tables.row_start, e, side="right") - 1
-    live = e < tables.row_start[owner] + tables.row_count[owner]
+    win = jnp.arange(tables.windows.shape[0])
+    # the last neuron whose windows start at or before this one; a lane
+    # is its if it lies before that row's end (else padding or a spare)
+    owner = jnp.searchsorted(tables.win_start, win, side="right") - 1
+    nth = win - tables.win_start[owner]
+    k = jnp.arange(width)
+    live = (nth[:, None] * width + k[None, :]
+            < tables.row_count[owner][:, None])
+    pk = tables.windows[:, :width]
+    w = jax.lax.bitcast_convert_type(tables.windows[:, width:], jnp.float32)
     s = spikes.reshape(B, N).astype(ring.dtype)
-    wb = jnp.where(live[None], tables.flat_w[None] * s[:, owner], 0.0)
-    slot = (t + (tables.flat_pk >> shift)) % R
-    tgt = tables.flat_pk & ((1 << shift) - 1)
-    b = jnp.arange(B)[:, None]
+    wb = jnp.where(live[None], w[None] * s[:, owner, None], 0.0)
+    slot = (t + (pk >> shift)) % R
+    tgt = pk & ((1 << shift) - 1)
+    b = jnp.arange(B)[:, None, None]
     out = ring.reshape(R, B, N).at[slot[None], b, tgt[None]].add(wb)
     fired = s > 0
     n = jnp.sum(fired, dtype=jnp.int32)

@@ -187,16 +187,18 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
 
 def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
     """The jitted 200-step stream chunk of the Potjans-Diesmann circuit at
-    its published sizes (77,169 neurons, 2.99e8 synapses, 2.4 GB of
-    table): the whole EVENT segment, with the push's scatter into the
-    flat ring."""
+    its published sizes (77,169 neurons, 2.99e8 synapses in a 2.7 GB
+    table of windows): the whole EVENT segment, with the push's scatter
+    into the flat ring, and the pass's windows fetched without a loop."""
     from repro.core import events, microcircuit as mc, plan as plan_mod
     from repro.core.neuron import IafPscExp
     from repro.core.snn_layers import ff_integrate
     from repro.core.topology import encode_fanout
     from repro.kernels.event.ops import EventTables, GroupTables
 
-    n, R, T, syn, W = mc.N_FULL, 64, 200, 298_880_968, 512
+    # 298,880,968 synapses in rows of 512-entry windows: the builder's
+    # table holds ceil(synapses / 512) + N rows (~623,000 of them used)
+    n, R, T, W, syn = mc.N_FULL, 64, 200, 512, 298_880_968
     neuron = IafPscExp(bg_weight=87.81)
     nodes = []
     for q, name in enumerate(mc.POPULATIONS):
@@ -221,8 +223,8 @@ def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
               for q, name in enumerate(mc.POPULATIONS)}
     i32 = jnp.int32
     tables = GroupTables(EventTables(
-        S((N,), i32), S((N,), i32), S((N,), i32), S((syn + W,), i32),
-        S((syn + W,), f32)), 17, W)
+        S((N,), i32), S((N,), i32), S((N,), i32),
+        S((-(-syn // W) + N, 2 * W), i32)), 17, W)
     state = {name: {"v": S((1, n[q]), f32), "i": S((1, n[q]), f32),
                     "ref": S((1, n[q]), i32), "out": S((1, n[q]), f32),
                     "t": S((), i32), "arr": S((R, 1, n[q]), f32)}
@@ -242,5 +244,18 @@ def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
     loops = re.findall(rf"^\s*%while[.\d]* = .* while\(.*body=%{re.escape(head)}\b",
                        text, re.M)
     assert len(loops) == 1, f"the scatter's computation {head} is no loop body"
+    # the pass fetches its windows with one gather: no loop runs inside
+    # the pass loop's body, nor in any computation it calls
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.-]+) [^\n]*\{$(.*?)^\}$", text, re.M | re.S)}
+    seen, todo = set(), [head]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        assert not re.search(r" while\(", comps[c]), f"a loop in {c}"
+        todo += [k for k in re.findall(r"%([\w.-]+)", comps[c])
+                 if k in comps]
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 4e9

@@ -258,6 +258,81 @@ def test_burst_past_the_cap_drops_nothing_and_counts_the_pass():
     assert int(stats[2]) == 0
 
 
+def _rows_of(counts, n_post, seed):
+    """A FanOut whose row j holds counts[j] random synapses."""
+    rng = np.random.default_rng(seed)
+    pre = np.repeat(np.arange(len(counts)), counts)
+    m = pre.shape[0]
+    return encode_fanout(pre, rng.integers(0, n_post, m),
+                         rng.normal(size=m).astype(np.float32),
+                         rng.integers(1, 6, m), len(counts), n_post)
+
+
+@pytest.mark.parametrize("count", [0, 1, 7, 8, 9])
+def test_window_table_holds_each_row_whole_and_aligned(count):
+    """Windows of 8 entries: neuron 2 holds `count` synapses over two
+    edges (a row of 0, 1, width - 1, width, width + 1), between neighbours
+    of other lengths. Its row starts on a window of its own, reads back
+    the edges' live entries in edge order, and pads with weight 0. The
+    table's rows follow the entries and neurons alone."""
+    width, n = 8, 6
+    first = [5, 0, count // 2, 8, 0, 11]
+    second = [3, 0, count - count // 2, 0, 2, 9]
+    edges = [(_rows_of(first, n, 1), 0), (_rows_of(second, 4, 2), n)]
+    g = build_event_tables([(n, edges)], n + 4, width=width)
+    tab = g.tables
+    cnt = np.asarray(tab.row_count)
+    segs = np.asarray(tab.seg_count)
+    start = np.asarray(tab.win_start)
+    windows = np.asarray(tab.windows)
+    np.testing.assert_array_equal(cnt, np.add(first, second))
+    np.testing.assert_array_equal(segs, -(-cnt // width))
+    # consecutive rows, each from a window boundary, then spare windows
+    np.testing.assert_array_equal(start, np.cumsum(segs) - segs)
+    assert windows.shape == (-(-cnt.sum() // width) + n, 2 * width)
+    j = 2
+    rows = windows[start[j]:start[j] + segs[j]]
+    pk = rows[:, :width].reshape(-1)
+    w = rows[:, width:].reshape(-1).view(np.float32)
+    want_pk, want_w = [], []
+    for topo, off in edges:
+        c = int(np.asarray(topo.counts)[j])
+        d = np.asarray(topo.delays)[j, :c].astype(np.int32)
+        t = np.asarray(topo.targets)[j, :c].astype(np.int32) + off
+        want_pk.append((d << g.shift) | t)
+        want_w.append(np.asarray(topo.weights)[j, :c])
+    np.testing.assert_array_equal(pk[:count], np.concatenate(want_pk))
+    np.testing.assert_array_equal(w[:count], np.concatenate(want_w))
+    # padding lanes, and the spare windows, deliver nothing
+    spare = windows[segs.sum():]
+    assert not w[count:].any() and not spare[:, width:].any()
+    assert (pk[count:] == 1 << g.shift).all()
+    assert (spare[:, :width] == 1 << g.shift).all()
+
+
+@pytest.mark.parametrize("cap", [8, 16])
+def test_push_matches_the_oracle_over_passes(cap):
+    """A burst of 50 spikes at windows of 8 takes several passes at `cap`
+    8 and 16; the ring and the stats equal the oracle's."""
+    rng = np.random.default_rng(4)
+    n, m = 200, 3000
+    topo = encode_fanout(rng.integers(0, n, m), rng.integers(0, n, m),
+                         rng.normal(size=m).astype(np.float32),
+                         rng.integers(1, 6, m), n, n)
+    g = build_event_tables([(n, [(topo, 0)])], n, width=8)
+    ring = jax.random.normal(jax.random.PRNGKey(0), (8, n))
+    spikes = jnp.zeros((n,)).at[::4].set(1.0)
+    segs = int(np.asarray(g.tables.seg_count)[::4].sum())
+    assert segs > 2 * cap                          # three passes or more
+    got = event_push(ring, spikes, jnp.int32(6), g.tables, shift=g.shift,
+                     width=g.width, cap=cap, force=True)
+    want = event_push(ring, spikes, jnp.int32(6), g.tables, shift=g.shift,
+                      width=g.width, cap=cap)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               atol=1e-5)
+    assert list(np.asarray(got[1])) == list(np.asarray(want[1]))
+
+
 # ---------------------------------------------------------------------------
 # the IR and the encoding
 # ---------------------------------------------------------------------------
