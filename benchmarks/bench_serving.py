@@ -8,8 +8,8 @@ ragged arrival trace — N concurrent sessions with staggered arrival
 times, uneven stream lengths, and uneven chunk sizes — through both
 engines and times the whole serve (admission -> cohort windows -> drain):
 
-  * `BatchedEngine` (capacity-C cohorts, the continuous-batching path)
-  * `NaiveEngine`   (same scheduler/cache/semantics, B=1 windows)
+  * batched: capacity-C cohorts, the continuous-batching path
+  * naive:   the same engine at capacity 1, one session a window
 
 Timing is paired-adjacent (batched/naive alternating), median per-pair
 ratio as the speedup — the same noise discipline as `bench_snn_engine`.
@@ -64,13 +64,12 @@ def _trace(n_sessions: int, seed: int = 0
     return ev
 
 
-def _drive(kind: str, nodes, params, trace, cache_bytes=None):
+def _drive(capacity: int, nodes, params, trace, cache_bytes=None):
     """Replay the trace through one engine; returns (wall_s, engine)."""
     eng = make_engine(nodes, params,
-                      EngineConfig(window=WINDOW, capacity=CAPACITY,
+                      EngineConfig(window=WINDOW, capacity=capacity,
                                    queue_limit=None,
-                                   cache_bytes=cache_bytes),
-                      kind=kind)
+                                   cache_bytes=cache_bytes))
     last_round: Dict[str, int] = {}
     for r, sid, _ in trace:
         last_round[sid] = max(last_round.get(sid, -1), r)
@@ -97,8 +96,8 @@ def measure(repeats: int = 3) -> Dict:
     total_steps = sum(len(c) for _, _, c in trace)
 
     # warm both resident steps (compile outside the timed region)
-    _, eb = _drive("batched", nodes, params, trace)
-    _, en = _drive("naive", nodes, params, trace)
+    _, eb = _drive(CAPACITY, nodes, params, trace)
+    _, en = _drive(1, nodes, params, trace)
 
     # cross-engine parity on a few sessions (allclose, see module doc)
     max_err = 0.0
@@ -110,8 +109,8 @@ def measure(repeats: int = 3) -> Dict:
 
     tb, tn, ratios = [], [], []
     for _ in range(repeats):
-        t1, eng_b = _drive("batched", nodes, params, trace)
-        t2, _ = _drive("naive", nodes, params, trace)
+        t1, eng_b = _drive(CAPACITY, nodes, params, trace)
+        t2, _ = _drive(1, nodes, params, trace)
         tb.append(t1)
         tn.append(t2)
         ratios.append(t2 / t1)
@@ -148,7 +147,7 @@ def measure_cache_pressure() -> Dict:
     fp = session_footprint(nodes, params)
     trace = _trace(N_SESSIONS)
     budget = (N_SESSIONS // 2) * fp
-    t, eng = _drive("batched", nodes, params, trace, cache_bytes=budget)
+    t, eng = _drive(CAPACITY, nodes, params, trace, cache_bytes=budget)
     snap = eng.stats()
     return {
         "cache_bytes": budget,

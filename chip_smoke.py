@@ -19,8 +19,9 @@ reference quietly, and the incident log must stay empty after every phase.
      compressed topology streamed through `plan.run_stream`.
 
 Every served result is compared with the fp32 stepper reference:
-`events.run` (the stepper engine, XLA reference kernels) on the same chip
-under `jax.default_matmul_precision("highest")`. The served path runs at
+`plan.run_reference` (`events.run` and the same learning pass, XLA
+reference kernels) on the same chip under
+`jax.default_matmul_precision("highest")`. The served path runs at
 the default precision. Its kernels contract f32 operands at full f32
 precision (`repro.kernels.common.f32_precision`); the bounds below allow
 for the bf16 operand rounding that the default precision would apply.
@@ -132,16 +133,20 @@ def spikes(rng, shape, density) -> np.ndarray:
 
 
 def run_windows(nodes, params, x, window, *, learn=False, jit=False,
-                hidden="hidden"):
-    """`plan.run` over consecutive windows of x (T, B, n_in), carrying state
-    and (when learning) the chunked-online weights. Returns (outputs,
-    hidden spikes, final state) as numpy / a state tree."""
+                hidden="hidden", reference=False):
+    """`plan.run` (or, with `reference`, `plan.run_reference`) over
+    consecutive windows of x (T, B, n_in), carrying state and (when
+    learning) the chunked-online weights. Returns (outputs, hidden spikes,
+    final state) as numpy / a state tree."""
     from repro.core import events, plasticity
     from repro.core import plan as plan_mod
 
     plan = plan_mod.compile_program(nodes)
 
     def one(p, xw, st):
+        if reference:
+            return plan_mod.run_reference(nodes, p, xw, state=st,
+                                          record=(hidden,), learn=learn)
         return plan_mod.run(nodes, p, xw, state=st, plan=plan,
                             record=(hidden,), learn=learn)
 
@@ -158,13 +163,13 @@ def run_windows(nodes, params, x, window, *, learn=False, jit=False,
 
 def reference_windows(nodes, params, x, window, *, learn=False,
                       hidden="hidden"):
-    """The fp32 stepper reference: `events.run` (via the stepper engine, so
-    a plastic model gets the same chunked-online learning pass) with XLA
-    reference kernels and full-precision f32 matmuls."""
-    with env(REPRO_SNN_ENGINE="stepper", REPRO_KERNEL_IMPL="ref"), \
+    """The fp32 stepper reference: `plan.run_reference` (`events.run`,
+    then for a plastic model the same chunked-online learning pass) with
+    XLA reference kernels and full-precision f32 matmuls."""
+    with env(REPRO_KERNEL_IMPL="ref"), \
             jax.default_matmul_precision("highest"):
         return run_windows(nodes, params, x, window, learn=learn,
-                           hidden=hidden)
+                           hidden=hidden, reference=True)
 
 
 def li_filter(x: np.ndarray, tau: float) -> np.ndarray:

@@ -48,7 +48,7 @@ print(f"topology: {enc.storage_bits()/8:.0f} B encoded vs "
 # hidden layer pattern-matches the adaptive-threshold kernel (fused_rec via
 # `alifrec`), the LI readout the associative `linrec` scan — no stepper
 # fallback. `plan.run` is a drop-in for `events.run` (same signature and
-# numerics; REPRO_SNN_ENGINE=stepper brings the interpreted engine back).
+# numerics); `plan.run_reference` runs the interpreted stepper instead.
 compiled = plan.compile_program(nodes)
 print(f"plan: {compiled.describe()}")
 x = (jax.random.uniform(key, (100, 8, n_in)) < 0.05).astype(jnp.float32)
@@ -57,6 +57,9 @@ _, outs, recs = plan.run(nodes, params, x, record=("hidden",),
 rate = float(jnp.mean(recs["hidden"]))
 print(f"ran 100 INTEG/FIRE timesteps: hidden spike rate {rate:.1%}, "
       f"readout shape {outs.shape}")
+_, ref_outs, _ = plan.run_reference(nodes, params, x)
+print(f"max |plan - stepper reference| on the readout: "
+      f"{float(jnp.max(jnp.abs(outs - ref_outs))):.1e}")
 
 # 4. compile onto the chip grid
 ops = [Op("hidden", "fc", n_hidden, n_in + n_hidden, ("input",)),

@@ -36,9 +36,9 @@ update term applied over the window with the weight tile VMEM-resident —
 while unmatched rules (learned decays, oversized programs) run through the
 parity-checked per-step fallback (`plasticity.synapse_step` scanned over
 the realized spike trains). Either way, on-chip learning runs *inside*
-`plan.run` (and, forced to `REPRO_SNN_ENGINE=stepper`, as the same pass
-after the interpreted forward): within one run window the forward uses the
-entry weights, and the learned weight + final traces are published in
+`plan.run` (and, in `run_reference`, as the same pass after the
+interpreted forward): within one run window the forward uses the entry
+weights, and the learned weight + final traces are published in
 `state[node]["syn:<conn>"]` (chunked-online semantics; merge with
 `plasticity.apply_learned` between windows).
 
@@ -61,17 +61,19 @@ the source's full output tensor, seeded from the initial ring state.
 
 Capability checks keep the compiler conservative: a Program where any node
 reads a *later* node (previous-timestep semantics) compiles to a single
-whole-program fallback segment, i.e. exactly `events.run`. Every Program
+whole-program fallback segment, stepped like any other. Every Program
 runs; fusable ones run fast.
+
+`run_reference` is the fp32 reference the plan is held to: the whole
+Program through the stepper (`events.run`), with the same boundary faults,
+guards and learning pass as `run`.
 
 Every fallback decision carries a stable TB2xx diagnostic code next to
 its prose reason (`Segment.codes` / `PlasticLower.code`), so
 `Plan.describe()` is machine-checkable and `repro.analysis` can explain
 fusion without re-deriving the classifier.
 
-Env knobs: REPRO_SNN_ENGINE = plan | stepper | auto (auto = plan; set
-`stepper` to force the interpreted engine, e.g. when bisecting a numerics
-difference). REPRO_SNN_EXPLAIN=1 prints every compiled segment schedule
+Env knobs: REPRO_SNN_EXPLAIN=1 prints every compiled segment schedule
 (`Plan.describe()`) as Programs are lowered. REPRO_CHECK = off | warn |
 raise runs the full `repro.analysis` checker over each compiled Program:
 `warn` routes warning+ findings onto the kernel incident log
@@ -95,6 +97,7 @@ import numpy as np
 
 from repro.core import events, faults, guards, plasticity
 from repro.core.neuron import Decay, NeuronProgram, decay_array
+from repro.spanlog import span
 # note: `repro.kernels` re-exports an `incidents()` *function*, which
 # shadows the submodule on the package namespace — import names directly
 from repro.kernels.incidents import FallbackEvent, record as _record_incident
@@ -136,14 +139,6 @@ SYN_STEP = "step"
 # encoded here instead: ~7x margin over the worst observed drift. Use
 # this constant — not ad-hoc atol literals — whenever comparing engines.
 CROSS_ENGINE_ATOL = 1e-5
-
-
-def engine_mode() -> str:
-    mode = os.environ.get("REPRO_SNN_ENGINE", "auto")
-    if mode not in ("auto", "plan", "stepper"):
-        raise ValueError(f"REPRO_SNN_ENGINE={mode!r}: "
-                         "expected 'plan', 'stepper', or 'auto'")
-    return mode
 
 
 def check_mode() -> str:
@@ -874,17 +869,19 @@ def _run_event(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
 #
 # A `plan.run` state tree is {node: {key: array}} where every per-neuron
 # leaf carries the batch on axis 0 — except the delay ring, whose layout is
-# (depth, batch, n). The serve engine (repro.serve) keeps many batch-1
+# (depth, batch, n), and the synapse entries ("syn:<conn>", a dict each),
+# whose weight plane has NO batch axis (one tile per connection,
+# batch-summed updates). The serve engine (repro.serve) keeps many batch-1
 # streaming sessions in one device-resident pool per leaf, a row per
-# session along this batch axis, and gathers a cohort's rows into one
-# batched state inside its resident step. Synapse ("syn:") entries stay
-# out of the pool: their weight plane has NO batch axis (one tile per
-# connection, batch-summed updates), so batching sessions that learn would
-# alias their weights — the engine keeps those per-session and runs the
-# learning path vmapped instead.
+# session along the batch axis; a synapse entry's leaves get a leading row
+# axis of their own, so every learning session keeps its own weights.
 
 
-def state_batch_axis(key: str) -> int:
+def state_batch_axis(key: str) -> Optional[int]:
+    """The batch axis of the entry `key` of a node's state; None for a
+    synapse entry, which has none."""
+    if key.startswith("syn:"):
+        return None
     return 1 if key == "ring" else 0
 
 
@@ -1054,58 +1051,27 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
 
     Resilience hooks: active faults (`REPRO_FAULTS` / `faults.inject`)
     perturb the input raster and weight planes once at entry and node
-    outputs inside both engines, identically. `guard` enables numerical
-    guardrails (`core/guards.py`) — a policy string off|warn|raise|sanitize
-    or a full `GuardConfig`; None defers to `REPRO_GUARD` (default off).
+    outputs inside every segment, as in `run_reference`. `guard` enables
+    numerical guardrails (`core/guards.py`) — a policy string
+    off|warn|raise|sanitize or a full `GuardConfig`; None defers to
+    `REPRO_GUARD` (default off).
 
     EVENT segments build their fan-out tables from the connections' arrays
     on each call unless `prepared` (from `prepare`) holds them; under a
     caller's jit, pass `prepared` as an argument, or the tables become
     constants of the compiled program.
     """
-    mode = engine_mode()
     if plan is None:
         plan = compile_program(nodes)
     gcfg = guards.config(guard)
-    do_learn = learn and bool(plan.plastic)
     nodes_by_name = {n.name: n for n in nodes}
     T, B = x.shape[0], x.shape[1]
 
-    # injected faults hit the run boundary once, before either engine (and
-    # before init_state seeds plastic synapses), so both see the same world
+    # injected faults hit the run boundary once, before init_state seeds
+    # plastic synapses, exactly as in `run_reference`
     x = faults.perturb_input(x)
     params = faults.perturb_params(params)
     x = guards.check_tensor("input", x, gcfg)
-
-    if mode == "stepper" or plan.fully_fallback:
-        if not do_learn:
-            final, out, recs = events.run(nodes, params, x, state, record)
-            out = guards.check_tensor(f"{nodes[-1].name}.out", out, gcfg)
-            if gcfg.active and _is_spiking(nodes[-1]):
-                guards.check_spikes(nodes[-1].name, out, gcfg)
-            return final, out, recs
-        # interpreted forward, then the same learning pass over the
-        # realized spike trains (record what the plastic conns need)
-        if state is None:
-            state = events.init_state(nodes, B, x.dtype, params)
-        needed = set(record)
-        for p in plan.plastic:
-            needed.add(p.node)
-            conn = next(c for c in nodes_by_name[p.node].connections
-                        if c.key == p.conn)
-            if conn.src not in ("input", "self"):
-                needed.add(conn.src)
-        final, out, recs = events.run(nodes, params, x, state, tuple(needed))
-        out = guards.check_tensor(f"{nodes[-1].name}.out", out, gcfg)
-        if gcfg.active and _is_spiking(nodes[-1]):
-            guards.check_spikes(nodes[-1].name, out, gcfg)
-        outs = dict(recs)
-        outs["input"] = x
-        outs[nodes[-1].name] = out
-        new_state = dict(final)
-        _learn_pass(plan, nodes, params, outs, state, new_state,
-                    T, B, mod, gcfg)
-        return new_state, out, {r: outs[r] for r in record}
 
     if state is None:
         state = events.init_state(nodes, B, x.dtype, params)
@@ -1130,11 +1096,54 @@ def run(nodes: List[events.LayerNode], params: Dict[str, Any], x: Array,
         else:
             _run_fused(nodes_by_name[seg.names[0]], seg.kind, seg.lower,
                        params, outs, state, new_state, T, B, gcfg)
-    if do_learn:
+    if learn and plan.plastic:
         _learn_pass(plan, nodes, params, outs, state, new_state,
                     T, B, mod, gcfg)
     recs = {r: outs[r] for r in record}
     return new_state, outs[nodes[-1].name], recs
+
+
+def run_reference(nodes: List[events.LayerNode], params: Dict[str, Any],
+                  x: Array, state: Optional[Dict[str, Any]] = None,
+                  record: Tuple[str, ...] = (), mod: Optional[Array] = None,
+                  learn: bool = True,
+                  guard: Union[None, str, guards.GuardConfig] = None):
+    """The fp32 reference `run` is held to: the whole Program through the
+    stepper (`events.run`), one timestep at a time, then the same
+    learning pass over the realized spike trains. Same signature and
+    return as `run` (without `plan` and `prepared`), the same faults at
+    the run boundary, the same guards on the last node's outputs."""
+    plan = compile_program(nodes)
+    gcfg = guards.config(guard)
+    nodes_by_name = {n.name: n for n in nodes}
+    T, B = x.shape[0], x.shape[1]
+    x = faults.perturb_input(x)
+    params = faults.perturb_params(params)
+    x = guards.check_tensor("input", x, gcfg)
+    do_learn = learn and bool(plan.plastic)
+    if state is None:
+        state = events.init_state(nodes, B, x.dtype, params)
+    # record what the plastic connections' rules read
+    needed = set(record)
+    for p in plan.plastic if do_learn else ():
+        needed.add(p.node)
+        conn = next(c for c in nodes_by_name[p.node].connections
+                    if c.key == p.conn)
+        if conn.src not in ("input", "self"):
+            needed.add(conn.src)
+    final, out, recs = events.run(nodes, params, x, state, tuple(needed))
+    out = guards.check_tensor(f"{nodes[-1].name}.out", out, gcfg)
+    if gcfg.active and _is_spiking(nodes[-1]):
+        guards.check_spikes(nodes[-1].name, out, gcfg)
+    if not do_learn:
+        return final, out, recs
+    outs = dict(recs)
+    outs["input"] = x
+    outs[nodes[-1].name] = out
+    new_state = dict(final)
+    _learn_pass(plan, nodes, params, outs, state, new_state,
+                T, B, mod, gcfg)
+    return new_state, out, {r: outs[r] for r in record}
 
 
 class StreamSpikes(NamedTuple):
@@ -1242,22 +1251,20 @@ def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
     reads back only those; a chunk with more reads every word, so no
     spike is lost. Both modes log the spans `sim.chunk` (the call to its
     outputs on the host, with the counters as ids in list mode) and, in
-    list mode, `sim.readback` (`repro.serve.metrics.span`).
+    list mode, `sim.readback` (`repro.spanlog.span`).
     """
-    from repro.serve import metrics as _metrics
     if plan is None:
         plan = compile_program(nodes)
     if spikes not in ("dense", "list"):
         raise ValueError(f"spikes={spikes!r}: expected 'dense' or 'list'")
     listed = spikes == "list"
     if listed and not (len(plan.segments) == 1
-                       and plan.segments[0].kind == EVENT
-                       and engine_mode() != "stepper"):
+                       and plan.segments[0].kind == EVENT):
         raise ValueError("spikes='list' steps a program that compiles to "
                          f"one EVENT segment, not {plan.describe()}")
     gcfg = guards.config(guard)
     eager = gcfg.policy == "raise" and not listed
-    prepared = prepare(plan, nodes) if engine_mode() != "stepper" else {}
+    prepared = prepare(plan, nodes)
     mods = iter(mod) if mod is not None else None
     if state is not None:
         state = jax.tree_util.tree_map(jnp.copy, state)
@@ -1268,7 +1275,7 @@ def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
         m = next(mods) if mods is not None else None
         if state is None:
             state = events.init_state(nodes, x.shape[1], x.dtype, params)
-        with _metrics.span("sim.chunk", chunk=k) as sp:
+        with span("sim.chunk", chunk=k) as sp:
             if not listed:
                 if eager:
                     state, out, _ = run(nodes, params, x, state=state,
@@ -1281,7 +1288,7 @@ def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
                     t_host = int(state[nodes[0].name]["t"])
                 state, (pos, vals, n_nz, words, stats) = step(
                     params, prepared, state, x)
-                with _metrics.span("sim.readback", chunk=k):
+                with span("sim.readback", chunk=k):
                     n_nz, stats = int(n_nz), np.asarray(stats)
                     if n_nz <= pos.shape[0]:
                         pos, vals = np.asarray(pos)[:n_nz], \
@@ -1302,7 +1309,7 @@ def run_stream(nodes: List[events.LayerNode], params: Dict[str, Any],
 
 
 __all__ = ["Plan", "PlasticLower", "Segment", "compile_program",
-           "engine_mode", "check_mode", "run", "run_stream", "prepare",
+           "check_mode", "run", "run_reference", "run_stream", "prepare",
            "stream_step",
            "event_tables", "StreamSpikes",
            "CROSS_ENGINE_ATOL",

@@ -16,15 +16,14 @@ Two entry points live here:
 
 from repro.serve.loop import ServeConfig, ServeResult, Request, generate
 from repro.serve.client import StreamClient
-from repro.serve.engine import (EngineConfig, BatchedEngine, NaiveEngine,
-                                make_engine)
+from repro.serve.engine import EngineConfig, BatchedEngine, make_engine
 from repro.serve.metrics import Histogram, ServeMetrics
 from repro.serve.scheduler import Scheduler
 from repro.serve.sessions import Session, StateCache
 
 __all__ = [
     "ServeConfig", "ServeResult", "Request", "generate",
-    "EngineConfig", "BatchedEngine", "NaiveEngine", "make_engine",
+    "EngineConfig", "BatchedEngine", "make_engine",
     "Histogram", "ServeMetrics", "Scheduler", "Session", "StateCache",
     "StreamClient",
 ]

@@ -13,41 +13,37 @@ row), runs `plan.run`, and scatters the new state back into the pool,
 which it takes donated and returns. Only the rows, a fresh mask, the
 input block and the output cross the call. Nothing retraces unless the
 pool grows: free slots point at a scratch row and their results are
-discarded.
+discarded. A one-slot engine (`capacity=1`) serves one session at a time.
 
-Two engines share the scheduler/cache/metrics machinery:
-
-  * `BatchedEngine` — the continuous-batching engine. Inference cohorts
-    run the *flat* path (sessions concatenated along the batch axis, the
-    MXU-shaped layout). With `learn=True` on a plastic model, cohorts run
-    a per-session-`vmap`ped window instead: synapse weight planes have no
-    batch axis, so the flat path would batch-sum every tenant's update
-    into one tile — the vmap path keeps each session's learned weights in
-    its own state (entry weights come from the session's last published
-    `syn:` tensors via `plasticity.apply_learned`, the chunked-online
-    contract, per lane).
-    That path keeps a tuple of per-session state trees through its step
-    (and the cache keeps the trees); it shares no code with the pool.
-  * `NaiveEngine` — the one-session-at-a-time baseline: same scheduler,
-    same cache and pool, same semantics, but every served session pays
-    its own one-slot window launch. `bench_serving` measures the gap.
+Inference cohorts run *flat*: sessions side by side along the batch
+axis, the MXU-shaped layout. With `learn=True` on a plastic model the
+pool also holds every session's synapse entries (`syn:`, a row each) and
+the step runs the window once per slot instead: a synapse weight plane
+has no batch axis, so a flat cohort would batch-sum every tenant's update
+into one tile. The learning step gathers the same rows, `vmap`s
+`plan.run(learn=True)` over the slots at batch 1 — each lane's entry
+weights are its session's last published `syn:` tensors
+(`plasticity.apply_learned`, the chunked-online contract), a fresh
+session's the seeded ones of the initial-state row — and scatters the
+result back. Both steps take the same operands, so one cohort path
+assembles, dispatches and reads back either.
 
 Isolation invariant (property-tested): a session's output trajectory and
 final state are bit-identical whether it runs alone, interleaved with
-strangers, or is evicted to host and restored mid-stream. The flat path
+strangers, or is evicted to host and restored mid-stream. The flat step
 earns this because every per-slot computation in the fused kernels is
 row-independent and the executable is shape-fixed (solo and packed
 cohorts run the *same* compiled step, whichever pool rows they hold);
-the vmap path because lanes are independent by construction;
+the learning step because lanes are independent by construction;
 evict/restore because spill is a pure device<->host copy.
 
 Resilience composes: kernel dispatch inside the resident step follows
 the registry's failure policy (off-TPU an interpret-mode failure degrades
 to the reference and is recorded; compiled kernels never degrade),
 `REPRO_FAULTS` / `REPRO_GUARD` thread through
-`plan.run` unchanged. The step cache keys on the ambient
-faults/engine/dispatch environment, so entering a fault context retraces
-instead of silently replaying a clean executable.
+`plan.run` unchanged. The step cache keys on the ambient faults/dispatch
+environment, so entering a fault context retraces instead of silently
+replaying a clean executable.
 """
 
 from __future__ import annotations
@@ -65,11 +61,12 @@ from repro.core import plan as plan_mod
 from repro.serve.metrics import ServeMetrics, span
 from repro.serve.scheduler import Scheduler
 from repro.serve.sessions import (SCRATCH, Row, Session, StateCache,
-                                  StatePool, gather_rows, scatter_rows)
+                                  StatePool, gather_rows, scatter_rows,
+                                  vmap_slots)
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Knobs for the streaming engines.
+    """Knobs for the streaming engine.
 
     window:      scheduling quantum in timesteps (the chunked-online
                  window `plan.run` state round-trips at).
@@ -103,10 +100,10 @@ class EngineConfig:
 # resident step cache
 # ---------------------------------------------------------------------------
 #
-# Jitted window steps are cached per (nodes, path kind, guard, ambient
-# environment). Keys hold id()s of the live node objects — the closures
-# keep those objects alive, so ids cannot be recycled into a collision.
-# The environment fingerprint (engine mode, dispatch pins, active fault
+# Jitted window steps are cached per (nodes, learning or not, guard,
+# ambient environment). Keys hold id()s of the live node objects — the
+# closures keep those objects alive, so ids cannot be recycled into a
+# collision. The environment fingerprint (dispatch pins, active fault
 # spec) is part of the key because `plan.run` resolves all of those at
 # TRACE time: a cached clean-world executable must not be replayed inside
 # a `faults.inject(...)` context.
@@ -115,27 +112,26 @@ _STEP_CACHE: Dict[tuple, Callable] = {}
 
 
 def _env_fingerprint(guard: Optional[str]) -> tuple:
-    return (plan_mod.engine_mode(),
-            os.environ.get("REPRO_KERNEL_IMPL"),
+    return (os.environ.get("REPRO_KERNEL_IMPL"),
             os.environ.get("REPRO_PALLAS_INTERPRET"),
             os.environ.get("REPRO_SPIKEMM_SPARSE"),
             guard if guard is not None else os.environ.get("REPRO_GUARD"),
             faults.active())
 
 
-def _resident_step(nodes, compiled, kind: str,
+def _resident_step(nodes, compiled, learn: bool,
                    guard: Optional[str]) -> Callable:
-    key = (tuple(id(n) for n in nodes), kind, _env_fingerprint(guard))
+    key = (tuple(id(n) for n in nodes), learn, _env_fingerprint(guard))
     fn = _STEP_CACHE.get(key)
     if fn is not None:
         return fn
     nodes = list(nodes)
 
-    # "flat" takes the state pool (donated: updated in place) and the
-    # cohort's rows; its argument count does not grow with the capacity.
-    # "vmap_learn" takes a TUPLE of per-session state trees and returns a
-    # tuple of per-session results, stacked and sliced inside the program.
-    if kind == "flat":
+    # Both steps take the state pool (donated: updated in place), the
+    # cohort's rows and fresh mask and the (W, C, n_in) input, and return
+    # the pool and the (W, C, n_out) output; the argument count does not
+    # grow with the capacity.
+    if not learn:
         def step(params, pool, rows, fresh, x):
             with jax.named_scope("pack"):
                 state = gather_rows(pool, rows, fresh)
@@ -144,28 +140,24 @@ def _resident_step(nodes, compiled, kind: str,
                                       guard=guard)
             with jax.named_scope("unpack"):
                 return scatter_rows(pool, rows, ns), out
-        fn = jax.jit(step, donate_argnums=1)
-    elif kind == "vmap_learn":
-        def step(params, states, x):
+    else:
+        def step(params, pool, rows, fresh, x):
             with jax.named_scope("pack"):
-                st = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
-                                            *states)
+                state = gather_rows(pool, rows, fresh)
 
-            def one(st_i, x_i):
+            def lane(st, x_k):
                 # chunked-online entry weights = the session's last
-                # published learned tensors; fresh sessions carry seeds
-                p = plasticity.apply_learned(nodes, params, st_i)
-                ns, out, _ = plan_mod.run(nodes, p, x_i, state=st_i,
+                # published learned tensors; fresh sessions carry the
+                # seeded ones
+                p = plasticity.apply_learned(nodes, params, st)
+                ns, out, _ = plan_mod.run(nodes, p, x_k, state=st,
                                           plan=compiled, learn=True,
                                           guard=guard)
                 return ns, out
-            ns, out = jax.vmap(one)(st, x)
+            ns, out = vmap_slots(lane, state, x)
             with jax.named_scope("unpack"):
-                return tuple(jax.tree_util.tree_map(lambda l, i=i: l[i], ns)
-                             for i in range(len(states))), out
-        fn = jax.jit(step)
-    else:  # pragma: no cover
-        raise ValueError(f"unknown step kind {kind!r}")
+                return scatter_rows(pool, rows, ns), out
+    fn = jax.jit(step, donate_argnums=1)
     _STEP_CACHE[key] = fn
     return fn
 
@@ -202,14 +194,12 @@ def _pool_operands(handles: List[Row], slots: int):
 
 
 # ---------------------------------------------------------------------------
-# engines
+# the engine
 # ---------------------------------------------------------------------------
 
 
 class BatchedEngine:
     """Continuous-batching multi-tenant engine (see module docstring)."""
-
-    kind = "batched"
 
     def __init__(self, nodes: List[events.LayerNode], params: Dict[str, Any],
                  cfg: EngineConfig = EngineConfig(),
@@ -232,16 +222,14 @@ class BatchedEngine:
         self._step_id = -1          # index of the current (or last) step()
         init = events.init_state(self.nodes, 1, self.dtype, params)
         if self._learn:
-            # each session's tree lives in the cache; free cohort slots
-            # are zero-padded (results discarded)
-            self.pool = None
-            self._zero_full = jax.tree_util.tree_map(jnp.zeros_like, init)
+            # every session learns its own synapses: they are pool rows
+            core, self._syn = init, {}
         else:
             # synapse entries never change on the flat path: held once
             core, self._syn = _split_syn(init)
-            self.pool = StatePool(core, cfg.capacity, metrics=self.metrics)
-        self.cache = StateCache(cfg.cache_bytes, metrics=self.metrics,
-                                store=self.pool)
+        self.pool = StatePool(core, cfg.capacity, metrics=self.metrics)
+        self.cache = StateCache(self.pool, cfg.cache_bytes,
+                                metrics=self.metrics)
 
     def _infer_n_in(self) -> int:
         for n in self.nodes:
@@ -262,12 +250,7 @@ class BatchedEngine:
             self._sid_counter += 1
         with span("serve.open", sid=sid):
             self.scheduler.open(sid)
-            if self.pool is None:
-                state = events.init_state(self.nodes, 1, self.dtype,
-                                          self.params)
-            else:
-                state = Row(self.pool.claim(), True)
-            self.cache.put(sid, state)
+            self.cache.put(sid, Row(self.pool.claim(), True))
         return sid
 
     def submit(self, sid: str, chunk: np.ndarray) -> bool:
@@ -291,10 +274,7 @@ class BatchedEngine:
 
     def state_of(self, sid: str) -> Dict[str, Any]:
         """The session's current state tree (restored to device)."""
-        state = self.cache.get(sid)
-        if self.pool is None:
-            return state
-        return _merge_syn(self.pool.read(state), self._syn)
+        return _merge_syn(self.pool.read(self.cache.get(sid)), self._syn)
 
     def retire(self, sid: str) -> np.ndarray:
         """Drop a finished (or abandoned) session, freeing its state (its
@@ -333,9 +313,6 @@ class BatchedEngine:
                     self.metrics.bump("windows_run")
                     self.metrics.bump("session_windows", len(cohort))
                     self.metrics.bump("steps_run", sp.ids["steps"])
-                    # the replaced states' device buffers (learning path)
-                    # are released here, so the span times their release
-                    del states, new_states, outs
         if not cohort:
             return 0
         self.metrics.window_latency_s.observe(sp.seconds)
@@ -351,23 +328,17 @@ class BatchedEngine:
 
     def stats(self) -> Dict[str, Any]:
         snap = self.metrics.snapshot()
-        snap.update(engine=self.kind, window=self.cfg.window,
-                    capacity=self.cfg.capacity,
+        snap.update(window=self.cfg.window, capacity=self.cfg.capacity,
                     cache_hot_bytes=self.cache.hot_bytes,
                     cache_spilled=len(self.cache.spilled),
-                    pool_rows=self.pool.rows if self.pool else 0,
+                    pool_rows=self.pool.rows,
                     sessions_open=len(self.scheduler.sessions))
         return snap
 
-    # cohort execution — the part engines differ in ------------------------
-
     def _run_cohort(self, cohort: List[Tuple[Session, np.ndarray, int]],
-                    states: List[Any]) -> Tuple[List[Any], List[np.ndarray]]:
-        """One resident-step launch for the cohort: (new states, outputs),
-        one per session. A state is the session's pool `Row`, or on the
-        learning path its tree."""
-        if self._learn:
-            return self._run_learn(cohort, states)
+                    states: List[Row]) -> Tuple[List[Row], List[np.ndarray]]:
+        """One resident-step launch for the cohort: (new pool rows,
+        outputs), one per session."""
         C, W, i = self.cfg.capacity, self.cfg.window, self._step_id
         n_live = len(cohort)
         with span("serve.assemble", step=i):
@@ -380,7 +351,7 @@ class BatchedEngine:
             rows, fresh, x = jnp.asarray(rows), jnp.asarray(fresh), \
                 jnp.asarray(x)
         with span("serve.dispatch", step=i):
-            step = _resident_step(self.nodes, self.plan, "flat",
+            step = _resident_step(self.nodes, self.plan, self._learn,
                                   self.cfg.guard)
             self.pool.leaves, out = step(self.params, self.pool.leaves, rows,
                                          fresh, x)
@@ -393,72 +364,11 @@ class BatchedEngine:
             del x, out
         return news, outs
 
-    def _run_learn(self, cohort, states):
-        """The learning path: a tuple of per-session state trees through
-        the per-session `vmap` step."""
-        C, W, i = self.cfg.capacity, self.cfg.window, self._step_id
-        n_live = len(cohort)
-        with span("serve.assemble", step=i):
-            sts = tuple(states) + (C - n_live) * (self._zero_full,)
-            x = np.zeros((C, W, 1, self.n_in), self.dtype)
-            for k, (_, xw, _) in enumerate(cohort):
-                x[k, :, 0, :] = xw
-            x = jnp.asarray(x)
-        with span("serve.dispatch", step=i):
-            step = _resident_step(self.nodes, self.plan, "vmap_learn",
-                                  self.cfg.guard)
-            ns, out = step(self.params, sts, x)
-        with span("serve.readback", step=i):
-            out_np = np.asarray(out)            # one transfer per window
-            news = list(ns[:n_live])
-            outs = [out_np[k, :, 0, :] for k in range(n_live)]
-            # the input, the output and the free slots' states leave the
-            # device here, so the span times their release
-            del x, out, ns, sts
-        return news, outs
-
-
-class NaiveEngine(BatchedEngine):
-    """One-session-at-a-time baseline: same scheduler, cache, and
-    semantics, but each served session pays its own B=1 window launch —
-    the loop `bench_serving` measures the batching win against."""
-
-    kind = "naive"
-
-    def _run_cohort(self, cohort, states):
-        W = self.cfg.window
-        news: List[Any] = []
-        outs: List[np.ndarray] = []
-        for (sess, xw, _), state in zip(cohort, states):
-            if self._learn:
-                x = jnp.asarray(xw, self.dtype).reshape(1, W, 1, self.n_in)
-                step = _resident_step(self.nodes, self.plan, "vmap_learn",
-                                      self.cfg.guard)
-                ns, out = step(self.params, (state,), x)
-                news.append(ns[0])
-                outs.append(np.asarray(out)[0, :, 0, :])
-            else:
-                # a one-slot cohort through the pool
-                rows, fresh = _pool_operands([state], 1)
-                self.metrics.bump("fresh_resets", int(state.fresh))
-                x = jnp.asarray(xw, self.dtype).reshape(W, 1, self.n_in)
-                step = _resident_step(self.nodes, self.plan, "flat",
-                                      self.cfg.guard)
-                self.pool.leaves, out = step(self.params, self.pool.leaves,
-                                             rows, fresh, x)
-                news.append(Row(state.index, False))
-                outs.append(np.asarray(out)[:, 0, :])
-        return news, outs
-
 
 def make_engine(nodes, params, cfg: EngineConfig = EngineConfig(),
-                kind: str = "batched", **kw) -> BatchedEngine:
-    """Factory: kind = "batched" (continuous batching) | "naive"."""
-    cls = {"batched": BatchedEngine, "naive": NaiveEngine}.get(kind)
-    if cls is None:
-        raise ValueError(f"unknown engine kind {kind!r}; "
-                         "expected 'batched' or 'naive'")
-    return cls(nodes, params, cfg, **kw)
+                **kw) -> BatchedEngine:
+    """The streaming engine over a program: a `BatchedEngine`."""
+    return BatchedEngine(nodes, params, cfg, **kw)
 
 
-__all__ = ["EngineConfig", "BatchedEngine", "NaiveEngine", "make_engine"]
+__all__ = ["EngineConfig", "BatchedEngine", "make_engine"]

@@ -4,30 +4,30 @@ LRU state cache behind the engine.
 A `Session` is one tenant's streaming SNN run: an input buffer of
 not-yet-executed timesteps, an output trail, and — held separately — the
 persistent per-session neuron state that `plan.run` threads between
-windows. On the engine's inference path that state lives in a
-`StatePool`: one device array per state leaf, a row per session, read and
-written only inside the resident step, so a cohort crosses the jit
-boundary as a row vector instead of one state tree per slot (TaiBai keeps
-neuron state resident in the core and moves only spikes). The learning
-path keeps one state tree per session instead.
+windows. That state lives in a `StatePool`: one device array per state
+leaf, a row per session, read and written only inside the resident step,
+so a cohort crosses the jit boundary as a row vector instead of one state
+tree per slot (TaiBai keeps neuron state resident in the core and moves
+only spikes). A learning session's synapse entries are rows of the pool
+too, so each session keeps its own learned weights.
 
-The `StateCache` is the multi-tenant memory story over either store: hot
-sessions keep their state on the device (a pool row, or a tree); once the
-hot set exceeds the byte budget, the least-recently-used sessions are
-*spilled* to host memory (`numpy` copies; a pool row is freed) and
-restored bit-identically on readmission. Spill -> restore is a pure
-device<->host copy of every leaf (no re-quantization, no re-init), so a
-session's trajectory is exactly the same whether it stayed resident or
-bounced through the cache — the invariant the isolation property tests
-pin down.
+The `StateCache` is the multi-tenant memory story over the pool: hot
+sessions keep their state in a pool row; once the hot set exceeds the
+byte budget, the least-recently-used sessions are *spilled* to host
+memory (`numpy` copies; the row is freed) and restored bit-identically on
+readmission. Spill -> restore is a pure device<->host copy of every leaf
+(no re-quantization, no re-init), so a session's trajectory is exactly
+the same whether it stayed resident or bounced through the cache — the
+invariant the isolation property tests pin down.
 
-Byte accounting uses `plan.state_nbytes`: a tree's whole footprint
-(synapse entries included), or a pool row's.
+Byte accounting uses `plan.state_nbytes` of a pool row (synapse entries
+included where the pool holds them).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import OrderedDict
 from typing import (Any, Callable, Dict, Iterable, List, NamedTuple,
                     Optional, Sequence, Tuple)
@@ -122,10 +122,26 @@ class Row(NamedTuple):
 def _map(fn: Callable, tree: Dict[str, Any], *rest: Dict[str, Any]
          ) -> Dict[str, Any]:
     """`fn(batch_axis, leaf, *same leaf of rest)` over a {node: {key: leaf}}
-    state tree."""
-    return {node: {k: fn(state_batch_axis(k), v, *(r[node][k] for r in rest))
+    state tree; the leaves of a synapse entry have batch axis None."""
+    def entry(ax, v, *r):
+        if ax is None:
+            return jax.tree_util.tree_map(functools.partial(fn, None), v, *r)
+        return fn(ax, v, *r)
+    return {node: {k: entry(state_batch_axis(k), v,
+                            *(r[node][k] for r in rest))
                    for k, v in nd.items()}
             for node, nd in tree.items()}
+
+
+def _row_axis(ax: Optional[int]) -> int:
+    """The pool's row axis for a leaf of batch axis `ax`: that axis, or a
+    leading axis the pool adds to a leaf with none (synapse entries)."""
+    return 0 if ax is None else ax
+
+
+def _as_row(ax: Optional[int], v):
+    """A one-session leaf shaped as one pool row."""
+    return v[None] if ax is None else v
 
 
 def gather_rows(leaves: Dict[str, Any], rows: jax.Array,
@@ -135,27 +151,45 @@ def gather_rows(leaves: Dict[str, Any], rows: jax.Array,
     src = jnp.where(fresh, INIT, rows)
     # rows come from the free-list, so always in bounds: "clip" gathers
     # with no out-of-bounds mask
-    return _map(lambda ax, v: jnp.take(v, src, axis=ax, mode="clip"), leaves)
+    return _map(lambda ax, v: jnp.take(v, src, axis=_row_axis(ax),
+                                       mode="clip"), leaves)
 
 
 def scatter_rows(leaves: Dict[str, Any], rows: jax.Array,
                  state: Dict[str, Any]) -> Dict[str, Any]:
     """Inside the step: the pool with slot k of `state` written to row
     `rows[k]` (padded slots all write SCRATCH)."""
-    return _map(lambda ax, v, s: (v.at[rows] if ax == 0 else v.at[:, rows])
-                .set(s), leaves, state)
+    return _map(lambda ax, v, s: (v.at[rows] if _row_axis(ax) == 0
+                                  else v.at[:, rows]).set(s), leaves, state)
+
+
+def vmap_slots(fn: Callable, state: Dict[str, Any], x: jax.Array):
+    """Inside the step: `fn(session state, x_k)`, with a batch of 1 and
+    x_k (W, 1, n_in), run once per slot k of a cohort `state` (as
+    `gather_rows` returns it) and of its input x (W, C, n_in); returns
+    the cohort's new state and its (W, C, n_out) output. The slots run as
+    independent `vmap` lanes, so each keeps its own synapse entries."""
+    axes = _map(lambda ax, v: _row_axis(ax), state)
+
+    def one(st, x_k):
+        st = _map(lambda ax, v: v if ax is None else jnp.expand_dims(v, ax),
+                  st)
+        ns, out = fn(st, x_k[:, None])
+        return _map(lambda ax, v: v if ax is None else jnp.squeeze(v, ax),
+                    ns), out[:, 0]
+    return jax.vmap(one, in_axes=(axes, 1), out_axes=(axes, 1))(state, x)
 
 
 class StatePool:
     """The device-resident state of every hot session (module docstring).
 
-    Built from a one-session state tree `init` (batch axis of size 1, no
-    synapse entries): each leaf becomes one array whose batch axis holds
-    `rows` rows, every row a copy of `init`. The host free-list hands
-    rows out (`claim`) and takes them back (`free`); rows SCRATCH and
-    INIT are never handed out. The pool starts at the power of two at or
-    above 4 x `capacity` rows and doubles whenever the free-list runs
-    dry; the step traces anew once per size (`pool_grows` counts them).
+    Built from a one-session state tree `init` (batch axis of size 1):
+    each leaf becomes one array whose row axis (`_row_axis`) holds `rows`
+    rows, every row a copy of `init`. The host free-list hands rows out
+    (`claim`) and takes them back (`free`); rows SCRATCH and INIT are
+    never handed out. The pool starts at the power of two at or above
+    4 x `capacity` rows and doubles whenever the free-list runs dry; the
+    step traces anew once per size (`pool_grows` counts them).
     """
 
     def __init__(self, init: Dict[str, Any], capacity: int,
@@ -163,8 +197,8 @@ class StatePool:
         self.metrics = metrics or ServeMetrics()
         self.row_bytes = state_nbytes(init)
         self.rows = 1 << max(2, (4 * capacity - 1).bit_length())
-        self.leaves = _map(lambda ax, v: jnp.repeat(v, self.rows, axis=ax),
-                           init)
+        self.leaves = _map(lambda ax, v: jnp.repeat(
+            _as_row(ax, v), self.rows, axis=_row_axis(ax)), init)
         self._free = list(range(self.rows - 1, INIT, -1))
 
     def claim(self) -> int:
@@ -177,7 +211,8 @@ class StatePool:
             # built on the host: an eager device concatenate would compile
             # anew for each leaf's new shape
             h = np.asarray(v)
-            return jnp.asarray(np.concatenate([h, np.zeros_like(h)], axis=ax))
+            return jnp.asarray(np.concatenate([h, np.zeros_like(h)],
+                                              axis=_row_axis(ax)))
 
         old = self.rows
         self.leaves = _map(doubled, self.leaves)
@@ -188,20 +223,19 @@ class StatePool:
     def read(self, row: Row) -> Dict[str, Any]:
         """The session's one-row state tree (INIT's if it is fresh)."""
         i = INIT if row.fresh else row.index
-        return _map(lambda ax, v: jax.lax.slice_in_dim(v, i, i + 1, axis=ax),
+        return _map(lambda ax, v: v[i] if ax is None
+                    else jax.lax.slice_in_dim(v, i, i + 1, axis=ax),
                     self.leaves)
 
     def write(self, index: int, state: Dict[str, Any]) -> None:
         """Put a one-row state tree (device or host) into row `index`."""
         self.leaves = _map(
             lambda ax, v, s: jax.lax.dynamic_update_slice_in_dim(
-                v, jnp.asarray(s, v.dtype), index, axis=ax),
+                v, _as_row(ax, jnp.asarray(s, v.dtype)), index,
+                axis=_row_axis(ax)),
             self.leaves, state)
 
-    # the StateCache store protocol: a hot value is a Row ------------------
-
-    def nbytes(self, row: Row) -> int:
-        return self.row_bytes
+    # what the StateCache asks of its store: a hot value is a Row ---------
 
     def spill(self, row: Row) -> Optional[Dict[str, Any]]:
         """Copy the row to host and free it (a fresh row copies nothing)."""
@@ -221,25 +255,6 @@ class StatePool:
         self._free.append(row.index)
 
 
-class _Trees:
-    """The StateCache store where each session keeps its own device tree
-    (the learning path)."""
-
-    nbytes = staticmethod(state_nbytes)
-
-    @staticmethod
-    def spill(state: Dict[str, Any]) -> Dict[str, Any]:
-        return jax.tree_util.tree_map(np.asarray, state)
-
-    @staticmethod
-    def restore(host: Dict[str, Any]) -> Dict[str, Any]:
-        return jax.tree_util.tree_map(jnp.asarray, host)
-
-    @staticmethod
-    def free(state: Dict[str, Any]) -> None:
-        pass
-
-
 # ---------------------------------------------------------------------------
 # the LRU cache
 # ---------------------------------------------------------------------------
@@ -249,27 +264,25 @@ class StateCache:
     """LRU session-state cache with a hot-set byte budget.
 
     `put`/`get` move states in and out keyed by session id; every access
-    refreshes recency. A state is what the `store` keeps hot: a `Row` of
-    a `StatePool`, or (no store given) the session's own device tree.
-    When hot bytes exceed `budget_bytes`, the least-recently-used entries
-    spill to host (`numpy`) until the budget holds again — `get` of a
-    spilled entry restores it to device bit-identically and counts a
-    miss+restore. `budget_bytes=None` means unbounded (nothing ever
-    spills).
+    refreshes recency. A hot state is a `Row` of the `store`, a
+    `StatePool`, and costs its `row_bytes`. When hot bytes exceed
+    `budget_bytes`, the least-recently-used entries spill to host
+    (`numpy`) until the budget holds again — `get` of a spilled entry
+    restores it to device bit-identically and counts a miss+restore.
+    `budget_bytes=None` means unbounded (nothing ever spills).
     """
 
-    def __init__(self, budget_bytes: Optional[int] = None,
-                 metrics: Optional[ServeMetrics] = None,
-                 store: Optional[StatePool] = None):
+    def __init__(self, store: StatePool,
+                 budget_bytes: Optional[int] = None,
+                 metrics: Optional[ServeMetrics] = None):
         if budget_bytes is not None and budget_bytes <= 0:
             raise ValueError(f"budget_bytes must be positive or None, "
                              f"got {budget_bytes}")
         self.budget_bytes = budget_bytes
         self.metrics = metrics or ServeMetrics()
-        self.store = store if store is not None else _Trees()
-        # sid -> (state, nbytes, spilled?); insertion order = recency
-        self._entries: "OrderedDict[str, Tuple[Any, int, bool]]" = \
-            OrderedDict()
+        self.store = store
+        # sid -> (row or host copy, spilled?); insertion order = recency
+        self._entries: "OrderedDict[str, Tuple[Any, bool]]" = OrderedDict()
 
     # -- introspection ------------------------------------------------------
 
@@ -281,15 +294,15 @@ class StateCache:
 
     @property
     def hot_bytes(self) -> int:
-        return sum(nb for _, nb, spilled in self._entries.values()
-                   if not spilled)
+        return self.store.row_bytes * sum(
+            not spilled for _, spilled in self._entries.values())
 
     @property
     def spilled(self) -> Tuple[str, ...]:
-        return tuple(sid for sid, (_, _, sp) in self._entries.items() if sp)
+        return tuple(sid for sid, (_, sp) in self._entries.items() if sp)
 
     def is_spilled(self, sid: str) -> bool:
-        return self._entries[sid][2]
+        return self._entries[sid][1]
 
     # -- core ---------------------------------------------------------------
 
@@ -303,7 +316,7 @@ class StateCache:
         sid = None
         for sid, state in items:
             self._entries.pop(sid, None)
-            self._entries[sid] = (state, self.store.nbytes(state), False)
+            self._entries[sid] = (state, False)
         self._enforce(keep=(sid,))
 
     def get(self, sid: str) -> Any:
@@ -315,21 +328,21 @@ class StateCache:
         spills before the next call (a pool row stays the session's)."""
         out = []
         for sid in sids:
-            state, nb, spilled = self._entries.pop(sid)
+            state, spilled = self._entries.pop(sid)
             if spilled:
                 state = self.store.restore(state)
                 self.metrics.bump("cache_misses")
                 self.metrics.bump("cache_restores")
             else:
                 self.metrics.bump("cache_hits")
-            self._entries[sid] = (state, nb, False)
+            self._entries[sid] = (state, False)
             out.append(state)
         self._enforce(keep=sids)
         return out
 
     def drop(self, sid: str) -> None:
         entry = self._entries.pop(sid, None)
-        if entry is not None and not entry[2]:
+        if entry is not None and not entry[1]:
             self.store.free(entry[0])
 
     def _enforce(self, keep: Sequence[Optional[str]] = ()) -> None:
@@ -345,13 +358,13 @@ class StateCache:
         for sid in list(self._entries):
             if hot <= self.budget_bytes:
                 break
-            state, nb, spilled = self._entries[sid]
+            state, spilled = self._entries[sid]
             if spilled or sid in keep:
                 continue
-            self._entries[sid] = (self.store.spill(state), nb, True)
-            hot -= nb
+            self._entries[sid] = (self.store.spill(state), True)
+            hot -= self.store.row_bytes
             self.metrics.bump("cache_evictions")
 
 
 __all__ = ["Session", "Row", "StatePool", "StateCache", "SCRATCH", "INIT",
-           "gather_rows", "scatter_rows"]
+           "gather_rows", "scatter_rows", "vmap_slots"]

@@ -162,7 +162,7 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
                       EngineConfig(window=SHD_T, capacity=SHD_B))
     assert not [s for s in eng.plan.segments
                 if s.kind == plan_mod.FALLBACK], eng.plan.describe()
-    step = _resident_step(eng.nodes, eng.plan, "flat", None)
+    step = _resident_step(eng.nodes, eng.plan, False, None)
 
     def abstract(tree):
         return jax.tree_util.tree_map(
@@ -175,6 +175,16 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
     x = jax.ShapeDtypeStruct((SHD_T, SHD_B, SHD_IN), f32, sharding=one_chip)
     compiled = step.lower(abstract(params), pool, rows, fresh, x).compile()
     text = compiled.as_text()
+    # the flat step carries no synapse entry and no per-slot operand: the
+    # params, one array per pool leaf, then rows, fresh and the input,
+    # however many slots the cohort has
+    assert not [k for nd in eng.pool.leaves.values() for k in nd
+                if k.startswith("syn:")]
+    n_args = (len(jax.tree_util.tree_leaves(params))
+              + len(jax.tree_util.tree_leaves(eng.pool.leaves)) + 3)
+    entry = re.search(r"^ENTRY .*?^\}", text, re.M | re.S).group(0)
+    assert len(re.findall(r"= \S+ parameter\(\d+\)", entry)) == n_args
+    assert n_args <= 16, n_args
     assert text.count("tpu_custom_call") >= 3, "spikemm, lif, linrec kernels"
     # each kernel's instruction carries its wrapper's name, which is how a
     # profiler trace names it

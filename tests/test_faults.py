@@ -147,15 +147,15 @@ def test_faults_jit_matches_eager():
                                atol=plan.CROSS_ENGINE_ATOL)
 
 
-def test_faults_identical_across_engines(monkeypatch):
-    """The fused plan and the per-step stepper must see the SAME injected
-    world: masks depend only on (seed, site), never on engine internals."""
+def test_faults_identical_across_engines():
+    """The fused plan and the per-step stepper reference must see the SAME
+    injected world: masks depend only on (seed, site), never on engine
+    internals."""
     nodes, params = dh_net()
     x = spikes(jax.random.PRNGKey(1))
     with faults.inject(SPEC):
         _, fused, _ = plan.run(nodes, params, x)
-        monkeypatch.setenv("REPRO_SNN_ENGINE", "stepper")
-        _, stepped, _ = plan.run(nodes, params, x)
+        _, stepped, _ = plan.run_reference(nodes, params, x)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(stepped),
                                atol=plan.CROSS_ENGINE_ATOL)
 

@@ -13,7 +13,8 @@ from repro.core import events, plan
 from repro.core.neuron import (ALIF, LI, LIF, PLIF, Decay, NeuronProgram,
                                ProgramNeuron, StateVar, Threshold)
 from repro.core.snn_layers import (branch_integrate, ff_integrate,
-                                   make_dhsnn_shd, make_srnn_ecg)
+                                   make_dhsnn_shd, make_plastic_ff,
+                                   make_srnn_ecg)
 from repro.kernels.spikemm.ops import block_occupancy, occupancy_fraction
 
 KEY = jax.random.PRNGKey(0)
@@ -141,12 +142,37 @@ def test_compile_backref_forces_whole_program_fallback():
     _assert_equiv(nodes, params, _spikes(KEY, (12, 3, 5)))
 
 
-def test_force_stepper_env(monkeypatch):
-    monkeypatch.setenv("REPRO_SNN_ENGINE", "stepper")
-    assert plan.engine_mode() == "stepper"
-    monkeypatch.setenv("REPRO_SNN_ENGINE", "bogus")
-    with pytest.raises(ValueError):
-        plan.engine_mode()
+def test_run_reference_is_the_stepper():
+    """`run_reference` is `events.run`, bit for bit, on a program that
+    does not learn; on a plastic one the forward is still the stepper's
+    and the learning pass then publishes new weights (none with
+    learn=False)."""
+    ks = jax.random.split(KEY, 2)
+    nodes = [events.LayerNode("h", LIF(), ff_integrate, ("input",), 8),
+             events.LayerNode("ro", LI(), ff_integrate, ("h",), 3)]
+    params = {"h": {"w_input": _w(ks[0], 5, 8)},
+              "ro": {"w_h": _w(ks[1], 8, 3)}}
+    x = _spikes(KEY, (12, 3, 5))
+    want = events.run(nodes, params, x, record=("h",))
+    got = plan.run_reference(nodes, params, x, record=("h",))
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    nodes, params = make_plastic_ff(jax.random.PRNGKey(7), n_in=6,
+                                    n_hidden=10)
+    x = _spikes(KEY, (9, 2, 6))
+    seed = np.asarray(params["hidden"]["w_input"])
+    st, out, _ = plan.run_reference(nodes, params, x)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(events.run(nodes, params, x)[1]))
+    assert not np.array_equal(np.asarray(st["hidden"]["syn:input"]["w"]),
+                              seed)
+    frozen, _, _ = plan.run_reference(nodes, params, x, learn=False)
+    np.testing.assert_array_equal(
+        np.asarray(frozen["hidden"]["syn:input"]["w"]), seed)
 
 
 # ---------------------------------------------------------------------------

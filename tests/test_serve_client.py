@@ -111,4 +111,4 @@ def test_client_stats_passthrough():
     client = StreamClient(_engine())
     client.run(_chunked(_stream_data(6, T=16), 8))
     stats = client.stats()
-    assert stats["windows_run"] >= 1 and stats["engine"] == "batched"
+    assert stats["windows_run"] >= 1 and stats["capacity"] == C

@@ -5,10 +5,9 @@ cross-session contamination, long-prompt admission, and TB5xx checks.
 The load-bearing invariant: a session's output trajectory and final state
 are bit-identical whether it runs alone, interleaved with strangers, is
 evicted to host and restored mid-stream, or reuses a retired session's
-pool row — because the batched engine always executes the SAME
-fixed-shape resident step (free slots on the scratch row, fresh sessions
-reading the initial-state row) and spill/restore is a pure device<->host
-copy.
+pool row — because the engine always executes the SAME fixed-shape
+resident step (free slots on the scratch row, fresh sessions reading the
+initial-state row) and spill/restore is a pure device<->host copy.
 """
 
 import functools
@@ -28,6 +27,7 @@ from repro.core.snn_layers import (ff_integrate, make_dhsnn_shd,
 from repro.kernels.incidents import clear, incidents
 from repro.serve import (EngineConfig, Histogram, Scheduler, ServeMetrics,
                          Session, StateCache, make_engine)
+from repro.core.plan import state_nbytes
 from repro.serve.engine import _resident_step
 from repro.serve.sessions import (SCRATCH, Row, StatePool,
                                   gather_rows)
@@ -35,6 +35,8 @@ from repro.serve.loop import Request, ServeConfig, _admit, generate
 from tests._faults import forced_pallas
 
 W, C = 8, 4        # one cohort shape for the whole module: jit once
+# the engine's slots per cohort: C sessions side by side, or one at a time
+CAPACITY = {"batched": C, "one_slot": 1}
 
 
 @functools.lru_cache(maxsize=None)
@@ -82,9 +84,8 @@ def _run(kind, data, cache_bytes=None, learn=False, model=None, drip=0,
          window=W):
     nodes, params = model if model is not None else _model()
     eng = make_engine(nodes, params,
-                      EngineConfig(window=window, capacity=C,
-                                   cache_bytes=cache_bytes, learn=learn),
-                      kind=kind)
+                      EngineConfig(window=window, capacity=CAPACITY[kind],
+                                   cache_bytes=cache_bytes, learn=learn))
     for sid in data:
         eng.open(sid)
     if drip:     # ragged arrival: submit in drip-sized chunks, stepping
@@ -121,9 +122,9 @@ def _leaves(state):
 def test_isolation_solo_interleaved_evict_restore(n_extra, T, seed):
     """Session s0's outputs and final state: solo == interleaved with
     strangers == interleaved under a 1-byte cache (every window evicts
-    and restores) — exact equality, both engines."""
+    and restores) — exact equality, in cohorts of C slots and of one."""
     data = _streams(1 + n_extra, T, seed)
-    for kind in ("batched", "naive"):
+    for kind in CAPACITY:
         solo = _run(kind, {"s0": data["s0"]})
         inter = _run(kind, data)
         evict = _run(kind, data, cache_bytes=1)
@@ -141,7 +142,7 @@ def test_isolation_solo_interleaved_evict_restore(n_extra, T, seed):
             np.testing.assert_array_equal(a, b)
 
 
-@pytest.mark.parametrize("kind", ["batched", "naive"])
+@pytest.mark.parametrize("kind", sorted(CAPACITY))
 def test_ragged_arrival_matches_bulk(kind):
     """Dripping uneven chunks through interleaved steps produces exactly
     the bulk-submitted trajectory (scheduling never changes numerics)."""
@@ -214,7 +215,7 @@ def _row_of(eng, sid):
     return eng.cache.get(sid).index
 
 
-@pytest.mark.parametrize("kind", ["batched", "naive"])
+@pytest.mark.parametrize("kind", sorted(CAPACITY))
 def test_retired_row_reused_matches_solo(kind):
     """A row freed at retire goes to the next open; that session starts
     from the initial state, not the stale row, and its outputs and final
@@ -222,8 +223,8 @@ def test_retired_row_reused_matches_solo(kind):
     data = _streams(2, 29, seed=13)
     solo = _run(kind, {"s1": data["s1"]})
     nodes, params = _model()
-    eng = make_engine(nodes, params, EngineConfig(window=W, capacity=C),
-                      kind=kind)
+    eng = make_engine(nodes, params,
+                      EngineConfig(window=W, capacity=CAPACITY[kind]))
     eng.open("s0")
     row = _row_of(eng, "s0")
     eng.submit("s0", data["s0"])
@@ -239,6 +240,44 @@ def test_retired_row_reused_matches_solo(kind):
     for a, b in zip(_leaves(solo.state_of("s1")), _leaves(eng.state_of("s1"))):
         np.testing.assert_array_equal(a, b)
     assert eng.stats()["fresh_resets"] == 2
+
+
+def _syn_w(state):
+    return {(n, k): np.asarray(v["w"]) for n, d in state.items()
+            for k, v in d.items() if k.startswith("syn:")}
+
+
+def test_retired_learning_row_reused_starts_from_seeded_weights():
+    """With learn=True a session's synapse entries are pool rows too: a
+    session that takes over a retired learner's row starts from the
+    seeded weights, not its predecessor's learned ones, and its outputs
+    and learned weights equal a solo run's bit for bit."""
+    model = _plastic_model()
+    nodes, params = model
+    data = _streams(2, 20, seed=19, n_in=10)
+    solo = _run("batched", {"s1": data["s1"]}, learn=True, model=model)
+    eng = make_engine(nodes, params,
+                      EngineConfig(window=W, capacity=C, learn=True))
+    eng.open("s0")
+    row = _row_of(eng, "s0")
+    eng.submit("s0", data["s0"])
+    eng.close("s0")
+    eng.drain()
+    learned = _syn_w(eng.state_of("s0"))
+    seeded = _syn_w(events.init_state(nodes, 1, jnp.float32, params))
+    assert learned.keys() == seeded.keys() and seeded
+    assert any(not np.array_equal(learned[k], seeded[k]) for k in seeded)
+    eng.retire("s0")
+    eng.open("s1")
+    assert _row_of(eng, "s1") == row
+    for k, w in _syn_w(eng.state_of("s1")).items():
+        np.testing.assert_array_equal(w, seeded[k])
+    eng.submit("s1", data["s1"])
+    eng.close("s1")
+    eng.drain()
+    np.testing.assert_array_equal(solo.outputs("s1"), eng.outputs("s1"))
+    for a, b in zip(_leaves(solo.state_of("s1")), _leaves(eng.state_of("s1"))):
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("model", sorted(MODELS))
@@ -294,7 +333,7 @@ def test_flat_step_argument_count_is_independent_of_capacity():
     counts = []
     for cap in (8, 64):
         eng = make_engine(nodes, params, EngineConfig(window=W, capacity=cap))
-        step = _resident_step(eng.nodes, eng.plan, "flat", None)
+        step = _resident_step(eng.nodes, eng.plan, False, None)
         rows, fresh = np.zeros(cap, np.int32), np.ones(cap, bool)
         x = jnp.zeros((W, cap, 12), jnp.float32)
         lowered = step.lower(params, eng.pool.leaves, rows, fresh, x)
@@ -305,83 +344,97 @@ def test_flat_step_argument_count_is_independent_of_capacity():
 
 
 # ---------------------------------------------------------------------------
-# state cache (over a session's own tree, and over a pool row)
+# state cache (over pool rows, with and without a learning session's
+# synapse entry)
 # ---------------------------------------------------------------------------
 
 
-def _toy_state(v, n=4):
-    return {"node": {"mem": jnp.full((1, n), float(v), jnp.float32),
-                     "out": jnp.zeros((1, n), jnp.float32)}}
+def _toy_state(v, syn=False, n=4):
+    """A one-session state; with `syn`, a synapse entry beside it whose
+    weight has no batch axis (the pool gives it a row axis of its own)."""
+    st = {"node": {"mem": jnp.full((1, n), float(v), jnp.float32),
+                   "out": jnp.zeros((1, n), jnp.float32)}}
+    if syn:
+        st["node"]["syn:input"] = {
+            "w": jnp.full((n, 3), float(v), jnp.float32),
+            "pre": jnp.full((1, n), -float(v), jnp.float32)}
+    return st
 
 
-def _toy_cache(store, budget, metrics=None):
-    pool = (StatePool(_toy_state(0), capacity=1, metrics=metrics)
-            if store == "pool" else None)
-    return StateCache(budget_bytes=budget, metrics=metrics, store=pool)
+def _toy_cache(syn, budget, metrics=None):
+    pool = StatePool(_toy_state(0, syn), capacity=1, metrics=metrics)
+    return StateCache(pool, budget_bytes=budget, metrics=metrics)
 
 
-def _put(cache, sid, v):
-    if isinstance(cache.store, StatePool):
-        i = cache.store.claim()
-        cache.store.write(i, _toy_state(v))
-        cache.put(sid, Row(i, False))
-    else:
-        cache.put(sid, _toy_state(v))
+def _put(cache, sid, v, syn):
+    i = cache.store.claim()
+    cache.store.write(i, _toy_state(v, syn))
+    cache.put(sid, Row(i, False))
 
 
-def _tree(cache, state):
-    return cache.store.read(state) if isinstance(state, Row) else state
+def _assert_row(cache, row, v, syn):
+    """The row reads back as `_toy_state(v, syn)`, leaf for leaf, shapes
+    included, on the device."""
+    got = cache.store.read(row)
+    want = _toy_state(v, syn)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert isinstance(g, jax.Array)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-STORES = ["trees", "pool"]
+ROWS = {"plain": False, "syn": True}
 
 
-@pytest.mark.parametrize("store", STORES)
-def test_cache_lru_spills_and_restores_bit_identical(store):
+@pytest.mark.parametrize("row", sorted(ROWS))
+def test_cache_lru_spills_and_restores_bit_identical(row):
+    syn = ROWS[row]
     m = ServeMetrics()
-    nbytes = 2 * 4 * 4                          # two (1,4) float32 leaves
-    cache = _toy_cache(store, 2 * nbytes, m)
+    nbytes = state_nbytes(_toy_state(0, syn))
+    assert nbytes == (2 * 4 * 4 if not syn else 2 * 4 * 4 + 4 * 12 + 4 * 4)
+    cache = _toy_cache(syn, 2 * nbytes, m)
     for i in range(3):
-        _put(cache, f"s{i}", i)
+        _put(cache, f"s{i}", i + 1, syn)
     assert cache.hot_bytes <= 2 * nbytes
     assert cache.spilled == ("s0",)             # LRU spilled first
     assert m.cache_evictions == 1
-    got = _tree(cache, cache.get("s0"))         # restore refreshes recency
-    np.testing.assert_array_equal(np.asarray(got["node"]["mem"]),
-                                  np.full((1, 4), 0.0))
-    assert isinstance(got["node"]["mem"], jax.Array)
+    _assert_row(cache, cache.get("s0"), 1, syn)  # restore refreshes recency
     assert not cache.is_spilled("s0")
     assert m.cache_misses == 1 and m.cache_restores == 1
     assert "s0" not in cache.spilled and len(cache.spilled) == 1
 
 
-@pytest.mark.parametrize("store", STORES)
-def test_cache_unbounded_never_spills(store):
-    cache = _toy_cache(store, None)
+@pytest.mark.parametrize("row", sorted(ROWS))
+def test_cache_unbounded_never_spills(row):
+    syn = ROWS[row]
+    cache = _toy_cache(syn, None)
     for i in range(20):
-        _put(cache, f"s{i}", i)
+        _put(cache, f"s{i}", i, syn)
     assert cache.spilled == ()
+    assert cache.hot_bytes == 20 * state_nbytes(_toy_state(0, syn))
     for i in range(20):
-        np.testing.assert_array_equal(
-            np.asarray(_tree(cache, cache.get(f"s{i}"))["node"]["mem"]),
-            np.full((1, 4), float(i)))
+        _assert_row(cache, cache.get(f"s{i}"), i, syn)
 
 
-@pytest.mark.parametrize("store", STORES)
-def test_cache_budget_smaller_than_one_session_still_serves(store):
+@pytest.mark.parametrize("row", sorted(ROWS))
+def test_cache_budget_smaller_than_one_session_still_serves(row):
+    syn = ROWS[row]
     m = ServeMetrics()
-    cache = _toy_cache(store, 1, m)
-    _put(cache, "a", 1)
-    _put(cache, "b", 2)
-    got = _tree(cache, cache.get("a"))          # the active session stays hot
+    cache = _toy_cache(syn, 1, m)
+    _put(cache, "a", 1, syn)
+    _put(cache, "b", 2, syn)
+    got = cache.get("a")                        # the active session stays hot
     assert not cache.is_spilled("a") and cache.is_spilled("b")
-    np.testing.assert_array_equal(np.asarray(got["node"]["mem"]),
-                                  np.full((1, 4), 1.0))
+    _assert_row(cache, got, 1, syn)
+    _assert_row(cache, cache.get("b"), 2, syn)  # b restored, a spilled
+    assert cache.is_spilled("a") and m.cache_restores == 2
 
 
 def test_cache_rejects_bad_budget():
     with pytest.raises(ValueError, match="budget_bytes"):
-        StateCache(budget_bytes=0)
+        StateCache(StatePool(_toy_state(0), capacity=1), budget_bytes=0)
 
 
 # ---------------------------------------------------------------------------

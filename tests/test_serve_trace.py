@@ -11,6 +11,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import spanlog
 from repro.core.snn_layers import make_dhsnn_shd
 from repro.serve import EngineConfig, make_engine, metrics
 
@@ -162,7 +163,7 @@ def test_every_span_has_its_twin_in_the_profiler_trace(tmp_path):
 
 
 def test_bound_drops_the_oldest_and_counts_them(traced, monkeypatch):
-    monkeypatch.setattr(metrics, "_MAX_SPANS", 3)
+    monkeypatch.setattr(spanlog, "_MAX_SPANS", 3)   # the log serve re-exports
     for k in range(5):
         metrics.record("serve.queue", k, k + 1, window=k)
     assert [r.ids["window"] for r in metrics.spans()] == [2, 3, 4]

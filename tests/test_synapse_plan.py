@@ -202,15 +202,14 @@ def test_plastic_on_inter_layer_and_delayed_connection():
                                    np.asarray(ref[k]), atol=1e-5, rtol=1e-5)
 
 
-def test_plastic_learning_identical_under_stepper_engine(monkeypatch):
-    """REPRO_SNN_ENGINE=stepper still learns — same trajectories as the
-    plan engine (the learning pass is engine-independent)."""
+def test_plastic_learning_identical_under_stepper_engine():
+    """The stepper reference (`plan.run_reference`) learns the same
+    trajectories as the plan (the learning pass is the same code)."""
     nodes, params = make_plastic_ff(jax.random.PRNGKey(7), n_in=6,
                                     n_hidden=10)
     x = _spikes(KEY, (9, 2, 6))
     st_plan, o_plan, _ = plan.run(nodes, params, x)
-    monkeypatch.setenv("REPRO_SNN_ENGINE", "stepper")
-    st_step, o_step, _ = plan.run(nodes, params, x)
+    st_step, o_step, _ = plan.run_reference(nodes, params, x)
     np.testing.assert_allclose(np.asarray(o_plan), np.asarray(o_step),
                                atol=1e-5)
     for k in st_plan["hidden"]["syn:input"]:
