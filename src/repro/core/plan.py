@@ -752,9 +752,14 @@ def _run_fallback(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
 # the group's arrival ring at slot t mod R as every member's current, runs
 # each member's FIRE (the NeuronProgram interpreter, background drive
 # included), clears the slot, and pushes the step's spikes through the
-# group's flat fan-out table (`kernels/event`). The ring is the members'
-# `arr` states side by side; the tables are built once per `run` call, or
-# once per stream (`prepare`), never inside a traced chunk.
+# group's flat fan-out table (`kernels/event`). The ring rides through the
+# scan flat, R slots of B * N entries one after another, the layout the
+# push scatters into: a step reads and clears its slot as one slice and
+# never reshapes the whole ring (on a TPU the 3-D and the flat ring are
+# tiled differently, so a reshape is a copy). The members' `arr` states,
+# (R, B, n) each, are joined and flattened once per call and split back
+# once after the scan. The tables are built once per `run` call, or once
+# per stream (`prepare`), never inside a traced chunk.
 
 
 def _event_members(seg: Segment, nodes_by_name: Dict[str, events.LayerNode]
@@ -813,9 +818,11 @@ def _run_event(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
     rings = [state[n.name]["arr"] for n in members if "arr" in state[n.name]]
     R = rings[0].shape[0]
     dtype = rings[0].dtype
+    M = B * N
     ring = jnp.concatenate(
         [state[n.name]["arr"] if "arr" in state[n.name]
-         else jnp.zeros((R, B, n.out_dim), dtype) for n in members], axis=-1)
+         else jnp.zeros((R, B, n.out_dim), dtype) for n in members],
+        axis=-1).reshape(R * M)
     fire0 = {n.name: {k: v for k, v in state[n.name].items()
                       if k not in ("out", "ring", "t", "arr")
                       and not k.startswith("syn:")} for n in members}
@@ -825,8 +832,8 @@ def _run_event(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
 
     def body(carry, _):
         fs, ring, t, _last, acc = carry
-        slot = t % R
-        cur = ring[slot]                                    # (B, N)
+        at = (t % R) * M
+        cur = jax.lax.dynamic_slice(ring, (at,), (M,)).reshape(B, N)
         new_fs, outs = {}, []
         with jax.named_scope("fire"):
             for n in members:
@@ -836,16 +843,18 @@ def _run_event(seg: Segment, nodes_by_name: Dict[str, events.LayerNode],
                 new_fs[n.name] = ns
                 outs.append(faults.perturb_output(n.name, so))
         spk = jnp.concatenate(outs, axis=-1).astype(dtype)
-        ring = ring.at[slot].set(jnp.zeros((B, N), dtype))
+        ring = jax.lax.dynamic_update_slice(ring, jnp.zeros((M,), dtype),
+                                            (at,))
         with jax.named_scope("integ"):
-            flat, st = event_push(ring.reshape(R, B * N), spk.reshape(-1), t,
-                                  tables, shift=shift, width=width)
+            ring, st = event_push(ring, spk.reshape(-1), t, tables,
+                                  shift=shift, width=width)
         y = _pack_bits(spk) if packed else spk
-        return (new_fs, flat.reshape(R, B, N), t + 1, spk, acc + st), y
+        return (new_fs, ring, t + 1, spk, acc + st), y
 
     carry0 = (fire0, ring, t0, last0, jnp.zeros((3,), jnp.uint32))
     (fs, ring, tT, last, stats), ys = jax.lax.scan(body, carry0, None,
                                                    length=T)
+    ring = ring.reshape(R, B, N)
     for n in members:
         o = offs[n.name]
         ns = dict(fs[n.name])

@@ -18,8 +18,14 @@ to, not what the table holds:
      windows (window r belongs to the neuron whose cumulative count
      first exceeds r: the count of cumulative sums <= r);
   2. gather those windows: one row gather of the table;
-  3. scatter-add their weights into the flat ring at slot
-     (t + delay) mod R.
+  3. scatter-add their weights into the ring at slot (t + delay) mod R.
+
+The ring is one flat float32 array of R * B * N entries, slot after slot,
+each slot a batch row after another: the push takes and returns it in
+that layout, and its caller (`core/plan.py`, the EVENT segment) keeps it
+so through every step of a chunk. On a TPU a 3-D (R, B, N) ring and a
+flat one are tiled differently, so each reshape between them would copy
+the whole ring.
 
 Scattering is what the chip does slowest here (an XLA scatter on a TPU
 v5e takes ~9 ns an update, PERF.md), so a spike scatters its own synapses
@@ -151,7 +157,8 @@ def build_event_tables(sources: Sequence[Tuple[int, Sequence]],
 def event_push_xla(ring, spikes, t, tables, *, shift: int, width: int,
                    cap: int):
     """The ranking push (see the module docstring)."""
-    R, M = ring.shape
+    M = spikes.shape[0]
+    R = ring.shape[0] // M
     N = tables.win_start.shape[0]
     B = M // N
     spare = tables.windows.shape[0] - 1
@@ -189,10 +196,10 @@ def event_push_xla(ring, spikes, t, tables, *, shift: int, width: int,
 
         passes = (total + cap - 1) // cap
         _, flat = jax.lax.while_loop(lambda c: c[0] < passes, one_pass,
-                                     (jnp.int32(0), ring.reshape(-1)))
+                                     (jnp.int32(0), ring))
         stats = jnp.stack([jnp.sum(s, dtype=jnp.int32), events,
                            (passes > 1).astype(jnp.int32)])
-    return flat.reshape(R, M), stats.astype(jnp.uint32)
+    return flat, stats.astype(jnp.uint32)
 
 
 def _pallas_impl(ring, spikes, t, tables, *, blocks, interpret, shift,
@@ -210,9 +217,11 @@ def _ref_impl(ring, spikes, t, tables, *, shift, width, cap):
 def event_push(ring: Array, spikes: Array, t, tables: EventTables, *,
                shift: int, width: int, cap: int = 0, force: bool = False
                ) -> Tuple[Array, Array]:
-    """One step's push: (ring (R, B*N), stats uint32[3]: spikes, synaptic
-    events, 1 where the step's windows took more than one pass). `cap`
-    (windows a pass) 0 takes the registry's block (tuned, else 32)."""
+    """One step's push of `spikes` (B*N,) into the flat ring (R*B*N,):
+    (the ring in the same flat layout, stats uint32[3]: spikes, synaptic
+    events, 1 where the step's windows took more than one pass). R is
+    `ring.size // spikes.size`. `cap` (windows a pass) 0 takes the
+    registry's block (tuned, else 32)."""
     if not cap:
         spec = registry.get("event_push")
         cap = spec.resolve_blocks(spec.dims_of(ring, spikes, t, tables))[
@@ -244,7 +253,7 @@ def _make_inputs(key):
     g = build_event_tables(sources, sum(sizes), width=8)
     N, B, R = sum(sizes), 2, 8
     k1, k2 = jax.random.split(key)
-    ring = jax.random.normal(k1, (R, B * N), jnp.float32)
+    ring = jax.random.normal(k1, (R * B * N,), jnp.float32)
     spikes = (jax.random.uniform(k2, (B * N,)) < 0.13).astype(jnp.float32)
     return ring, spikes, jnp.int32(5), g.tables, g.shift, g.width
 
@@ -264,8 +273,8 @@ registry.register(registry.KernelSpec(
     # more padded slots on a quiet step, a smaller one for more passes
     block_axes=(registry.BlockAxis("cap", "M", preferred=32, align=8),),
     dims_of=lambda ring, spikes, t, tables, *rest: {
-        "R": ring.shape[0], "M": ring.shape[1],
-        "N": tables.win_start.shape[0]},
+        "R": ring.shape[0] // spikes.shape[0], "M": spikes.shape[0],
+        "N": tables.win_start.shape[0], "RM": ring.shape[0]},
     candidates=({"cap": 16}, {"cap": 32}, {"cap": 64}),
     make_inputs=_make_inputs,
     diff_argnums=(),
@@ -273,8 +282,8 @@ registry.register(registry.KernelSpec(
     # not a Pallas kernel (XLA operations hold nothing in VMEM by this
     # model): the pass's gathered windows, target and weight per slot
     vmem_bytes=lambda dims, b: 4 * b["cap"] * 2 * SEGMENT,
-    # one pass updates the whole ring in place (no grid)
+    # one pass updates the whole flat ring in place (no grid)
     tile_model=registry.TileModel(
-        out=(("R", None), ("M", None)),
+        out=(("RM", None),),
         tiles=lambda dims, b: {"windows": (b["cap"], 2 * SEGMENT)}),
 ))

@@ -10,12 +10,14 @@ import jax.numpy as jnp
 
 def event_push_ref(ring, spikes, t, tables, *, shift: int, width: int,
                    cap: int):
-    """ring (R, B*N) f32, spikes (B*N,) 0/1, t the step -> (ring, stats).
+    """ring (R*B*N,) f32, R slots of B*N one after another, spikes (B*N,)
+    0/1, t the step -> (the ring in the same layout, stats).
 
     stats (uint32[3]): spikes, synaptic events delivered, and 1 where the
     step's spiking neurons hold more than `cap` windows (a ranking push
     needs an extra pass)."""
-    R, M = ring.shape
+    M = spikes.shape[0]
+    R = ring.shape[0] // M
     N = tables.win_start.shape[0]
     B = M // N
     win = jnp.arange(tables.windows.shape[0])
@@ -41,4 +43,4 @@ def event_push_ref(ring, spikes, t, tables, *, shift: int, width: int,
     segs = jnp.sum(jnp.where(fired, tables.seg_count[None, :], 0),
                    dtype=jnp.int32)
     stats = jnp.stack([n, events, (segs > cap).astype(jnp.int32)])
-    return out.reshape(R, M), stats.astype(jnp.uint32)
+    return out.reshape(-1), stats.astype(jnp.uint32)

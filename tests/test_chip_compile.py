@@ -51,15 +51,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def chip_dispatch(monkeypatch):
+def _steer_to_chip(mp):
     """Steer dispatch as it runs on the chip: compiled Pallas kernels, the
     spec-default blocks (the checked-in tuning cache holds CPU entries
     only, which the chip never sees)."""
     from repro.kernels import tuning
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    monkeypatch.setattr(tuning, "lookup_tuned", lambda *a, **k: None)
+    mp.setenv("REPRO_KERNEL_IMPL", "pallas")
+    mp.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mp.setattr(tuning, "lookup_tuned", lambda *a, **k: None)
+
+
+@pytest.fixture
+def chip_dispatch(monkeypatch):
+    _steer_to_chip(monkeypatch)
 
 
 def _compile(fn, shapes, one_chip):
@@ -195,11 +199,26 @@ def test_resident_serve_step_compiles_for_v5e(dendritic, one_chip,
     assert {"integ", "fire", "pack", "unpack"} <= scopes, scopes
 
 
-def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
+# the Potjans-Diesmann circuit's stream chunk: 64 ring slots, 200 steps
+MC_R, MC_T = 64, 200
+
+
+@pytest.fixture(scope="module")
+def microcircuit_chunk(one_chip):
     """The jitted 200-step stream chunk of the Potjans-Diesmann circuit at
     its published sizes (77,169 neurons, 2.99e8 synapses in a 2.7 GB
-    table of windows): the whole EVENT segment, with the push's scatter
-    into the flat ring, and the pass's windows fetched without a loop."""
+    table of windows), compiled once for the module: (compiled, the HLO
+    text, its computations by name)."""
+    with pytest.MonkeyPatch.context() as mp:
+        _steer_to_chip(mp)
+        compiled = _compile_microcircuit_chunk(one_chip)
+    text = compiled.as_text()
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.-]+) [^\n]*\{$(.*?)^\}$", text, re.M | re.S)}
+    return compiled, text, comps
+
+
+def _compile_microcircuit_chunk(one_chip):
     from repro.core import events, microcircuit as mc, plan as plan_mod
     from repro.core.neuron import IafPscExp
     from repro.core.snn_layers import ff_integrate
@@ -208,7 +227,7 @@ def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
 
     # 298,880,968 synapses in rows of 512-entry windows: the builder's
     # table holds ceil(synapses / 512) + N rows (~623,000 of them used)
-    n, R, T, W, syn = mc.N_FULL, 64, 200, 512, 298_880_968
+    n, R, T, W, syn = mc.N_FULL, MC_R, MC_T, 512, 298_880_968
     neuron = IafPscExp(bg_weight=87.81)
     nodes = []
     for q, name in enumerate(mc.POPULATIONS):
@@ -240,32 +259,77 @@ def test_microcircuit_chunk_compiles_for_v5e(one_chip, chip_dispatch):
                     "t": S((), i32), "arr": S((R, 1, n[q]), f32)}
              for q, name in enumerate(mc.POPULATIONS)}
     step = plan_mod.stream_step(nodes, plan, spikes="list")
-    compiled = step.lower(params, {0: tables}, state,
-                          S((T, 1, 0), f32)).compile()
-    text = compiled.as_text()
-    # `push_roofline.sim` finds the push's pass loop in the trace as the
-    # innermost loop around the one operation that writes the flat ring
-    ring = re.compile(rf"^\s*%[\w.-]+ = f32\[{R * N}\]\S* (fusion|scatter)\(",
-                      re.M)
+    return step.lower(params, {0: tables}, state,
+                      S((T, 1, 0), f32)).compile()
+
+
+def _called(comps, root):
+    """`root` and every computation it calls, directly or not."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [k for k in re.findall(r"%([\w.-]+)", comps[c])
+                     if k in comps]
+    return seen
+
+
+def _pass_loop(text):
+    """(the pass loop's instruction, its body): `push_roofline.sim` finds
+    the push's pass loop in the trace as the innermost loop around the
+    one operation that writes the flat ring."""
+    from repro.core import microcircuit as mc
+    ring = re.compile(
+        rf"^\s*%[\w.-]+ = f32\[{MC_R * sum(mc.N_FULL)}\]\S* (fusion|scatter)\(",
+        re.M)
     hits = ring.findall(text)
     assert len(hits) == 1, hits
     at = ring.search(text).start()
     head = re.findall(r"^(?:ENTRY )?%([\w.-]+) .*\{$", text[:at], re.M)[-1]
-    loops = re.findall(rf"^\s*%while[.\d]* = .* while\(.*body=%{re.escape(head)}\b",
-                       text, re.M)
+    loops = re.findall(
+        rf"^\s*(%while[.\d]*) = .* while\(.*body=%{re.escape(head)}\b",
+        text, re.M)
     assert len(loops) == 1, f"the scatter's computation {head} is no loop body"
+    return loops[0], head
+
+
+def test_microcircuit_chunk_compiles_for_v5e(microcircuit_chunk):
+    """The whole EVENT segment, with the push's scatter into the flat
+    ring, and the pass's windows fetched without a loop."""
+    compiled, text, comps = microcircuit_chunk
+    _, head = _pass_loop(text)
     # the pass fetches its windows with one gather: no loop runs inside
     # the pass loop's body, nor in any computation it calls
-    comps = {m.group(1): m.group(2) for m in re.finditer(
-        r"^(?:ENTRY )?%([\w.-]+) [^\n]*\{$(.*?)^\}$", text, re.M | re.S)}
-    seen, todo = set(), [head]
-    while todo:
-        c = todo.pop()
-        if c in seen:
-            continue
-        seen.add(c)
+    for c in _called(comps, head):
         assert not re.search(r" while\(", comps[c]), f"a loop in {c}"
-        todo += [k for k in re.findall(r"%([\w.-]+)", comps[c])
-                 if k in comps]
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 4e9
+
+
+def test_microcircuit_scan_keeps_the_ring_flat(microcircuit_chunk):
+    """The 200-step scan carries the arrival ring flat: a step runs the
+    push's pass loop and no other loop, and neither relays the ring into
+    its 3-D (R, B, N) layout nor builds a whole ring of zeros. Those move
+    the whole 19.8 MB ring every step and do no arithmetic; the one
+    relayout each way belongs at the chunk's entry and exit."""
+    from repro.core import microcircuit as mc
+    _, text, comps = microcircuit_chunk
+    N = sum(mc.N_FULL)
+    pass_loop, _ = _pass_loop(text)
+    scans = [c for c, body in comps.items()
+             if re.search(rf"^\s*{re.escape(pass_loop)} = ", body, re.M)]
+    assert len(scans) == 1, scans
+    scan = scans[0]
+    assert re.search(rf"^\s*%while[.\d]* = .* while\(.*body=%{re.escape(scan)}\b",
+                     text, re.M), f"{scan}, which runs the pass loop, is no loop body"
+    step = "\n".join(comps[c] for c in _called(comps, scan))
+    loops = re.findall(r"^\s*(%[\w.-]+) = .* while\(", step, re.M)
+    assert loops == [pass_loop], loops
+    shaped = re.findall(
+        rf"^\s*%[\w.-]+ = f32\[(?:{MC_R},1,{N}|1,{MC_R},{N})\].*$", step, re.M)
+    assert not shaped, shaped[:3]
+    zeros = re.findall(
+        rf"^\s*%[\w.-]+ = f32\[(?:{MC_R * N}|{MC_R},1,{N}|1,{MC_R},{N})\]"
+        r"\S* broadcast\(.*$", step, re.M)
+    assert not zeros, zeros[:3]

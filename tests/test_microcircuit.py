@@ -133,6 +133,26 @@ def test_one_shot_run_equals_stream_and_stepper(circuit):
                                   np.asarray(out1))
 
 
+def test_arrival_ring_crosses_chunk_borders_bit_for_bit(circuit):
+    """The ring each chunk hands the next, split back into the members'
+    (R, B, n) `arr` states: 3 chunks of `run_stream` leave every member
+    the ring of one `plan.run` over the same steps, bit for bit."""
+    nodes, params, st0 = circuit
+    T = 20
+    final = None
+    for st, _ in plan.run_stream(nodes, params, [jnp.zeros((T, 1, 0))] * 3,
+                                 state=st0):
+        final = jax.tree_util.tree_map(jnp.copy, st)
+    one, _, _ = plan.run(nodes, params, jnp.zeros((3 * T, 1, 0)), st0)
+    live = 0
+    for n in nodes:
+        got = np.asarray(final[n.name]["arr"])
+        assert got.shape == st0[n.name]["arr"].shape
+        np.testing.assert_array_equal(got, np.asarray(one[n.name]["arr"]))
+        live += int(np.count_nonzero(got))
+    assert live > 0                       # currents in flight at the end
+
+
 def test_stream_donates_its_state_and_keeps_the_callers(circuit):
     nodes, params, st0 = circuit
     it = plan.run_stream(nodes, params, [jnp.zeros((5, 1, 0))] * 2,
@@ -237,12 +257,13 @@ def test_burst_past_the_cap_drops_nothing_and_counts_the_pass():
                          rng.normal(size=m).astype(np.float32),
                          rng.integers(1, 6, m), n, n)
     g = build_event_tables([(n, [(topo, 0)])], n)
-    ring = jnp.zeros((8, n))
+    ring = jnp.zeros((8 * n,))                   # 8 slots of n, flat
     spikes = jnp.zeros((n,)).at[:50].set(1.0)    # 50 spikes, cap 8
     out, stats = event_push(ring, spikes, jnp.int32(3), g.tables,
                             shift=g.shift, width=g.width, cap=8, force=True)
     ref, rstats = event_push(ring, spikes, jnp.int32(3), g.tables,
                              shift=g.shift, width=g.width, cap=8)
+    assert out.shape == ring.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
     pre, post, w, d = topo.coo_delays()
     fired = pre < 50
@@ -313,14 +334,14 @@ def test_window_table_holds_each_row_whole_and_aligned(count):
 @pytest.mark.parametrize("cap", [8, 16])
 def test_push_matches_the_oracle_over_passes(cap):
     """A burst of 50 spikes at windows of 8 takes several passes at `cap`
-    8 and 16; the ring and the stats equal the oracle's."""
+    8 and 16; the flat ring and the stats equal the oracle's."""
     rng = np.random.default_rng(4)
     n, m = 200, 3000
     topo = encode_fanout(rng.integers(0, n, m), rng.integers(0, n, m),
                          rng.normal(size=m).astype(np.float32),
                          rng.integers(1, 6, m), n, n)
     g = build_event_tables([(n, [(topo, 0)])], n, width=8)
-    ring = jax.random.normal(jax.random.PRNGKey(0), (8, n))
+    ring = jax.random.normal(jax.random.PRNGKey(0), (8 * n,))
     spikes = jnp.zeros((n,)).at[::4].set(1.0)
     segs = int(np.asarray(g.tables.seg_count)[::4].sum())
     assert segs > 2 * cap                          # three passes or more
@@ -328,6 +349,7 @@ def test_push_matches_the_oracle_over_passes(cap):
                      width=g.width, cap=cap, force=True)
     want = event_push(ring, spikes, jnp.int32(6), g.tables, shift=g.shift,
                       width=g.width, cap=cap)
+    assert got[0].shape == want[0].shape == ring.shape
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                atol=1e-5)
     assert list(np.asarray(got[1])) == list(np.asarray(want[1]))
